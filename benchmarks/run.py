@@ -15,9 +15,11 @@ Every suite's wall-clock is split into ``compile_s`` (XLA compilation
 time, measured through ``jax.monitoring``'s event-duration stream) and
 ``execute_s`` (everything else): a new lane that triggers one extra
 compile is a very different signal from a steady-state slowdown, and
-``benchmarks.compare`` gates only the latter.  Each suite also writes a
-``results/BENCH_<suite>.manifest.json`` (schema, wall split, versions) so
-a results directory is self-describing.
+``benchmarks.compare`` gates only the latter.  Compiled programs persist
+across runs in the cache ``repro.sim.compile_cache`` places
+(``$JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``).  Each
+suite also writes a ``results/BENCH_<suite>.manifest.json`` (schema,
+wall split, versions) so a results directory is self-describing.
 """
 from __future__ import annotations
 
@@ -74,6 +76,7 @@ def _write_manifest(suite_key: str, manifest: dict) -> None:
 
 
 def main() -> None:
+    from repro.sim.compile_cache import enable_compile_cache
     from repro.sim.telemetry import BENCH_MANIFEST_SCHEMA, versions
 
     from . import (chains, cold_start, continuum_bench, drops, failures,
@@ -81,6 +84,7 @@ def main() -> None:
                    replay, roofline, serving_bench, stress, sweep_speed,
                    telemetry, vertical, workload_analysis)
 
+    enable_compile_cache()
     _install_compile_listener()
     suites = [
         ("workload_analysis(Figs2-5)", workload_analysis.run),

@@ -34,8 +34,9 @@ Three step modes (``STEP_MODES``), numerically identical
   (``core.pool_jax.pool_step_batch`` + ``register_step_backend``) as ONE
   fused Pallas kernel (``repro.kernels.pool_step``): rank-by-counting
   instead of argsort, prefix-sum eviction, and slot placement in a
-  single pass over the stacked ``[pools, slots]`` axes.  Compiled on
-  TPU, interpreted (bit-identically) on CPU.
+  single pass over the stacked ``[pools, slots]`` axes.  Compiled by
+  Mosaic on TPU (run and checked against ``"gather"`` on a TPU v5e by
+  ``chip_smoke.py``), interpreted (bit-identically) on CPU.
 
 Autoscaled scenarios (``Scenario(..., autoscale=Autoscale(...))``) run the
 same per-event step inside an outer scan over fixed-length epochs
@@ -64,15 +65,15 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from ..core.compat import deprecated
 from ..core.continuum import (Autoscale, ChainPlan, ClusterConfig, Failures,
                               cloud_cold_draws, cluster_outcomes_ref,
                               route_hashes)
+from ..core.ieee import ieee_div
 from ..core.pool_jax import (Event, PoolState, get_step_backend, init_pool,
-                             pool_resize, pool_step, pool_step_batch)
+                             pool_resize, pool_step, pool_step_batch, put)
 from ..core.registry import ROUTING, RouteCtx, observed_usage
 from ..core.types import DROP, HIT, MISS, PoolConfig, Trace
 from .metrics import ClusterResult, build_result
@@ -398,11 +399,12 @@ def _chain_event(chain: ChainAcc, cx: ChainXs, ccold: jax.Array,
     final = chain.lat[cx.cid] + stage_lat
     new_dropped = chain.dropped[cx.cid] | (outcome == DROP)
     miss = cx.last & (new_dropped | (final > cdl[cx.cid]))
+    # the bool rows are updated by select (``pool_jax.put``), not scatter
     return ChainAcc(
         lat=chain.lat.at[cx.cid].set(final),
-        dropped=chain.dropped.at[cx.cid].set(new_dropped),
-        done=chain.done.at[cx.cid].set(chain.done[cx.cid] | cx.last),
-        missed=chain.missed.at[cx.cid].set(chain.missed[cx.cid] | miss)
+        dropped=put(chain.dropped, cx.cid, new_dropped),
+        done=put(chain.done, cx.cid, chain.done[cx.cid] | cx.last),
+        missed=put(chain.missed, cx.cid, chain.missed[cx.cid] | miss)
     ), miss.astype(jnp.int32)
 
 
@@ -523,12 +525,8 @@ def _make_step(routing: jax.Array, unified: jax.Array, cloud: jax.Array,
         core_ev = Event(ev.t, ev.func_id, ev.size, ev.cls, ev.warm, ev.cold,
                         ev.used)
         if mode == "gather":
-            one = tree(lambda a: a[p], pools)
-            new_one, outcome = pool_step(one, core_ev)
-            if up_n is not None:
-                new_one = tree(lambda nw, old: jnp.where(ok, nw, old),
-                               new_one, one)
-            pools = tree(lambda a, b: a.at[p].set(b), pools, new_one)
+            stepped, outcome = pool_step(tree(lambda a: a[p], pools),
+                                         core_ev)
         else:
             # step every pool, keep only the routed one: "vmap" batches
             # the per-pool step, any other mode is a registered step
@@ -539,12 +537,14 @@ def _make_step(routing: jax.Array, unified: jax.Array, cloud: jax.Array,
                     pools, core_ev)
             else:
                 stepped, outs = pool_step_batch(pools, core_ev, backend)
-            sel = (jnp.arange(2 * n) == p) & ok
-            pools = tree(
-                lambda a, b: jnp.where(
-                    sel.reshape((-1,) + (1,) * (a.ndim - 1)), b, a),
-                pools, stepped)
             outcome = outs[p]
+        # write the routed pool back by select, not scatter (see
+        # ``pool_jax.put``); a request to a down node changes nothing
+        sel = (jnp.arange(2 * n) == p) & ok
+        pools = tree(
+            lambda a, b: jnp.where(
+                sel.reshape((-1,) + (1,) * (a.ndim - 1)), b, a),
+            pools, stepped)
         outcome = jnp.where(ok, outcome, DROP)
         return pools, (node, outcome)
 
@@ -784,8 +784,8 @@ def _run_autoscale_impl(pools: PoolState, events: ClusterEvent,
         press_s, press_l = press[:, 0], press[:, 1]
         tot = press_s + press_l
         delta = jnp.where(tot > 0,
-                          gain * (press_s - press_l)
-                          / jnp.where(tot > 0, tot, jnp.float32(1.0)),
+                          ieee_div(gain * (press_s - press_l),
+                                   jnp.where(tot > 0, tot, jnp.float32(1.0))),
                           jnp.float32(0.0))
         # a trailing partial epoch (pad suffix ⇒ last event invalid) never
         # completes: no re-split, the frac row just repeats
@@ -805,7 +805,8 @@ def _run_autoscale_impl(pools: PoolState, events: ClusterEvent,
             resized, pools)
         # node add/remove from the cluster-wide drop fraction (post-resize
         # residency decides "emptiest"; at most one node moves per epoch)
-        drop_frac = dropw / jnp.maximum(jnp.sum(val), jnp.float32(1.0))
+        drop_frac = ieee_div(dropw,
+                             jnp.maximum(jnp.sum(val), jnp.float32(1.0)))
         n_active = jnp.sum(active.astype(jnp.int32))
         can_spawn = is_full & (drop_frac > spawn_th) & (n_active < n)
         can_retire = (is_full & ~can_spawn & (drop_frac < retire_th)
@@ -815,10 +816,9 @@ def _run_autoscale_impl(pools: PoolState, events: ClusterEvent,
         cand_retire = jnp.argmin(
             jnp.where(active, used_n, jnp.float32(jnp.inf)))
         new_active = jnp.where(
-            can_spawn, active.at[cand_spawn].set(True),
-            jnp.where(can_retire, active.at[cand_retire].set(False),
-                      active))
-        retire_mask = jnp.zeros((n,), bool).at[cand_retire].set(can_retire)
+            can_spawn, put(active, cand_spawn, True),
+            jnp.where(can_retire, put(active, cand_retire, False), active))
+        retire_mask = (jnp.arange(n) == cand_retire) & can_retire
         cnt, pools = _invalidate_nodes(pools, retire_mask, n)
         if tel_on:
             # retirement invalidations belong to the epoch's last real
@@ -903,15 +903,26 @@ def _lane_specs(axes: tuple) -> tuple:
 def _shard_lanes(fn, axes: tuple, devices: int | None):
     """Wrap a vmapped sweep impl in shard_map over the lane axis (every
     output of every runner is lane-stacked, hence the blanket out_specs).
-    A no-op when ``devices`` is None.  ``check_rep=False`` because
+    A no-op when ``devices`` is None.  ``check_vma=False`` because
     pallas_call (``mode="fused"``) has no replication rule — harmless
     here since no output is replicated."""
     if devices is None:
         return fn
-    return shard_map(fn, mesh=_lane_mesh(devices),
-                     in_specs=_lane_specs(axes),
-                     out_specs=PartitionSpec("lanes"),
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=_lane_mesh(devices),
+                         in_specs=_lane_specs(axes),
+                         out_specs=PartitionSpec("lanes"),
+                         check_vma=False)
+
+
+def _lanes_np(x: jax.Array, placement: dict | None) -> np.ndarray:
+    """Host copy of a lane-stacked output.  With a ``placement`` dict
+    (sharded sweeps), first record how many lane rows, pad lanes
+    included, each device holds — read from the output's shards, so it
+    says where the lanes really ran."""
+    if placement is not None:
+        placement.update({str(sh.device): int(sh.data.shape[0])
+                          for sh in x.addressable_shards})
+    return np.asarray(x)
 
 
 def _lane_pad(lanes: int, devices: int | None) -> int:
@@ -1192,12 +1203,14 @@ def _sweep_chain_data(chains, configs, t_len: int, rng_seed: int):
 
 def _sweep_cluster(trace: Trace, configs, rng_seed: int = 0,
                    mode: str = "gather", telemetry: int | None = None,
-                   chains=None, devices: int | None = None):
+                   chains=None, devices: int | None = None,
+                   placement: dict | None = None):
     """Returns one ``ClusterResult`` per config — or, with ``telemetry``
     and/or ``chains`` (one compiled ``ChainPlan`` per config), one
     ``(result, extras)`` pair per config.  ``devices`` shards the lane
     axis across a device mesh (results stay bit-identical; pad lanes are
-    sliced off here by never reading their rows)."""
+    sliced off here by never reading their rows); ``placement`` receives
+    the lane rows per device (see :func:`_lanes_np`)."""
     check_step_mode(mode)
     devices = check_devices(devices)
     configs, n, pools, routing, unified, cloud = _stack_configs(
@@ -1219,7 +1232,7 @@ def _sweep_cluster(trace: Trace, configs, rng_seed: int = 0,
                       _lane_pad(len(configs), devices))
     outs = _sweep_runner(n, mode, tel=tel_on, chain=ch_on,
                          devices=devices)(*args)
-    nodes, outcomes = np.asarray(outs[0]), np.asarray(outs[1])
+    nodes, outcomes = _lanes_np(outs[0], placement), np.asarray(outs[1])
     out = []
     for g, c in enumerate(configs):
         cc = (clouds[g] if ch_on
@@ -1304,8 +1317,8 @@ def _simulate_cluster_failures_ref(
 def _sweep_cluster_failures(
         trace: Trace, configs, failures, rng_seed: int = 0,
         mode: str = "gather", telemetry: int | None = None,
-        chains=None, devices: int | None = None
-        ) -> list[tuple[ClusterResult, dict]]:
+        chains=None, devices: int | None = None,
+        placement: dict | None = None) -> list[tuple[ClusterResult, dict]]:
     """Vmapped sweep over failure-injected configs: each lane's compiled
     up/recover masks ride as data (lanes bucket by mask shape, which the
     shared trace and ``n_nodes`` pin)."""
@@ -1336,7 +1349,7 @@ def _sweep_cluster_failures(
                       _lane_pad(len(configs), devices))
     outs = _sweep_failures_runner(n, mode, tel=tel_on, chain=ch_on,
                                   devices=devices)(*args)
-    nodes, outcomes = np.asarray(outs[0]), np.asarray(outs[1])
+    nodes, outcomes = _lanes_np(outs[0], placement), np.asarray(outs[1])
     invals = np.asarray(outs[2], np.int64)
     out = []
     for g, c in enumerate(configs):
@@ -1655,11 +1668,27 @@ def _simulate_cluster_chunked_jax(
     return result, extras
 
 
+def lower_chunk_program(cfg: ClusterConfig, trace: Trace,
+                        mode: str = "gather", chunk_events: int = 65536):
+    """The first-chunk program of a static chunked run (what
+    ``simulate(..., chunk_events=...)`` executes), lowered but not run,
+    to inspect what the compiler is given — e.g. whether
+    ``mode="fused"`` holds the Pallas kernel as a ``tpu_custom_call``."""
+    check_step_mode(mode)
+    chunk = check_chunk_events(chunk_events)
+    ev = _chunk_slice(_host_events(trace, cfg.n_nodes), 0,
+                      min(chunk, len(trace)), chunk, _drop_size(cfg))
+    return _chunk_runner(cfg.n_nodes, mode).lower(
+        init_cluster(cfg), ev, jnp.int32(int(cfg.routing)),
+        jnp.asarray(cfg.unified, bool), _cloud_vec(cfg))
+
+
 def _sweep_cluster_chunked(trace: Trace, configs, rng_seed: int = 0,
                            mode: str = "gather",
                            chunk_events: int = 65536,
                            failures=None, telemetry: int | None = None,
-                           chains=None, devices: int | None = None):
+                           chains=None, devices: int | None = None,
+                           placement: dict | None = None):
     """Chunked twin of ``_sweep_cluster`` / ``_sweep_cluster_failures``:
     the chunk loop threads one *stacked* donated carry across all lanes.
     With ``failures`` (one ``Failures``/None per config), ``telemetry``
@@ -1747,7 +1776,7 @@ def _sweep_cluster_chunked(trace: Trace, configs, rng_seed: int = 0,
         else:
             carry, nodes, outcomes = run(carry, ev, routing, unified,
                                          cloud, *wx)
-        nodes_out[:, s:e] = np.asarray(nodes)[:lanes, :e - s]
+        nodes_out[:, s:e] = _lanes_np(nodes, placement)[:lanes, :e - s]
         outcomes_out[:, s:e] = np.asarray(outcomes)[:lanes, :e - s]
     out = []
     invals = (np.asarray(carry[1], np.int64) if failing else None)
@@ -1855,7 +1884,7 @@ def _simulate_cluster_autoscale_ref(
 def _sweep_cluster_autoscale(
         trace: Trace, configs, autoscales, failures=None, rng_seed: int = 0,
         mode: str = "gather", telemetry: int | None = None, chains=None,
-        devices: int | None = None
+        devices: int | None = None, placement: dict | None = None
         ) -> list[tuple[ClusterResult, np.ndarray, dict]]:
     """Vmapped sweep over autoscaled configs.  All configs must share
     ``n_nodes``/``max_slots`` AND all autoscales ``epoch_events`` (the
@@ -1926,7 +1955,7 @@ def _sweep_cluster_autoscale(
                                    chain=ch_on, devices=devices)(*args)
     nodes, outcomes, fracs, actives, invals = outs[:5]
     # pad lanes (if any) are dropped here: only real lane rows are read
-    nodes = (np.asarray(nodes)[:len(configs)]
+    nodes = (_lanes_np(nodes, placement)[:len(configs)]
              .reshape(len(configs), -1)[:, :n_events])
     outcomes = (np.asarray(outcomes)[:len(configs)]
                 .reshape(len(configs), -1)[:, :n_events])

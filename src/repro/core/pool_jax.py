@@ -24,6 +24,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .ieee import ieee_div
 from .registry import (REPLACEMENT, RESIZE, ROUTING, ResizeCtx, SlotStats,
                        replacement_priority, shrink_amounts)
 from .types import DROP, HIT, MISS, Policy, PoolConfig
@@ -120,8 +121,27 @@ def _priority(p: PoolState) -> jax.Array:
     return replacement_priority(jnp, p.policy, stats)
 
 
+def put(x: jax.Array, i: jax.Array, v) -> jax.Array:
+    """``x.at[i].set(v)`` along the last axis, written as a select: ``i``
+    is one index (``x`` of shape ``[S]``) or one index per row (``x`` of
+    shape ``[P, S]``, ``v`` scalar or ``[P]``).  The scan step updates
+    slots this way and never by scatter: under a wide ``vmap`` XLA:TPU
+    lost bool scatter updates (on a v5e at 1,024 sweep lanes, a newly
+    placed slot's ``valid`` bit stayed False in some lanes)."""
+    hit = jnp.arange(x.shape[-1]) == jnp.expand_dims(i, -1)
+    return jnp.where(hit, jnp.expand_dims(v, -1) if jnp.ndim(v) else v, x)
+
+
+def _unpermute(order: jax.Array, vals: jax.Array) -> jax.Array:
+    """The bools ``vals``, given in permuted order ``order``, back in slot
+    order.  The scatter runs on int32: XLA:TPU loses bool scatter updates
+    under a wide ``vmap`` (see :func:`put`)."""
+    return jnp.zeros(order.shape, jnp.int32).at[order].set(
+        vals.astype(jnp.int32)) != 0
+
+
 def _gd(clock, freq, cold_cost, size):
-    return clock + freq * cold_cost / jnp.maximum(size, 1e-6)
+    return clock + ieee_div(freq * cold_cost, jnp.maximum(size, 1e-6))
 
 
 def _evict_prefix(p: PoolState, idle: jax.Array, deficit: jax.Array,
@@ -143,7 +163,7 @@ def _evict_prefix(p: PoolState, idle: jax.Array, deficit: jax.Array,
     sz_ord = jnp.where(idle[order], sz[order], 0.0)
     freed_before = jnp.cumsum(sz_ord) - sz_ord
     evict_ord = idle[order] & (freed_before < deficit - 1e-9)
-    evict = jnp.zeros_like(p.valid).at[order].set(evict_ord)
+    evict = _unpermute(order, evict_ord)
     freed = jnp.sum(jnp.where(evict, sz, 0.0))
     return evict, freed
 
@@ -184,11 +204,11 @@ def pool_step(p: PoolState, ev: Event) -> tuple[PoolState, jax.Array]:
                          < p.size[hit_slot]).astype(jnp.int32),
     )
     hit_state = p._replace(
-        last_use=p.last_use.at[hit_slot].set(ev.t),
-        freq=p.freq.at[hit_slot].set(new_freq),
-        gd_pri=p.gd_pri.at[hit_slot].set(
-            _gd(p.clock, new_freq, cold_cost, p.size[hit_slot])),
-        busy_until=p.busy_until.at[hit_slot].set(ev.t + ev.warm),
+        last_use=put(p.last_use, hit_slot, ev.t),
+        freq=put(p.freq, hit_slot, new_freq),
+        gd_pri=put(p.gd_pri, hit_slot,
+                   _gd(p.clock, new_freq, cold_cost, p.size[hit_slot])),
+        busy_until=put(p.busy_until, hit_slot, ev.t + ev.warm),
         **hit_extra,
     )
 
@@ -220,20 +240,20 @@ def pool_step(p: PoolState, ev: Event) -> tuple[PoolState, jax.Array]:
         jnp.maximum(p.clock, jnp.max(jnp.where(evict, p.gd_pri, -_INF))),
         p.clock)
     miss_extra = {} if not rz else dict(
-        alloc=jnp.where(evict, 0.0, alloc1).at[ins].set(ev.size),
-        used=jnp.where(evict, 0.0, p.used).at[ins].set(ev.used),
+        alloc=put(jnp.where(evict, 0.0, alloc1), ins, ev.size),
+        used=put(jnp.where(evict, 0.0, p.used), ins, ev.used),
         acc_used=p.acc_used + ev.used,
         acc_alloc=p.acc_alloc + ev.size,
     )
     miss_state = p._replace(
-        func_id=p.func_id.at[ins].set(ev.func_id),
-        size=p.size.at[ins].set(ev.size),
-        last_use=p.last_use.at[ins].set(ev.t),
-        freq=p.freq.at[ins].set(1.0),
-        gd_pri=p.gd_pri.at[ins].set(_gd(new_clock, 1.0, cold_cost, ev.size)),
-        busy_until=p.busy_until.at[ins].set(ev.t + ev.cold),
-        seq=p.seq.at[ins].set(p.next_seq),
-        valid=valid_after.at[ins].set(True),
+        func_id=put(p.func_id, ins, ev.func_id),
+        size=put(p.size, ins, ev.size),
+        last_use=put(p.last_use, ins, ev.t),
+        freq=put(p.freq, ins, 1.0),
+        gd_pri=put(p.gd_pri, ins, _gd(new_clock, 1.0, cold_cost, ev.size)),
+        busy_until=put(p.busy_until, ins, ev.t + ev.cold),
+        seq=put(p.seq, ins, p.next_seq),
+        valid=put(valid_after, ins, True),
         free=free1 + freed - ev.size,
         clock=new_clock,
         next_seq=p.next_seq + 1.0,
@@ -320,7 +340,7 @@ def _evict_place_lax(pri, seq, size, idle, valid, deficit):
         sz_ord = jnp.where(idle[order], size[order], 0.0)
         freed_before = jnp.cumsum(sz_ord) - sz_ord
         evict_ord = idle[order] & (freed_before < deficit - 1e-9)
-        evict = jnp.zeros_like(valid).at[order].set(evict_ord)
+        evict = _unpermute(order, evict_ord)
         freed = jnp.sum(jnp.where(evict, size, 0.0))
         avail = jnp.sum(jnp.where(idle, size, 0.0))
         valid_after = valid & ~evict
@@ -360,11 +380,11 @@ def pool_step_batch(p: PoolState, ev: Event, evict_place):
                          < p.size[rows, hit_slot]).astype(jnp.int32),
     )
     hit_state = p._replace(
-        last_use=p.last_use.at[rows, hit_slot].set(ev.t),
-        freq=p.freq.at[rows, hit_slot].set(new_freq),
-        gd_pri=p.gd_pri.at[rows, hit_slot].set(
-            _gd(p.clock, new_freq, cold_cost, p.size[rows, hit_slot])),
-        busy_until=p.busy_until.at[rows, hit_slot].set(ev.t + ev.warm),
+        last_use=put(p.last_use, hit_slot, ev.t),
+        freq=put(p.freq, hit_slot, new_freq),
+        gd_pri=put(p.gd_pri, hit_slot,
+                   _gd(p.clock, new_freq, cold_cost, p.size[rows, hit_slot])),
+        busy_until=put(p.busy_until, hit_slot, ev.t + ev.warm),
         **hit_extra,
     )
 
@@ -400,21 +420,20 @@ def pool_step_batch(p: PoolState, ev: Event, evict_place):
         p.clock)
     valid_after = p.valid & ~evict
     miss_extra = {} if not rz else dict(
-        alloc=jnp.where(evict, 0.0, alloc1).at[rows, ins].set(ev.size),
-        used=jnp.where(evict, 0.0, p.used).at[rows, ins].set(ev.used),
+        alloc=put(jnp.where(evict, 0.0, alloc1), ins, ev.size),
+        used=put(jnp.where(evict, 0.0, p.used), ins, ev.used),
         acc_used=p.acc_used + ev.used,
         acc_alloc=p.acc_alloc + ev.size,
     )
     miss_state = p._replace(
-        func_id=p.func_id.at[rows, ins].set(ev.func_id),
-        size=p.size.at[rows, ins].set(ev.size),
-        last_use=p.last_use.at[rows, ins].set(ev.t),
-        freq=p.freq.at[rows, ins].set(1.0),
-        gd_pri=p.gd_pri.at[rows, ins].set(
-            _gd(new_clock, 1.0, cold_cost, ev.size)),
-        busy_until=p.busy_until.at[rows, ins].set(ev.t + ev.cold),
-        seq=p.seq.at[rows, ins].set(p.next_seq),
-        valid=valid_after.at[rows, ins].set(True),
+        func_id=put(p.func_id, ins, ev.func_id),
+        size=put(p.size, ins, ev.size),
+        last_use=put(p.last_use, ins, ev.t),
+        freq=put(p.freq, ins, 1.0),
+        gd_pri=put(p.gd_pri, ins, _gd(new_clock, 1.0, cold_cost, ev.size)),
+        busy_until=put(p.busy_until, ins, ev.t + ev.cold),
+        seq=put(p.seq, ins, p.next_seq),
+        valid=put(valid_after, ins, True),
         free=free1 + freed - ev.size,
         clock=new_clock,
         next_seq=p.next_seq + 1.0,

@@ -36,6 +36,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, NamedTuple
 
+from .ieee import div
+
 
 class RouteCtx(NamedTuple):
     """Inputs available to a routing decision, one invocation at a time.
@@ -244,13 +246,15 @@ def resize_policies() -> list[str]:
 # built-in routing policies (codes 0-3 == the historical RoutingPolicy enum)
 # --------------------------------------------------------------------------
 # All load comparisons are float32 so the numpy oracle and the JAX engine
-# take bit-identical decisions on exact-f32 traces.  Every built-in
+# take bit-identical decisions on exact-f32 traces; policies divide with
+# ``core.ieee.div``, whose JAX quotient rounds like numpy's on every
+# backend (XLA:TPU's own f32 division does not always).  Every built-in
 # respects ``ctx.node_up`` — on an all-up mask each reduces to its
 # historical decision bit-for-bit (the masking selects the unmasked
 # values exactly), so static scenarios are unchanged.
 
 def _free_frac(xp, ctx: RouteCtx):
-    return ctx.free / xp.maximum(ctx.cap, xp.float32(1e-6))
+    return div(xp, ctx.free, xp.maximum(ctx.cap, xp.float32(1e-6)))
 
 
 def _nth_masked(xp, mask, j):
@@ -369,7 +373,8 @@ def _fair_share(xp, ctx: ResizeCtx):
                         xp.maximum(ctx.alloc - floor, xp.float32(0.0)),
                         xp.float32(0.0))
     total = xp.sum(headroom, axis=-1, keepdims=True)
-    ratio = xp.minimum(ctx.deficit / xp.maximum(total, xp.float32(1e-6)),
+    ratio = xp.minimum(div(xp, ctx.deficit,
+                           xp.maximum(total, xp.float32(1e-6))),
                        xp.float32(1.0))
     return ctx.alloc - headroom * ratio
 

@@ -3,7 +3,6 @@ dispatch:
 
 * TPU backend            -> compiled Pallas kernels
 * everything else        -> the pure-jnp oracles in ``ref.py``
-* ``REPRO_FORCE_REF=1``  -> oracles everywhere (escape hatch)
 * ``interpret=True``     -> Pallas interpret mode (CPU kernel validation)
 
 The dry-run lowers on host devices, so it exercises the oracle path; on a
@@ -12,17 +11,12 @@ per-shard shapes (see models/attention.py).
 """
 from __future__ import annotations
 
-import functools
-import os
-
 import jax
 
 from . import ref as _ref
 
 
 def _use_kernels() -> bool:
-    if os.environ.get("REPRO_FORCE_REF", "0") == "1":
-        return False
     return jax.default_backend() == "tpu"
 
 

@@ -11,30 +11,20 @@ single pod = 16 x 16 = 256 chips; multi-pod = 2 pods = 512 chips.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; 0.4.x does not
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-
-
-def _axis_types_kw(n_axes: int) -> dict:
-    """``axis_types=`` kwarg when supported, else nothing (the 0.4.x
-    default is equivalent to all-Auto)."""
-    return {} if AxisType is None else {
-        "axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh on the real local device (CPU tests/examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"), **_axis_types_kw(2))
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # hardware constants (TPU v5e)

@@ -105,9 +105,9 @@ def simulate(scenario: Scenario, trace: Trace, *, engine: str = "jax",
 
     ``mode`` selects the JAX scan-step formulation (|STEP_MODES|, see
     ``repro.cluster.engine.STEP_MODES``; ``"fused"`` runs the Pallas
-    evict-and-place kernel from ``repro.kernels.pool_step`` — compiled on
-    TPU, interpreted bit-identically elsewhere); it is ignored by the
-    reference engine.  ``rng_seed``
+    evict-and-place kernel from ``repro.kernels.pool_step`` — compiled by
+    Mosaic on TPU, interpreted bit-identically elsewhere); it is ignored
+    by the reference engine.  ``rng_seed``
     fixes the cloud cold-start draws (common random numbers: both engines
     and every scenario of a sweep price offloads identically).
 
@@ -212,9 +212,13 @@ def sweep(trace: Trace, scenarios: Iterable[Scenario], *,
     JAX devices with ``shard_map`` (``"all"`` = every visible device,
     ``None`` = the exact pre-sharding single-device programs).  Each
     device runs its shard of the already-vmapped scan, so results are
-    **bit-identical** to the unsharded sweep for any device count; lane
+    **bit-identical** to the unsharded sweep for any device count on CPU
+    meshes and, for 1,024 lanes of the ``benchmarks/giga_sweep.py`` grid,
+    on four TPU v5e chips; lane
     counts that don't divide are padded with no-op duplicate lanes that
-    are sliced off before ``Result`` assembly.  On CPU, set
+    are sliced off before ``Result`` assembly; each result's
+    ``run_info["lane_devices"]`` maps every device to the lane rows (pad
+    lanes included) its shard of the outputs held.  On CPU, set
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` *before the
     first jax import* to turn host cores into a device mesh (see
     ``docs/sweeps.md``).  The reference engine validates and then
@@ -272,16 +276,20 @@ def sweep(trace: Trace, scenarios: Iterable[Scenario], *,
         cfgs = [scenarios[i].to_cluster_config() for i in idxs]
         chs = [plans[i] for i in idxs] if chained else None
         info = {**base_info, "mode": gmode}
+        placement = None
+        if dev:     # sharded groups record the lane rows each device held
+            placement = info["lane_devices"] = {}
         if epoch is None and not failing:
             if chunk is not None:
                 outs = _sweep_cluster_chunked(trace, cfgs, rng_seed=rng_seed,
                                               mode=gmode, chunk_events=chunk,
                                               telemetry=telw, chains=chs,
-                                              devices=dev)
+                                              devices=dev,
+                                              placement=placement)
             else:
                 outs = _sweep_cluster(trace, cfgs, rng_seed=rng_seed,
                                       mode=gmode, telemetry=telw, chains=chs,
-                                      devices=dev)
+                                      devices=dev, placement=placement)
             for i, out in zip(idxs, outs):
                 raw, extras = (out, {}) if telw is None and not chained \
                     and not rz else out
@@ -293,11 +301,12 @@ def sweep(trace: Trace, scenarios: Iterable[Scenario], *,
                 pairs = _sweep_cluster_chunked(
                     trace, cfgs, rng_seed=rng_seed, mode=gmode,
                     chunk_events=chunk, failures=fails, telemetry=telw,
-                    chains=chs, devices=dev)
+                    chains=chs, devices=dev, placement=placement)
             else:
                 pairs = _sweep_cluster_failures(
                     trace, cfgs, fails, rng_seed=rng_seed, mode=gmode,
-                    telemetry=telw, chains=chs, devices=dev)
+                    telemetry=telw, chains=chs, devices=dev,
+                    placement=placement)
             for i, (raw, extras) in zip(idxs, pairs):
                 results[i] = _wrap(scenarios[i], trace, raw, extras, None,
                                    telw, info, plans[i])
@@ -306,7 +315,7 @@ def sweep(trace: Trace, scenarios: Iterable[Scenario], *,
                 trace, cfgs, [scenarios[i].autoscale for i in idxs],
                 [scenarios[i].failures for i in idxs],
                 rng_seed=rng_seed, mode=gmode, telemetry=telw, chains=chs,
-                devices=dev)
+                devices=dev, placement=placement)
             for i, (raw, fracs, extras) in zip(idxs, triples):
                 results[i] = _wrap(scenarios[i], trace, raw, extras, fracs,
                                    telw, info, plans[i])

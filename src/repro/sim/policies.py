@@ -17,6 +17,7 @@ doomed ones.
 """
 from __future__ import annotations
 
+from ..core.ieee import div
 from ..core.registry import ROUTING, RouteCtx, register_routing
 
 
@@ -36,7 +37,7 @@ def cost_model(xp, ctx: RouteCtx):
     Ties (e.g. several idle nodes predicting zero) resolve to the lowest
     node index in both engines (``argmin`` takes the first minimum).
     """
-    frac = ctx.free / xp.maximum(ctx.cap, xp.float32(1e-6))
+    frac = div(xp, ctx.free, xp.maximum(ctx.cap, xp.float32(1e-6)))
     cold_cost = ctx.cold - ctx.warm
     p_cold = xp.float32(1.0) - frac
     edge_pred = p_cold * cold_cost

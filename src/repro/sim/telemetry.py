@@ -343,11 +343,16 @@ def scenario_hash(scenario) -> str:
 
 
 def versions() -> dict:
+    """Software versions, the host OS, and the JAX device the run used."""
     import jax
+    dev = jax.devices()[0]
     return {"python": sys.version.split()[0],
             "jax": jax.__version__,
             "numpy": np.__version__,
-            "platform": platform.platform()}
+            "platform": platform.platform(),
+            "device_platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "device_count": jax.device_count()}
 
 
 def run_manifest(result) -> dict:

@@ -217,3 +217,74 @@ def test_continuum_wrapper_matches_cluster_oracle():
     assert old.edge.hits == new.edge.hits
     assert old.edge.drops == new.edge.drops
     assert np.allclose(old.latencies, new.latencies)
+
+
+def _bool_scatters(jaxpr) -> list[str]:
+    """Primitives of every scatter into a bool array in ``jaxpr`` and its
+    sub-jaxprs (scan, cond and switch bodies)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name.startswith("scatter")
+                and eqn.outvars[0].aval.dtype == np.bool_):
+            found.append(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _bool_scatters(sub)
+    return found
+
+
+@pytest.mark.parametrize("mode", ["gather", "vmap", "fused"])
+@pytest.mark.parametrize("masked", [False, True])
+def test_vmapped_step_updates_bools_by_select(mode, masked):
+    """Under a wide sweep ``vmap`` XLA:TPU lost updates of bool scatters
+    (a v5e at 1,024 lanes left a newly placed slot's ``valid`` bit
+    False), so the per-event step and the chain fold never scatter into
+    a bool array."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.cluster.engine import (ChainXs, _chain_event, _chain_init,
+                                      _make_step, _stack_configs,
+                                      cluster_events)
+    tr = quantized_trace(np.random.default_rng(3), 8)
+    _, n, pools, routing, unified, cloud = _stack_configs(
+        [het4(), het4(RoutingPolicy.LEAST_LOADED)], "test")
+    ev = jax.tree_util.tree_map(lambda a: a[0], cluster_events(tr, n))
+    up = jnp.ones((n,), bool) if masked else None
+
+    def step(p, r, u, c):
+        return _make_step(r, u, c, n, mode)(p, ev, up)
+    jaxpr = jax.make_jaxpr(jax.vmap(step))(pools, routing, unified, cloud)
+    assert _bool_scatters(jaxpr.jaxpr) == []
+
+    chain = jax.tree_util.tree_map(lambda a: jnp.stack([a, a]),
+                                   _chain_init(5))
+    cx = ChainXs(cid=jnp.int32(2), stage=jnp.int32(1), last=jnp.bool_(True))
+
+    def fold(ch, outcome):
+        return _chain_event(ch, cx, jnp.bool_(False), jnp.full((6,), 9.0),
+                            ev, outcome, jnp.asarray([0.1, 0.5], jnp.float32))
+    jaxpr = jax.make_jaxpr(jax.vmap(fold))(chain, jnp.asarray([0, 2]))
+    assert _bool_scatters(jaxpr.jaxpr) == []
+
+
+@pytest.mark.parametrize("shape,idx", [((6,), 4), ((3, 6), [5, 0, 2])])
+@pytest.mark.parametrize("dtype", [np.bool_, np.int32, np.float32])
+def test_put_matches_scatter(shape, idx, dtype):
+    """``pool_jax.put`` (the step's select-based slot update) equals
+    ``x.at[i].set(v)`` along the last axis, per row for 2-D ``x``."""
+    import jax.numpy as jnp
+
+    from repro.core.pool_jax import put
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.integers(0, 2, shape).astype(dtype))
+    i = jnp.asarray(idx, jnp.int32)
+    v = jnp.asarray(rng.integers(2, 5, shape[:-1]).astype(dtype))
+    got = put(x, i, v)
+    if len(shape) == 1:
+        want = x.at[i].set(v)
+    else:
+        want = x.at[jnp.arange(shape[0]), i].set(v)
+    assert got.dtype == x.dtype and bool(jnp.all(got == want))

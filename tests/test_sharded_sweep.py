@@ -212,3 +212,27 @@ def test_run_info_devices_key(trace):
     r = simulate(scens[0], trace)
     assert r.run_info["devices"] is None   # single runs never shard
     assert "devices" in r.manifest()["run"]
+
+
+@pytest.mark.parametrize("devices", DEVICES)
+@pytest.mark.parametrize("flavor", ["static", "chunked", "failures",
+                                    "autoscale"])
+def test_lane_devices_from_output_shards(trace, flavor, devices):
+    """A sharded sweep records where its lanes ran, read from the shards
+    of its outputs: each mesh device holds an equal share of the 5 lanes
+    plus pads.  Unsharded sweeps record nothing."""
+    kw, scens = {}, static_lanes()
+    if flavor == "chunked":
+        kw["chunk_events"] = 256
+    elif flavor == "failures":
+        t0 = float(trace.t[len(trace) // 4])
+        scens = static_lanes(failures=Failures(windows=((t0, t0 + 300.0, 0),)))
+    elif flavor == "autoscale":
+        scens = [Scenario(node_mb=(1024.0, 2048.0), small_frac=float(f),
+                          max_slots=64, autoscale=Autoscale(epoch_events=128))
+                 for f in np.linspace(0.55, 0.85, 5)]
+    assert "lane_devices" not in sweep(trace, scens, **kw)[0].run_info
+    res = sweep(trace, scens, devices=devices, **kw)
+    rows = -(-len(scens) // devices)
+    want = {str(d): rows for d in jax.devices()[:devices]}
+    assert all(r.run_info["lane_devices"] == want for r in res)

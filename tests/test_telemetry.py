@@ -11,6 +11,7 @@ yesterday's behavior bit for bit.
 import dataclasses
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -307,7 +308,10 @@ def test_run_manifest_identity():
                           "chunk_events": 128, "devices": None,
                           "rng_seed": 0}
     assert man["summary"] == res.summary()
-    assert {"python", "jax", "numpy", "platform"} <= set(man["versions"])
+    assert {"python", "jax", "numpy", "platform", "device_platform",
+            "device_kind", "device_count"} <= set(man["versions"])
+    assert man["versions"]["device_platform"] == jax.devices()[0].platform
+    assert man["versions"]["device_count"] == jax.device_count()
     # the manifest is JSON-serializable as-is
     json.dumps(man, default=float)
     # same scenario, same trace -> same identity; different trace differs
