@@ -31,11 +31,9 @@ devices, and checks every 97th lane against the oracle.
 Each phase prints one JSON line; the last line is the verdict
 ``{"ok": true, "device": {...}}``.  The script exits non-zero, with no
 verdict, when JAX finds no TPU, when a phase raises, or when a
-comparison differs.  ``compile_s`` is XLA compile time (from
-``jax.monitoring``), ``execute_s`` the rest of the JAX run's wall time;
-every run ends in host numpy arrays, so the device work is complete.
-Events/s are one unrepeated run each, not a benchmark.  The compile
-cache is set up by ``repro.sim.compile_cache.enable_compile_cache``.
+comparison differs.  It times nothing: the benchmark under ``bench/``
+measures speed.  The compile cache is set up by
+``repro.sim.compile_cache.enable_compile_cache``.
 """
 from __future__ import annotations
 
@@ -44,14 +42,12 @@ import dataclasses
 import json
 import os
 import sys
-import time
 from concurrent.futures import ThreadPoolExecutor
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
 
 import jax  # noqa: E402
-import jax.monitoring  # noqa: E402
 import numpy as np  # noqa: E402
 
 from benchmarks.common import GB, MEMORY_GB, SPLITS  # noqa: E402
@@ -74,53 +70,6 @@ HEAVY_EVENTS = 100_000      # replay prefix of the heavy-carry scenario
 GIGA_LANES = 1024
 PAPER_ORACLE_STRIDE = 5     # every 5th paper-sweep lane against the oracle
 GIGA_ORACLE_STRIDE = 97     # every 97th giga lane against the oracle
-ONE_RUN = "events/s from one unrepeated run, not a benchmark"
-
-_compile = {"s": 0.0, "hits": 0, "misses": 0}
-
-
-def _listen() -> None:
-    """Accumulate compile seconds and persistent-cache hits/misses."""
-    def on_duration(event: str, secs: float, **kw) -> None:
-        if event.startswith("/jax/core/compile"):
-            _compile["s"] += secs
-
-    def on_event(event: str, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            _compile["hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            _compile["misses"] += 1
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    jax.monitoring.register_event_listener(on_event)
-
-
-class _Clock:
-    """Wall, compile and cache counters over the JAX runs of one phase."""
-
-    def __init__(self):
-        self.wall = 0.0
-        self.compile_s = 0.0
-        self.hits = self.misses = 0
-
-    def run(self, fn, *args, **kwargs):
-        c0, h0, m0 = _compile["s"], _compile["hits"], _compile["misses"]
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        self.wall += time.perf_counter() - t0
-        self.compile_s += _compile["s"] - c0
-        self.hits += _compile["hits"] - h0
-        self.misses += _compile["misses"] - m0
-        return out
-
-    def fields(self, events: int | None = None) -> dict:
-        execute_s = max(self.wall - self.compile_s, 0.0)
-        out = {"compile_s": self.compile_s, "execute_s": execute_s,
-               "cache_hits": self.hits, "cache_misses": self.misses}
-        if events:
-            out.update(events_per_s=events / execute_s if execute_s else None,
-                       note=ONE_RUN)
-        return out
 
 
 def _same(a, b) -> bool:
@@ -173,28 +122,19 @@ def device_phase() -> dict:
     return info
 
 
-def _timed(fn, *args, **kwargs):
-    t0 = time.perf_counter()
-    return fn(*args, **kwargs), time.perf_counter() - t0
-
-
 def replay_phase():
     """The Azure-schema day end to end on the chip, against the oracle."""
-    t0 = time.perf_counter()
     tr = trace_from_tables(synthesize_azure_schema(SCHEMA))
-    ingest_s = time.perf_counter() - t0
     kiss = Scenario.cluster(NODE_MB, routing="size_aware", max_slots=256,
                             name="kiss")
-    clock = _Clock()
     with ThreadPoolExecutor(1) as host:
         # the numpy oracle runs on the host while the chip replays
-        oracle = host.submit(_timed, simulate, kiss, tr, engine="ref")
-        got = clock.run(simulate, kiss, tr, chunk_events=CHUNK)
-        ref, oracle_s = oracle.result()
+        oracle = host.submit(simulate, kiss, tr, engine="ref")
+        got = simulate(kiss, tr, chunk_events=CHUNK)
+        ref = oracle.result()
     _check("replay vs oracle", got, ref)
     _report("replay", events=len(tr), lanes=1, mode="gather",
-            chunk_events=CHUNK, ingest_s=ingest_s, **clock.fields(len(tr)),
-            oracle_events=len(tr), oracle_s=oracle_s, oracle="agree")
+            chunk_events=CHUNK, oracle_events=len(tr), oracle="agree")
     return tr, kiss, got
 
 
@@ -204,14 +144,12 @@ def paper_sweep_phase() -> None:
     grid = ([Scenario.kiss(gb * GB, small_frac=f)
              for gb in MEMORY_GB for f in SPLITS]
             + [Scenario.baseline(gb * GB) for gb in MEMORY_GB])
-    clock = _Clock()
-    res = clock.run(sweep, tr, grid)
+    res = sweep(tr, grid)
     lanes = range(0, len(grid), PAPER_ORACLE_STRIDE)
     for i in lanes:
         _check(f"paper sweep lane {grid[i].label}", res[i],
                simulate(grid[i], tr, engine="ref"))
     _report("paper_sweep", events=len(tr), lanes=len(grid),
-            **clock.fields(len(tr) * len(grid)),
             oracle_lanes=len(lanes), oracle="agree")
 
 
@@ -224,8 +162,7 @@ def fused_phase(tr, kiss: Scenario, gathered) -> None:
                                   mode="fused", chunk_events=CHUNK)
     if "tpu_custom_call" not in lowered.as_text():
         raise AssertionError("fused program holds no tpu_custom_call")
-    clock = _Clock()
-    got = clock.run(simulate, kiss, prefix, mode="fused", chunk_events=CHUNK)
+    got = simulate(kiss, prefix, mode="fused", chunk_events=CHUNK)
     n = len(prefix)
     if not (_same(got.node, gathered.node[:n])
             and _same(got.outcome, gathered.outcome[:n])):
@@ -233,21 +170,18 @@ def fused_phase(tr, kiss: Scenario, gathered) -> None:
     _check("fused vs gather prefix", got,
            simulate(kiss, prefix, chunk_events=CHUNK))
     _report("fused", events=n, lanes=1, chunk_events=CHUNK,
-            tpu_custom_call=True, **clock.fields(n), compared_with="gather",
-            oracle="agree")
+            tpu_custom_call=True, compared_with="gather", oracle="agree")
 
     short = tr.head(FUSED_SWEEP_EVENTS)
     lanes = [dataclasses.replace(kiss, small_frac=(f,) * kiss.n_nodes,
                                  name=f"kiss-{f}") for f in (0.7, 0.8, 0.9)]
     lanes.append(dataclasses.replace(kiss, unified=(True,) * kiss.n_nodes,
                                      name="baseline"))
-    clock = _Clock()
-    fused = clock.run(sweep, short, lanes, mode="fused")
+    fused = sweep(short, lanes, mode="fused")
     for s, a, b in zip(lanes, fused, sweep(short, lanes)):
         _check(f"fused sweep lane {s.label}", a, b)
     _report("fused_sweep", events=len(short), lanes=len(lanes),
-            **clock.fields(len(short) * len(lanes)), compared_with="gather",
-            oracle="agree")
+            compared_with="gather", oracle="agree")
 
 
 def heavy_carry_phase(tr) -> None:
@@ -260,22 +194,19 @@ def heavy_carry_phase(tr) -> None:
         autoscale=Autoscale(epoch_events=4096, spawn_drop_frac=0.05,
                             retire_drop_frac=0.001),
         telemetry=4096, resize="fair_share")
-    clock = _Clock()
-    got = clock.run(simulate, heavy, prefix)
+    got = simulate(heavy, prefix)
     _check("heavy carry vs oracle", got,
            simulate(heavy, prefix, engine="ref"))
-    _report("heavy_carry", events=len(prefix), lanes=1,
-            **clock.fields(len(prefix)), oracle="agree")
+    _report("heavy_carry", events=len(prefix), lanes=1, oracle="agree")
 
     ctr = chained_trace(ChainConfig(seed=0))
     chained = Scenario.cluster(NODE_MB, routing="slack_aware", max_slots=256,
                                chains=Chains(slack=2.0), telemetry=1024,
                                name="chains")
-    clock = _Clock()
-    got = clock.run(simulate, chained, ctr)
+    got = simulate(chained, ctr)
     _check("chains vs oracle", got, simulate(chained, ctr, engine="ref"))
-    _report("chains", events=len(ctr), lanes=1, **clock.fields(len(ctr)),
-            chains=len(got.chains), oracle="agree")
+    _report("chains", events=len(ctr), lanes=1, chains=len(got.chains),
+            oracle="agree")
 
 
 def sharded_phase(n_dev: int) -> None:
@@ -284,18 +215,14 @@ def sharded_phase(n_dev: int) -> None:
     sharded sweep read from its outputs' shards."""
     tr = edge_trace(seed=0, duration_s=600.0)
     grid = giga_grid(GIGA_LANES)
-    one, many = _Clock(), _Clock()
-    base = one.run(sweep, tr, grid)
-    got = many.run(sweep, tr, grid, devices=n_dev)
+    base = sweep(tr, grid)
+    got = sweep(tr, grid, devices=n_dev)
     bad = [i for i, (a, b) in enumerate(zip(got, base))
            if not (_same(a.node, b.node) and _same(a.outcome, b.outcome)
                    and a.summary() == b.summary())]
     rows = got[0].run_info["lane_devices"]
     fields = dict(events=len(tr), lanes=len(grid), devices=n_dev,
-                  lanes_per_device=rows,
-                  one_chip=one.fields(len(tr) * len(grid)),
-                  sharded=many.fields(len(tr) * len(grid)),
-                  compared_with="devices=None")
+                  lanes_per_device=rows, compared_with="devices=None")
     if bad:
         # which side the oracle takes, on the first few differing lanes
         judged = {}
@@ -338,7 +265,6 @@ def main(argv=None) -> int:
               f"{jax.device_count()} device(s)", file=sys.stderr)
         return 2
     cache = enable_compile_cache()
-    _listen()
     info = device_phase()
     _report("compile_cache", dir=cache)
     if args.chips > 1:

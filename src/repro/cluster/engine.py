@@ -65,6 +65,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec
 
 from ..core.compat import deprecated
@@ -191,6 +192,7 @@ def init_cluster(cfg: ClusterConfig) -> PoolState:
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *states)
 
 
+@jax.named_scope("step.route")
 def _route(routing: jax.Array, ev: ClusterEvent, free_t: jax.Array,
            cap_t: jax.Array, cloud: jax.Array, node_up: jax.Array,
            chain_slack: jax.Array, chain_stage: jax.Array) -> jax.Array:
@@ -273,6 +275,7 @@ def _tel_init(n_windows: int, n_nodes: int) -> TelAcc:
                   cmiss=jnp.zeros((w,), jnp.int32))
 
 
+@jax.named_scope("step.acc")
 def _tel_event(tel: TelAcc, wi: jax.Array, ev: ClusterEvent,
                outcome: jax.Array, pools: PoolState, n_nodes: int,
                up_cnt: jax.Array, act_cnt: jax.Array,
@@ -382,6 +385,7 @@ def _chain_pre(chain: ChainAcc, cdl: jax.Array, cx: ChainXs):
     return cdl[cx.cid] - chain.lat[cx.cid], cx.stage
 
 
+@jax.named_scope("step.acc")
 def _chain_event(chain: ChainAcc, cx: ChainXs, ccold: jax.Array,
                  cdl: jax.Array, ev: ClusterEvent, outcome: jax.Array,
                  cloud: jax.Array):
@@ -524,27 +528,30 @@ def _make_step(routing: jax.Array, unified: jax.Array, cloud: jax.Array,
         p = node * 2 + tgt[node]
         core_ev = Event(ev.t, ev.func_id, ev.size, ev.cls, ev.warm, ev.cold,
                         ev.used)
-        if mode == "gather":
-            stepped, outcome = pool_step(tree(lambda a: a[p], pools),
-                                         core_ev)
-        else:
-            # step every pool, keep only the routed one: "vmap" batches
-            # the per-pool step, any other mode is a registered step
-            # backend driving the batched pool_step_batch (the "fused"
-            # Pallas kernel being the first)
-            if mode == "vmap":
-                stepped, outs = jax.vmap(pool_step, in_axes=(0, None))(
-                    pools, core_ev)
+        with jax.named_scope("pool.step"):
+            if mode == "gather":
+                stepped, outcome = pool_step(tree(lambda a: a[p], pools),
+                                             core_ev)
             else:
-                stepped, outs = pool_step_batch(pools, core_ev, backend)
-            outcome = outs[p]
+                # step every pool, keep only the routed one: "vmap"
+                # batches the per-pool step, any other mode is a
+                # registered step backend driving the batched
+                # pool_step_batch (the "fused" Pallas kernel being the
+                # first)
+                if mode == "vmap":
+                    stepped, outs = jax.vmap(pool_step, in_axes=(0, None))(
+                        pools, core_ev)
+                else:
+                    stepped, outs = pool_step_batch(pools, core_ev, backend)
+                outcome = outs[p]
         # write the routed pool back by select, not scatter (see
         # ``pool_jax.put``); a request to a down node changes nothing
-        sel = (jnp.arange(2 * n) == p) & ok
-        pools = tree(
-            lambda a, b: jnp.where(
-                sel.reshape((-1,) + (1,) * (a.ndim - 1)), b, a),
-            pools, stepped)
+        with jax.named_scope("step.writeback"):
+            sel = (jnp.arange(2 * n) == p) & ok
+            pools = tree(
+                lambda a, b: jnp.where(
+                    sel.reshape((-1,) + (1,) * (a.ndim - 1)), b, a),
+                pools, stepped)
         outcome = jnp.where(ok, outcome, DROP)
         return pools, (node, outcome)
 
@@ -1082,6 +1089,13 @@ def _mask_grid(mask: np.ndarray, n_events: int, epoch_events: int,
     return jnp.asarray(mask.reshape(n_epochs, e, mask.shape[1]))
 
 
+def _host_nbytes(tree) -> int:
+    """Bytes of the host (numpy) arrays in ``tree``: what a call that
+    takes it uploads to the device."""
+    return sum(a.nbytes for a in jax.tree_util.tree_leaves(tree)
+               if isinstance(a, np.ndarray))
+
+
 def _cloud_vec(cfg: ClusterConfig) -> jnp.ndarray:
     return jnp.asarray([cfg.cloud_rtt_s, cfg.cloud_cold_prob], jnp.float32)
 
@@ -1100,34 +1114,42 @@ def _simulate_cluster_jax(cfg: ClusterConfig, trace: Trace,
     ``"chains"`` per-chain arrays."""
     check_step_mode(mode)
     rz_on = cfg.resize_policy is not None
-    events = cluster_events(trace, cfg.n_nodes, resize=rz_on)
-    cloud_cold = cloud_cold_draws(len(trace), cfg.cloud_cold_prob, rng_seed)
-    args = (init_cluster(cfg), events, jnp.int32(int(cfg.routing)),
-            jnp.asarray(cfg.unified, bool), _cloud_vec(cfg))
-    n_w = None if telemetry is None else _n_windows(len(trace), telemetry)
-    if telemetry is not None or chains is not None:
-        args = args + ((None, None) if telemetry is None else
-                       (_widx(len(trace), telemetry),
-                        _tel_init(n_w, cfg.n_nodes)))
-    if chains is not None:
-        args = args + (_chain_xs(chains), jnp.asarray(cloud_cold),
-                       jnp.asarray(chains.deadline),
-                       _chain_init(chains.n_chains))
-    outs = _run_cluster(*args, n_nodes=cfg.n_nodes, mode=mode)
-    node, outcome = outs[0], outs[1]
-    result = build_result(cfg, trace, np.asarray(node), np.asarray(outcome),
-                          cloud_cold)
-    if telemetry is None and chains is None and not rz_on:
-        return result
-    extras = {}
-    if telemetry is not None:
-        extras["telemetry"] = _tel_np(outs[2], n_w)
-    if chains is not None:
-        extras["chains"] = _chain_np(outs[-2] if rz_on else outs[-1],
-                                     chains.n_chains)
-    if rz_on:
-        extras["vertical"] = _vert_np(outs[-1])
-    return result, extras
+    with TraceAnnotation("sim.prep"):
+        events = cluster_events(trace, cfg.n_nodes, resize=rz_on)
+        cloud_cold = cloud_cold_draws(len(trace), cfg.cloud_cold_prob,
+                                      rng_seed)
+        args = (init_cluster(cfg), events, jnp.int32(int(cfg.routing)),
+                jnp.asarray(cfg.unified, bool), _cloud_vec(cfg))
+        n_w = (None if telemetry is None
+               else _n_windows(len(trace), telemetry))
+        if telemetry is not None or chains is not None:
+            args = args + ((None, None) if telemetry is None else
+                           (_widx(len(trace), telemetry),
+                            _tel_init(n_w, cfg.n_nodes)))
+        if chains is not None:
+            args = args + (_chain_xs(chains), jnp.asarray(cloud_cold),
+                           jnp.asarray(chains.deadline),
+                           _chain_init(chains.n_chains))
+    with TraceAnnotation("sim.dispatch", h2d_bytes=_host_nbytes(args)):
+        outs = _run_cluster(*args, n_nodes=cfg.n_nodes, mode=mode)
+    with TraceAnnotation("sim.wait"):
+        jax.block_until_ready(outs[:2])
+    with TraceAnnotation("sim.fetch",
+                         d2h_bytes=outs[0].nbytes + outs[1].nbytes):
+        node, outcome = np.asarray(outs[0]), np.asarray(outs[1])
+    with TraceAnnotation("sim.result"):
+        result = build_result(cfg, trace, node, outcome, cloud_cold)
+        if telemetry is None and chains is None and not rz_on:
+            return result
+        extras = {}
+        if telemetry is not None:
+            extras["telemetry"] = _tel_np(outs[2], n_w)
+        if chains is not None:
+            extras["chains"] = _chain_np(outs[-2] if rz_on else outs[-1],
+                                         chains.n_chains)
+        if rz_on:
+            extras["vertical"] = _vert_np(outs[-1])
+        return result, extras
 
 
 def _simulate_cluster_ref(cfg: ClusterConfig, trace: Trace,
@@ -1604,68 +1626,79 @@ def _simulate_cluster_chunked_jax(
     chunk = check_chunk_events(chunk_events)
     n, t_len = cfg.n_nodes, len(trace)
     rz_on = cfg.resize_policy is not None
-    ev_np = _host_events(trace, n, resize=rz_on)
-    routing = jnp.int32(int(cfg.routing))
-    unified = jnp.asarray(cfg.unified, bool)
-    cloud = _cloud_vec(cfg)
-    drop = _drop_size(cfg)
     tel_on, ch_on = telemetry is not None, chains is not None
-    n_w = None if not tel_on else _n_windows(t_len, telemetry)
-    cloud_cold = cloud_cold_draws(t_len, cfg.cloud_cold_prob, rng_seed)
-    cxs_np = _chain_xs_np(chains) if ch_on else None
-    cdl = jnp.asarray(chains.deadline) if ch_on else None
-    nodes_out = np.empty(t_len, np.int32)
-    outcomes_out = np.empty(t_len, np.int32)
-    if failures is None:
-        run = _chunk_runner(n, mode)
-        carry = init_cluster(cfg)
-        if tel_on or ch_on:
-            carry = ((carry,) + ((_tel_init(n_w, n),) if tel_on else ())
-                     + ((_chain_init(chains.n_chains),) if ch_on else ()))
-    else:
-        run = _failures_chunk_runner(n, mode)
-        up_full, rec_full = _failure_masks(failures, trace, n)
-        carry = ((init_cluster(cfg), jnp.zeros((n,), jnp.int32))
-                 + ((_tel_init(n_w, n),) if tel_on else ())
-                 + ((_chain_init(chains.n_chains),) if ch_on else ()))
-    for s in range(0, t_len, chunk):
-        e = min(s + chunk, t_len)
-        ev = _chunk_slice(ev_np, s, e, chunk, drop)
-        kw = ({} if not tel_on
-              else {"widx": _chunk_widx(s, e, chunk, telemetry, n_w)})
-        if ch_on:
-            kw.update(cxs=_chunk_chain(cxs_np, chains.n_chains, s, e,
-                                       chunk),
-                      ccold=_chunk_pad(cloud_cold, s, e, chunk, False),
-                      cdl=cdl)
+    with TraceAnnotation("sim.prep"):
+        ev_np = _host_events(trace, n, resize=rz_on)
+        routing = jnp.int32(int(cfg.routing))
+        unified = jnp.asarray(cfg.unified, bool)
+        cloud = _cloud_vec(cfg)
+        drop = _drop_size(cfg)
+        n_w = None if not tel_on else _n_windows(t_len, telemetry)
+        cloud_cold = cloud_cold_draws(t_len, cfg.cloud_cold_prob, rng_seed)
+        cxs_np = _chain_xs_np(chains) if ch_on else None
+        cdl = jnp.asarray(chains.deadline) if ch_on else None
+        nodes_out = np.empty(t_len, np.int32)
+        outcomes_out = np.empty(t_len, np.int32)
         if failures is None:
-            carry, nodes, outcomes = run(carry, ev, routing, unified,
-                                         cloud, **kw)
+            run = _chunk_runner(n, mode)
+            carry = init_cluster(cfg)
+            if tel_on or ch_on:
+                carry = ((carry,) + ((_tel_init(n_w, n),) if tel_on else ())
+                         + ((_chain_init(chains.n_chains),) if ch_on
+                            else ()))
         else:
-            carry, nodes, outcomes = run(
-                carry, ev, jnp.asarray(_chunk_mask(up_full, s, e, chunk,
-                                                   True)),
-                jnp.asarray(_chunk_mask(rec_full, s, e, chunk, False)),
-                routing, unified, cloud, **kw)
-        nodes_out[s:e] = np.asarray(nodes[:e - s])
-        outcomes_out[s:e] = np.asarray(outcomes[:e - s])
-    result = build_result(cfg, trace, nodes_out, outcomes_out, cloud_cold)
-    extras = {}
-    if tel_on:
-        extras["telemetry"] = _tel_np(
-            carry[1 if failures is None else 2], n_w)
-    if ch_on:
-        extras["chains"] = _chain_np(carry[-1], chains.n_chains)
-    if rz_on:
-        # the accumulators ride the threaded carry's pool state, so the
-        # final chunk's pools already hold the whole-trace totals
-        p_end = carry if isinstance(carry, PoolState) else carry[0]
-        extras["vertical"] = _vert_np(_vert_of(p_end)[0])
-    if failures is None:
-        return result if not extras else (result, extras)
-    extras.update(invalidated=np.asarray(carry[1], np.int64),
-                  node_up=up_full)
-    return result, extras
+            run = _failures_chunk_runner(n, mode)
+            up_full, rec_full = _failure_masks(failures, trace, n)
+            carry = ((init_cluster(cfg), jnp.zeros((n,), jnp.int32))
+                     + ((_tel_init(n_w, n),) if tel_on else ())
+                     + ((_chain_init(chains.n_chains),) if ch_on else ()))
+    for i, s in enumerate(range(0, t_len, chunk)):
+        e = min(s + chunk, t_len)
+        with TraceAnnotation("sim.chunk", index=i, events=e - s,
+                             pad=chunk - (e - s)):
+            with TraceAnnotation("sim.slice"):
+                ev = _chunk_slice(ev_np, s, e, chunk, drop)
+                fmask = () if failures is None else (
+                    jnp.asarray(_chunk_mask(up_full, s, e, chunk, True)),
+                    jnp.asarray(_chunk_mask(rec_full, s, e, chunk, False)))
+                kw = ({} if not tel_on
+                      else {"widx": _chunk_widx(s, e, chunk, telemetry,
+                                                n_w)})
+                if ch_on:
+                    kw.update(cxs=_chunk_chain(cxs_np, chains.n_chains, s,
+                                               e, chunk),
+                              ccold=_chunk_pad(cloud_cold, s, e, chunk,
+                                               False),
+                              cdl=cdl)
+            with TraceAnnotation("sim.dispatch",
+                                 h2d_bytes=_host_nbytes((ev, kw))):
+                carry, nodes, outcomes = run(carry, ev, *fmask, routing,
+                                             unified, cloud, **kw)
+            with TraceAnnotation("sim.wait"):
+                jax.block_until_ready((nodes, outcomes))
+            with TraceAnnotation("sim.fetch",
+                                 d2h_bytes=2 * nodes_out[s:e].nbytes):
+                nodes_out[s:e] = np.asarray(nodes[:e - s])
+                outcomes_out[s:e] = np.asarray(outcomes[:e - s])
+    with TraceAnnotation("sim.result"):
+        result = build_result(cfg, trace, nodes_out, outcomes_out,
+                              cloud_cold)
+        extras = {}
+        if tel_on:
+            extras["telemetry"] = _tel_np(
+                carry[1 if failures is None else 2], n_w)
+        if ch_on:
+            extras["chains"] = _chain_np(carry[-1], chains.n_chains)
+        if rz_on:
+            # the accumulators ride the threaded carry's pool state, so
+            # the final chunk's pools already hold the whole-trace totals
+            p_end = carry if isinstance(carry, PoolState) else carry[0]
+            extras["vertical"] = _vert_np(_vert_of(p_end)[0])
+        if failures is None:
+            return result if not extras else (result, extras)
+        extras.update(invalidated=np.asarray(carry[1], np.int64),
+                      node_up=up_full)
+        return result, extras
 
 
 def lower_chunk_program(cfg: ClusterConfig, trace: Trace,
@@ -1698,111 +1731,120 @@ def _sweep_cluster_chunked(trace: Trace, configs, rng_seed: int = 0,
     check_step_mode(mode)
     chunk = check_chunk_events(chunk_events)
     devices = check_devices(devices)
-    failing = failures is not None
-    telw = telemetry
-    tel_on, ch_on = telw is not None, chains is not None
-    configs, n, pools, routing, unified, cloud = _stack_configs(
-        configs, "chunked sweep")
-    rz_on = configs[0].resize_policy is not None
-    t_len, lanes = len(trace), len(configs)
-    pad = _lane_pad(lanes, devices)
-    lanes_p = lanes + pad
-    pools = _pad_tree(pools, pad)
-    routing, unified, cloud = (_pad_tree(a, pad)
-                               for a in (routing, unified, cloud))
-    ev_np = _host_events(trace, n, resize=rz_on)
-    drop = max(_drop_size(c) for c in configs)
-    n_w = None if telw is None else _n_windows(t_len, telw)
-    clouds = plan = cxs_np = cdl = None
-    if ch_on:
-        plan, clouds, _ = _sweep_chain_data(chains, configs, t_len,
-                                            rng_seed)
-        cxs_np = _chain_xs_np(plan)
-        cdl = _pad_tree(
-            jnp.asarray(np.stack([p.deadline for p in list(chains)])), pad)
-        clouds_p = clouds + clouds[:1] * pad
-    nodes_out = np.empty((lanes, t_len), np.int32)
-    outcomes_out = np.empty((lanes, t_len), np.int32)
-    if failing:
-        failures = list(failures)
-        if len(failures) != lanes:
-            raise ValueError("chunked failure sweep: need one Failures "
-                             "(or None) per config")
-        masks = [_failure_masks(f, trace, n) for f in failures]
-        up_full = np.stack([m[0] for m in masks])       # [L, T, N]
-        rec_full = np.stack([m[1] for m in masks])
-        if pad:
-            up_p = np.concatenate([up_full,
-                                   np.repeat(up_full[:1], pad, axis=0)])
-            rec_p = np.concatenate([rec_full,
-                                    np.repeat(rec_full[:1], pad, axis=0)])
-        else:
-            up_p, rec_p = up_full, rec_full
-        run = _sweep_failures_chunk_runner(n, mode, tel=tel_on,
-                                           chain=ch_on, devices=devices)
-        carry = (pools, jnp.zeros((lanes_p, n), jnp.int32))
-        if tel_on:
-            carry = carry + (_stack_tel(n_w, n, lanes_p),)
+    with TraceAnnotation("sim.prep"):
+        failing = failures is not None
+        telw = telemetry
+        tel_on, ch_on = telw is not None, chains is not None
+        configs, n, pools, routing, unified, cloud = _stack_configs(
+            configs, "chunked sweep")
+        rz_on = configs[0].resize_policy is not None
+        t_len, lanes = len(trace), len(configs)
+        pad = _lane_pad(lanes, devices)
+        lanes_p = lanes + pad
+        pools = _pad_tree(pools, pad)
+        routing, unified, cloud = (_pad_tree(a, pad)
+                                   for a in (routing, unified, cloud))
+        ev_np = _host_events(trace, n, resize=rz_on)
+        drop = max(_drop_size(c) for c in configs)
+        n_w = None if telw is None else _n_windows(t_len, telw)
+        clouds = plan = cxs_np = cdl = None
         if ch_on:
-            carry = carry + (_stack_chain(plan.n_chains, lanes_p),)
-    else:
-        run = _sweep_chunk_runner(n, mode, tel=tel_on, chain=ch_on,
-                                  devices=devices)
-        if tel_on or ch_on:
-            carry = ((pools,)
-                     + ((_stack_tel(n_w, n, lanes_p),) if tel_on else ())
-                     + ((_stack_chain(plan.n_chains, lanes_p),)
-                        if ch_on else ()))
+            plan, clouds, _ = _sweep_chain_data(chains, configs, t_len,
+                                                rng_seed)
+            cxs_np = _chain_xs_np(plan)
+            cdl = _pad_tree(jnp.asarray(
+                np.stack([p.deadline for p in list(chains)])), pad)
+            clouds_p = clouds + clouds[:1] * pad
+        nodes_out = np.empty((lanes, t_len), np.int32)
+        outcomes_out = np.empty((lanes, t_len), np.int32)
+        if failing:
+            failures = list(failures)
+            if len(failures) != lanes:
+                raise ValueError("chunked failure sweep: need one Failures "
+                                 "(or None) per config")
+            masks = [_failure_masks(f, trace, n) for f in failures]
+            up_full = np.stack([m[0] for m in masks])       # [L, T, N]
+            rec_full = np.stack([m[1] for m in masks])
+            if pad:
+                up_p = np.concatenate(
+                    [up_full, np.repeat(up_full[:1], pad, axis=0)])
+                rec_p = np.concatenate(
+                    [rec_full, np.repeat(rec_full[:1], pad, axis=0)])
+            else:
+                up_p, rec_p = up_full, rec_full
+            run = _sweep_failures_chunk_runner(n, mode, tel=tel_on,
+                                               chain=ch_on, devices=devices)
+            carry = (pools, jnp.zeros((lanes_p, n), jnp.int32))
+            if tel_on:
+                carry = carry + (_stack_tel(n_w, n, lanes_p),)
+            if ch_on:
+                carry = carry + (_stack_chain(plan.n_chains, lanes_p),)
         else:
-            carry = pools
-    for s in range(0, t_len, chunk):
+            run = _sweep_chunk_runner(n, mode, tel=tel_on, chain=ch_on,
+                                      devices=devices)
+            if tel_on or ch_on:
+                carry = ((pools,)
+                         + ((_stack_tel(n_w, n, lanes_p),) if tel_on else ())
+                         + ((_stack_chain(plan.n_chains, lanes_p),)
+                            if ch_on else ()))
+            else:
+                carry = pools
+    for i, s in enumerate(range(0, t_len, chunk)):
         e = min(s + chunk, t_len)
-        ev = _chunk_slice(ev_np, s, e, chunk, drop)
-        wx = ()
-        if tel_on or ch_on:
-            wx += (None if telw is None
-                   else _chunk_widx(s, e, chunk, telw, n_w),)
-        if ch_on:
-            wx += (_chunk_chain(cxs_np, plan.n_chains, s, e, chunk),
-                   jnp.stack([_chunk_pad(cc, s, e, chunk, False)
-                              for cc in clouds_p]), cdl)
-        if failing:
-            carry, nodes, outcomes = run(
-                carry, ev,
-                jnp.asarray(_chunk_mask(up_p, s, e, chunk, True, axis=1)),
-                jnp.asarray(_chunk_mask(rec_p, s, e, chunk, False,
-                                        axis=1)),
-                routing, unified, cloud, *wx)
-        else:
-            carry, nodes, outcomes = run(carry, ev, routing, unified,
-                                         cloud, *wx)
-        nodes_out[:, s:e] = _lanes_np(nodes, placement)[:lanes, :e - s]
-        outcomes_out[:, s:e] = np.asarray(outcomes)[:lanes, :e - s]
-    out = []
-    invals = (np.asarray(carry[1], np.int64) if failing else None)
-    tels = None
-    if tel_on:
-        tels = carry[2] if failing else carry[1]
-    chs = carry[-1] if ch_on else None
-    p_end = carry if isinstance(carry, PoolState) else carry[0]
-    for g, c in enumerate(configs):
-        cc = (clouds[g] if ch_on
-              else cloud_cold_draws(t_len, c.cloud_cold_prob, rng_seed))
-        res = build_result(c, trace, nodes_out[g], outcomes_out[g], cc)
-        extras = {}
+        with TraceAnnotation("sim.chunk", index=i, events=e - s,
+                             pad=chunk - (e - s)):
+            with TraceAnnotation("sim.slice"):
+                ev = _chunk_slice(ev_np, s, e, chunk, drop)
+                fmask = () if not failing else (
+                    jnp.asarray(_chunk_mask(up_p, s, e, chunk, True,
+                                            axis=1)),
+                    jnp.asarray(_chunk_mask(rec_p, s, e, chunk, False,
+                                            axis=1)))
+                wx = ()
+                if tel_on or ch_on:
+                    wx += (None if telw is None
+                           else _chunk_widx(s, e, chunk, telw, n_w),)
+                if ch_on:
+                    wx += (_chunk_chain(cxs_np, plan.n_chains, s, e, chunk),
+                           jnp.stack([_chunk_pad(cc, s, e, chunk, False)
+                                      for cc in clouds_p]), cdl)
+            with TraceAnnotation("sim.dispatch",
+                                 h2d_bytes=_host_nbytes((ev, wx))):
+                carry, nodes, outcomes = run(carry, ev, *fmask, routing,
+                                             unified, cloud, *wx)
+            with TraceAnnotation("sim.wait"):
+                jax.block_until_ready((nodes, outcomes))
+            with TraceAnnotation("sim.fetch",
+                                 d2h_bytes=nodes.nbytes + outcomes.nbytes):
+                nodes_out[:, s:e] = _lanes_np(nodes, placement)[:lanes,
+                                                                :e - s]
+                outcomes_out[:, s:e] = np.asarray(outcomes)[:lanes, :e - s]
+    with TraceAnnotation("sim.result"):
+        out = []
+        invals = (np.asarray(carry[1], np.int64) if failing else None)
+        tels = None
         if tel_on:
-            lane = jax.tree_util.tree_map(lambda a: a[g], tels)
-            extras["telemetry"] = _tel_np(lane, n_w)
-        if ch_on:
-            lane = jax.tree_util.tree_map(lambda a: a[g], chs)
-            extras["chains"] = _chain_np(lane, plan.n_chains)
-        if rz_on:
-            extras["vertical"] = _vert_np(
-                tuple(np.asarray(a)[g] for a in _vert_of(p_end)[0]))
-        if failing:
-            extras.update(invalidated=invals[g], node_up=up_full[g])
-        out.append((res, extras) if extras else res)
-    return out
+            tels = carry[2] if failing else carry[1]
+        chs = carry[-1] if ch_on else None
+        p_end = carry if isinstance(carry, PoolState) else carry[0]
+        for g, c in enumerate(configs):
+            cc = (clouds[g] if ch_on
+                  else cloud_cold_draws(t_len, c.cloud_cold_prob, rng_seed))
+            res = build_result(c, trace, nodes_out[g], outcomes_out[g], cc)
+            extras = {}
+            if tel_on:
+                lane = jax.tree_util.tree_map(lambda a: a[g], tels)
+                extras["telemetry"] = _tel_np(lane, n_w)
+            if ch_on:
+                lane = jax.tree_util.tree_map(lambda a: a[g], chs)
+                extras["chains"] = _chain_np(lane, plan.n_chains)
+            if rz_on:
+                extras["vertical"] = _vert_np(
+                    tuple(np.asarray(a)[g] for a in _vert_of(p_end)[0]))
+            if failing:
+                extras.update(invalidated=invals[g], node_up=up_full[g])
+            out.append((res, extras) if extras else res)
+        return out
 
 
 def _autoscale_extras(actives, inval, up, failures) -> dict:

@@ -220,7 +220,8 @@ def pool_step(p: PoolState, ev: Event) -> tuple[PoolState, jax.Array]:
     else:
         alloc1, free1 = None, p.free
     deficit = ev.size - free1
-    evict, freed = _evict_prefix(p, idle, deficit, alloc1)
+    with jax.named_scope("pool.evict"):
+        evict, freed = _evict_prefix(p, idle, deficit, alloc1)
     total_evictable = jnp.sum(
         jnp.where(idle, p.size if alloc1 is None else alloc1, 0.0))
 
@@ -400,14 +401,15 @@ def pool_step_batch(p: PoolState, ev: Event, evict_place):
     else:
         alloc1, free1 = None, p.free
     deficit = ev.size - free1                        # [P]
-    stats = SlotStats(last_use=p.last_use, freq=p.freq, gd_pri=p.gd_pri,
-                      size=p.size, busy_until=p.busy_until)
-    pri = jnp.where(idle,
-                    replacement_priority(jnp, p.policy[:, None], stats),
-                    _INF)
-    evict, freed, ins, avail, empty_exists = evict_place(
-        pri, p.seq, p.size if alloc1 is None else alloc1, idle, p.valid,
-        deficit)
+    with jax.named_scope("pool.evict"):
+        stats = SlotStats(last_use=p.last_use, freq=p.freq, gd_pri=p.gd_pri,
+                          size=p.size, busy_until=p.busy_until)
+        pri = jnp.where(idle,
+                        replacement_priority(jnp, p.policy[:, None], stats),
+                        _INF)
+        evict, freed, ins, avail, empty_exists = evict_place(
+            pri, p.seq, p.size if alloc1 is None else alloc1, idle,
+            p.valid, deficit)
 
     can_place = ((ev.size <= p.capacity + 1e-9)
                  & (avail >= deficit - 1e-9)

@@ -16,6 +16,7 @@ from __future__ import annotations
 from typing import Iterable, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..cluster.engine import (STEP_MODES, _simulate_cluster_autoscale_jax,
                               _simulate_cluster_autoscale_ref,
@@ -71,6 +72,13 @@ def _chain_plan(scenario: Scenario, trace: Trace):
             "(Trace.chain_id/stage/chain_len set) — e.g. "
             "repro.workloads.chained_trace")
     return scenario.chains.compile(trace)
+
+
+def _fingerprint(trace: Trace) -> str:
+    """``trace_fingerprint`` under its span, which counts the bytes hashed."""
+    n = sum(np.asarray(a).nbytes for a in trace if a is not None)
+    with TraceAnnotation("sim.fingerprint", bytes=n):
+        return trace_fingerprint(trace)
 
 
 def _wrap(scenario: Scenario, trace: Trace, raw, extras: dict,
@@ -134,6 +142,14 @@ def simulate(scenario: Scenario, trace: Trace, *, engine: str = "jax",
     _check_engine(engine)
     check_step_mode(mode)
     chunk = _check_chunkable(scenario, chunk_events)
+    chunks = -(-len(trace) // chunk) if chunk and engine == "jax" else 0
+    with TraceAnnotation("sim.simulate", events=len(trace), chunks=chunks):
+        return _simulate(scenario, trace, engine, mode, rng_seed, chunk)
+
+
+def _simulate(scenario: Scenario, trace: Trace, engine: str, mode: str,
+              rng_seed: int, chunk: int | None) -> Result:
+    """The body of :func:`simulate`, inside its ``sim.simulate`` span."""
     cfg = scenario.to_cluster_config()
     asc, fails = scenario.autoscale, scenario.failures
     telw = _telw(scenario)
@@ -143,7 +159,7 @@ def simulate(scenario: Scenario, trace: Trace, *, engine: str = "jax",
             "chunk_events": chunk if engine == "jax" else None,
             "devices": None,   # single runs are never sharded
             "rng_seed": rng_seed,
-            "trace_fingerprint": trace_fingerprint(trace)}
+            "trace_fingerprint": _fingerprint(trace)}
     fracs = None
     rz_on = scenario.resize is not None
     bare = fails is None and telw is None and plan is None and not rz_on
@@ -247,6 +263,15 @@ def sweep(trace: Trace, scenarios: Iterable[Scenario], *,
         # anyway (the chunk_events precedent)
         return [simulate(s, trace, engine="ref", rng_seed=rng_seed)
                 for s in scenarios]
+    chunks = -(-len(trace) // chunk) if chunk else 0
+    with TraceAnnotation("sim.sweep", events=len(trace),
+                         lanes=len(scenarios), chunks=chunks):
+        return _sweep(trace, scenarios, modes, rng_seed, chunk, dev)
+
+
+def _sweep(trace: Trace, scenarios: list, modes: list, rng_seed: int,
+           chunk: int | None, dev: int | None) -> list[Result]:
+    """The JAX body of :func:`sweep`, inside its ``sim.sweep`` span."""
     plans = [_chain_plan(s, trace) for s in scenarios]
     groups: dict[tuple[int, int, int | None, bool, int | None, bool, bool,
                        str], list[int]] = {}
@@ -268,9 +293,9 @@ def sweep(trace: Trace, scenarios: Iterable[Scenario], *,
              plans[i] is not None, s.resize is not None, modes[i]),
             []).append(i)
     results: list[Result | None] = [None] * len(scenarios)
-    base_info = {"engine": engine, "chunk_events": chunk,
+    base_info = {"engine": "jax", "chunk_events": chunk,
                  "devices": dev, "rng_seed": rng_seed,
-                 "trace_fingerprint": trace_fingerprint(trace)}
+                 "trace_fingerprint": _fingerprint(trace)}
     for ((_, _, epoch, failing, telw, chained, rz, gmode),
          idxs) in groups.items():
         cfgs = [scenarios[i].to_cluster_config() for i in idxs]
