@@ -6,8 +6,9 @@ configurations (split ratios x policies x pool sizes) sweep in one ``vmap``
 (see ``simulator_jax.py``).  Semantics are bit-compatible with the sequential
 oracle in ``pool_ref.py`` (property-tested):
 
-* greedy eviction in (priority, launch-seq) order == sort + prefix-sum over
-  freed bytes, evicting the minimal prefix that covers the deficit;
+* greedy eviction in (priority, launch-seq) order == one keyed sort +
+  prefix-sum over freed bytes, evicting the minimal prefix that covers the
+  deficit;
 * busy containers are never evicted;
 * GreedyDual clock inflates to the max evicted priority.
 
@@ -144,26 +145,56 @@ def _gd(clock, freq, cold_cost, size):
     return clock + ieee_div(freq * cold_cost, jnp.maximum(size, 1e-6))
 
 
+def _sort_key(x: jax.Array) -> jax.Array:
+    """f32 -> i32 keys whose signed order is the order ``lax.sort`` puts
+    floats in: ``-0.0`` as ``+0.0`` and every NaN as one NaN, above
+    ``+inf``.  Plain float ``<``/``==`` would not order NaNs at all."""
+    x = jnp.where(x == 0, 0.0, x)
+    x = jnp.where(jnp.isnan(x), jnp.nan, x)
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return b ^ ((b >> 31) & 0x7FFFFFFF)
+
+
 def _evict_prefix(p: PoolState, idle: jax.Array, deficit: jax.Array,
                   bytes_per_slot: jax.Array | None = None):
     """The minimal ``(priority, seq)``-ordered prefix of idle slots whose
-    eviction covers ``deficit``: greedy eviction == sort + prefix-sum over
-    freed bytes.  Returns ``(evict bool[S], freed f32)``.  Shared by the
-    miss path of ``pool_step`` and by ``pool_resize`` — JAX<->oracle
-    bit-equivalence depends on both sites evicting in the identical
-    order.  ``bytes_per_slot`` is what an eviction actually frees (the
-    post-shrink ``alloc`` when resize is on; defaults to ``size``) — the
-    eviction *order* never depends on it."""
+    eviction covers ``deficit``: greedy eviction == one keyed sort +
+    prefix-sum over freed bytes.  Returns ``(evict bool[S], freed f32)``.
+    Shared by the miss path of ``pool_step`` and by ``pool_resize`` —
+    JAX<->oracle bit-equivalence depends on both sites evicting in the
+    identical order.  ``bytes_per_slot`` is what an eviction actually
+    frees (the post-shrink ``alloc`` when resize is on; defaults to
+    ``size``) — the eviction *order* never depends on it.
+
+    One stable sort on the keys ``(priority, seq, slot)`` carries the idle
+    mask and the freed bytes along, so nothing is gathered into sorted
+    order; the prefix goes back to slot order by comparing each slot's
+    key with the key at its last position, so nothing is scattered back
+    either (on the TPU a dynamic gather or scatter over the slots costs
+    more than the sort).  Bitwise equal to the two stable argsorts of
+    ``_evict_place_lax``, NaN and ``-0.0`` priorities included."""
     sz = p.size if bytes_per_slot is None else bytes_per_slot
     pri = jnp.where(idle, _priority(p), _INF)       # only idle are evictable
-    # order slots by (priority, seq): stable argsort of priority over a
-    # seq-sorted permutation.
-    by_seq = jnp.argsort(p.seq, stable=True)
-    order = by_seq[jnp.argsort(pri[by_seq], stable=True)]
-    sz_ord = jnp.where(idle[order], sz[order], 0.0)
-    freed_before = jnp.cumsum(sz_ord) - sz_ord
-    evict_ord = idle[order] & (freed_before < deficit - 1e-9)
-    evict = _unpermute(order, evict_ord)
+    slot = jnp.arange(pri.shape[-1], dtype=jnp.int32)
+    kp, ks = _sort_key(pri), _sort_key(p.seq)
+    kp_o, ks_o, slot_o, idle_o, sz_o = jax.lax.sort(
+        (kp, ks, slot, idle, jnp.where(idle, sz, 0.0)),
+        num_keys=3, is_stable=True)
+    freed_before = jnp.cumsum(sz_o) - sz_o
+    evict_ord = idle_o & (freed_before < deficit - 1e-9)
+    # freed_before never falls along the sorted order, so the evicted
+    # idle slots are those ranked up to the last evicted position; read
+    # its key by a one-hot reduction (no dynamic index)
+    last = jnp.max(jnp.where(evict_ord, slot, -1))
+    at = slot == last
+
+    def key_at(k):
+        return jnp.max(jnp.where(at, k, jnp.iinfo(jnp.int32).min))
+
+    lp, ls, lslot = key_at(kp_o), key_at(ks_o), key_at(slot_o)
+    ranked = (kp < lp) | ((kp == lp) & ((ks < ls) | ((ks == ls)
+                                                    & (slot <= lslot))))
+    evict = idle & (last >= 0) & ranked
     freed = jnp.sum(jnp.where(evict, sz, 0.0))
     return evict, freed
 
@@ -332,9 +363,12 @@ def get_step_backend(name: str):
 
 @register_step_backend("lax")
 def _evict_place_lax(pri, seq, size, idle, valid, deficit):
-    """Reference backend: the exact ``_evict_prefix`` argsort composite,
-    vmapped over the pool axis.  This is the jaxpr the fused kernel is
-    priced against in ``benchmarks/pool_step.py``."""
+    """Reference backend: the former ``_evict_prefix`` formulation (two
+    stable argsorts, gathers into sorted order and a scatter back),
+    vmapped over the pool axis.  No engine path runs it by default: it is
+    kept as the reference that ``_evict_prefix`` and the fused kernel are
+    tested against bit for bit, and the jaxpr the fused kernel is priced
+    against in ``benchmarks/pool_step.py``."""
     def one(pri, seq, size, idle, valid, deficit):
         by_seq = jnp.argsort(seq, stable=True)
         order = by_seq[jnp.argsort(pri[by_seq], stable=True)]

@@ -6,7 +6,8 @@ The acceptance bar of the step-backend layer: ``mode="fused"`` must be
 across every registered routing x replacement policy, all three scan
 shapes (static, failure-injected, autoscaled), chunked scans, and mixed
 fused/vmap sweep lanes.  Plus interpret-mode unit tests of the kernel's
-rank-by-counting against ``_evict_prefix``'s argsort order, and the
+rank-by-counting against the argsort order of ``_evict_place_lax`` and
+against ``_evict_prefix``'s keyed sort, and the
 pinned GreedyDual no-eviction clock regression.
 """
 import jax
@@ -66,7 +67,7 @@ def _random_batch(rng, p=8, s=24):
 
 
 def test_rank_by_counting_matches_argsort_on_ties():
-    """The kernel ranks by counting; ``_evict_prefix`` double-argsorts.
+    """The kernel ranks by counting; ``_evict_place_lax`` double-argsorts.
     With heavy priority ties the (priority, seq) tie-break must still
     produce the identical evict set, bit for bit."""
     for seed in range(5):
@@ -80,7 +81,7 @@ def test_rank_by_counting_matches_argsort_on_ties():
 
 def test_kernel_matches_evict_prefix_per_pool():
     """Same thing one pool at a time, against ``_evict_prefix`` itself
-    (the semantics-of-record composite on a real ``PoolState``)."""
+    (the keyed sort the engines run, on a real ``PoolState``)."""
     rng = np.random.default_rng(42)
     p = init_pool(PoolConfig(2048.0, Policy.LRU, 16))
     # warm the pool with a few inserts so seq/valid are realistic

@@ -13,7 +13,9 @@ module is imported: only one process at a time may load the TPU library,
 and every test worker imports every test file.  All of these tests live
 in this one file so that one worker loads it.
 """
+import collections
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,19 +95,16 @@ def test_fused_kernel_compiles_under_vmap(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_gather_chunk_program_compiles(one_chip):
-    """The default-mode replay program: one 65536-event chunk on the
-    4-node replay cluster (``benchmarks/replay.py``)."""
-    chunk = 65536
-    scn = Scenario.cluster((2048.0, 2048.0, 4096.0, 8192.0),
-                           routing="size_aware", max_slots=256)
+def _lower_chunk(sharding, scn: Scenario, chunk: int):
+    """The ``gather``-mode chunk program of ``scn`` for ``chunk`` events,
+    lowered for ``sharding``'s device (the default device if ``None``)."""
     cfg = scn.to_cluster_config()
 
     def sds(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
     def col(dtype):
-        return jax.ShapeDtypeStruct((chunk,), dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct((chunk,), dtype, sharding=sharding)
 
     f32, i32 = jnp.float32, jnp.int32
     pools = jax.tree_util.tree_map(
@@ -113,9 +112,57 @@ def test_gather_chunk_program_compiles(one_chip):
     events = ClusterEvent(t=col(f32), func_id=col(i32), size=col(f32),
                           cls=col(i32), warm=col(f32), cold=col(f32),
                           h1=col(i32), h2=col(i32))
-    compiled = _chunk_runner(cfg.n_nodes, "gather").lower(
+    return _chunk_runner(cfg.n_nodes, "gather").lower(
         pools, events, sds(jax.ShapeDtypeStruct((), i32)),
         sds(jax.ShapeDtypeStruct((cfg.n_nodes,), jnp.bool_)),
-        sds(jax.ShapeDtypeStruct((2,), f32))).compile()
+        sds(jax.ShapeDtypeStruct((2,), f32)))
+
+
+def test_gather_chunk_program_compiles(one_chip):
+    """The default-mode replay program: one 65536-event chunk on the
+    4-node replay cluster (``benchmarks/replay.py``)."""
+    scn = Scenario.cluster((2048.0, 2048.0, 4096.0, 8192.0),
+                           routing="size_aware", max_slots=256)
+    compiled = _lower_chunk(one_chip, scn, 65536).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
+
+
+# one KiSS edge node of 10 GB split 80/20 with 1,024 slots per pool, in
+# 65,536-event chunks: the benchmark's stress replay
+_STRESS = dict(node_mb=(10240.0,), small_frac=0.8, unified=False,
+               routing="sticky", replacement="lru", max_slots=1024)
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = .*? ([a-z][\w\-]*)\(")
+
+
+def _evict_opcodes(hlo: str) -> collections.Counter:
+    """Opcodes of the compiled instructions whose ``op_name`` carries the
+    ``pool.evict`` scope (a fusion counts under its own opcode, its fused
+    instructions under theirs)."""
+    out = collections.Counter()
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m and "pool.evict" in line:
+            out[m.group(1)] += 1
+    return out
+
+
+def _assert_one_sort(hlo: str) -> None:
+    ops = _evict_opcodes(hlo)
+    assert ops["sort"] == 1, ops
+    assert not any(op.startswith(("gather", "scatter")) for op in ops), ops
+
+
+def test_stress_replay_evict_is_one_sort(one_chip):
+    """In the stress replay's chunk program compiled for a v5e, the
+    eviction holds one sort and no gather or scatter: on the TPU a
+    dynamic gather or scatter over 1,024 slots costs more than the sort."""
+    _assert_one_sort(_lower_chunk(one_chip, Scenario(**_STRESS),
+                                  65536).compile().as_text())
+
+
+def test_stress_replay_evict_is_one_sort_cpu():
+    """The same structure compiled for the default (CPU) device, where no
+    TPU topology can be described."""
+    _assert_one_sort(_lower_chunk(None, Scenario(**_STRESS),
+                                  65536).compile().as_text())
