@@ -1089,6 +1089,25 @@ def _mask_grid(mask: np.ndarray, n_events: int, epoch_events: int,
     return jnp.asarray(mask.reshape(n_epochs, e, mask.shape[1]))
 
 
+def _route_counts(cfg: ClusterConfig, trace: Trace,
+                  node: np.ndarray) -> dict:
+    """``sim.result``'s counters, counted only while a profiler records:
+    ``resteered``, the invocations routed off their sticky home node
+    ``func_id % n_nodes``, and ``unhostable``, those larger than every
+    node's target pool (capacity alone, as ``size_aware`` judges it),
+    which no routing can place."""
+    if not TraceAnnotation.is_enabled():
+        return {}
+    caps = np.float32(cfg.pool_caps())
+    tgt = np.where(np.asarray(cfg.unified)[:, None], 0, [[0, 1]])
+    best = np.take_along_axis(caps, tgt, axis=1).max(axis=0)
+    size = np.asarray(trace.size_mb, np.float32)
+    fits = np.take(best, trace.cls) >= size - np.float32(1e-9)
+    home = np.asarray(trace.func_id) % cfg.n_nodes
+    return {"resteered": int(np.count_nonzero(node != home)),
+            "unhostable": int(size.size - np.count_nonzero(fits))}
+
+
 def _host_nbytes(tree) -> int:
     """Bytes of the host (numpy) arrays in ``tree``: what a call that
     takes it uploads to the device."""
@@ -1114,7 +1133,7 @@ def _simulate_cluster_jax(cfg: ClusterConfig, trace: Trace,
     ``"chains"`` per-chain arrays."""
     check_step_mode(mode)
     rz_on = cfg.resize_policy is not None
-    with TraceAnnotation("sim.prep"):
+    with TraceAnnotation("sim.prep", nodes=cfg.n_nodes):
         events = cluster_events(trace, cfg.n_nodes, resize=rz_on)
         cloud_cold = cloud_cold_draws(len(trace), cfg.cloud_cold_prob,
                                       rng_seed)
@@ -1137,7 +1156,7 @@ def _simulate_cluster_jax(cfg: ClusterConfig, trace: Trace,
     with TraceAnnotation("sim.fetch",
                          d2h_bytes=outs[0].nbytes + outs[1].nbytes):
         node, outcome = np.asarray(outs[0]), np.asarray(outs[1])
-    with TraceAnnotation("sim.result"):
+    with TraceAnnotation("sim.result", **_route_counts(cfg, trace, node)):
         result = build_result(cfg, trace, node, outcome, cloud_cold)
         if telemetry is None and chains is None and not rz_on:
             return result
@@ -1627,7 +1646,7 @@ def _simulate_cluster_chunked_jax(
     n, t_len = cfg.n_nodes, len(trace)
     rz_on = cfg.resize_policy is not None
     tel_on, ch_on = telemetry is not None, chains is not None
-    with TraceAnnotation("sim.prep"):
+    with TraceAnnotation("sim.prep", nodes=n):
         ev_np = _host_events(trace, n, resize=rz_on)
         routing = jnp.int32(int(cfg.routing))
         unified = jnp.asarray(cfg.unified, bool)
@@ -1680,7 +1699,8 @@ def _simulate_cluster_chunked_jax(
                                  d2h_bytes=2 * nodes_out[s:e].nbytes):
                 nodes_out[s:e] = np.asarray(nodes[:e - s])
                 outcomes_out[s:e] = np.asarray(outcomes[:e - s])
-    with TraceAnnotation("sim.result"):
+    with TraceAnnotation("sim.result",
+                         **_route_counts(cfg, trace, nodes_out)):
         result = build_result(cfg, trace, nodes_out, outcomes_out,
                               cloud_cold)
         extras = {}

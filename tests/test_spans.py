@@ -17,8 +17,9 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.cluster.engine import (_chunk_runner, _chunk_slice, _drop_size,
-                                  _host_events, _tel_init, _widx,
-                                  init_cluster, lower_chunk_program)
+                                  _host_events, _route_counts, _tel_init,
+                                  _widx, init_cluster, lower_chunk_program)
+from repro.core.types import Trace
 from repro.sim import Scenario, simulate, sweep
 
 from conftest import quantized_trace
@@ -126,6 +127,65 @@ def test_monolithic_simulate_spans(tmp_path, trace):
     assert all(_inside(s, root) for s in spans)
     (fetch,) = _named(spans, "sim.fetch")
     assert fetch[3] == {"d2h_bytes": 2 * EVENTS * 4}
+
+
+# four nodes of 1, 2, 0.5 and 4 GB split 80/20: small pools of 819.2,
+# 1638.4, 409.6 and 3276.8 MB, large pools of 204.8, 409.6, 102.4 and
+# 819.2 MB; size-aware routing over them, by hand (h = func mod 4):
+ROUTED = [  # (func, size, class, node)
+    (0, 300.0, 1, 1),   # large pools of 1 and 3 hold it: h 0 -> node 1
+    (1, 50.0, 0, 1),    # every small pool holds it: stays home
+    (3, 500.0, 1, 3),   # only node 3 holds it, its home
+    (2, 1000.0, 1, 2),  # no pool holds it: home, dropped
+    (5, 250.0, 1, 3),   # nodes 1 and 3: h 1 -> node 3
+    (6, 900.0, 1, 2),   # no pool holds it: home, dropped
+]
+RESTEERED, UNHOSTABLE = 2, 2
+
+
+@pytest.mark.parametrize("chunk", [None, 4], ids=["monolithic", "chunked"])
+def test_routing_counters_on_a_heterogeneous_site(tmp_path, chunk):
+    f, size, cls, node = (np.array(c) for c in zip(*ROUTED))
+    n = len(ROUTED)
+    trace = Trace(t=np.arange(n, dtype=np.float32), func_id=f.astype(
+        np.int32), size_mb=size.astype(np.float32), cls=cls.astype(np.int32),
+        warm_dur=np.ones(n, np.float32), cold_dur=np.full(n, 2, np.float32))
+    scn = Scenario(node_mb=(1024.0, 2048.0, 512.0, 4096.0), small_frac=0.8,
+                   unified=False, routing="size_aware")
+    res, spans = _spans(tmp_path, lambda: simulate(scn, trace,
+                                                   chunk_events=chunk))
+    assert res.node.tolist() == node.tolist()
+    assert np.count_nonzero(res.node != f % 4) == RESTEERED
+    (prep,) = _named(spans, "sim.prep")
+    assert prep[3] == {"nodes": 4}
+    (result,) = _named(spans, "sim.result")
+    assert result[3] == {"resteered": RESTEERED, "unhostable": UNHOSTABLE}
+
+
+def test_routing_counters_of_a_unified_pool(tmp_path):
+    """A unified node hosts either class in its whole memory: only a
+    container larger than every node is unhostable."""
+    trace = Trace(t=np.float32([0, 1, 2]), func_id=np.int32([0, 1, 2]),
+                  size_mb=np.float32([300, 900, 1100]),
+                  cls=np.int32([1, 1, 1]), warm_dur=np.ones(3, np.float32),
+                  cold_dur=np.full(3, 2, np.float32))
+    scn = Scenario(node_mb=(1024.0, 512.0), small_frac=0.8,
+                   unified=(True, False), routing="sticky")
+    _, spans = _spans(tmp_path, lambda: simulate(scn, trace))
+    (result,) = _named(spans, "sim.result")
+    assert result[3] == {"resteered": 0, "unhostable": 1}
+
+
+def test_routing_counters_cost_nothing_without_a_profiler():
+    """Outside a profile ``sim.result`` carries no counters, so the
+    O(events) count is not paid on an unprofiled call."""
+    trace = Trace(t=np.float32([0, 1]), func_id=np.int32([0, 1]),
+                  size_mb=np.float32([300, 50]), cls=np.int32([1, 0]),
+                  warm_dur=np.ones(2, np.float32),
+                  cold_dur=np.full(2, 2, np.float32))
+    cfg = Scenario(node_mb=(1024.0, 512.0),
+                   routing="size_aware").to_cluster_config()
+    assert _route_counts(cfg, trace, np.int32([0, 1])) == {}
 
 
 @pytest.mark.parametrize("mode", ["gather", "vmap", "fused"])
