@@ -24,10 +24,12 @@ Three step modes (``STEP_MODES``), numerically identical
 
 * ``"gather"`` (default) — dynamic-slice the selected pool out of the
   stack, step it, scatter it back: O(slots) work per event regardless of
-  cluster size.
+  cluster size, and only the pool step's branch the event takes runs
+  (the eviction sort only on a miss that evicts).
 * ``"vmap"`` — ``jax.vmap(pool_step)`` steps *all* pools against the
   event and a select mask keeps only the routed pool's new state: the
-  fully batched formulation, O(N * slots) per event, useful as a
+  fully batched formulation, O(N * slots) per event with every branch
+  of the step computed (a batched ``cond`` is a select), useful as a
   cross-check and on accelerators where the batched sort amortizes.
 * ``"fused"`` — the same all-pools formulation, but the miss-path
   evict-and-place decision runs through the step-backend seam
@@ -1089,22 +1091,26 @@ def _mask_grid(mask: np.ndarray, n_events: int, epoch_events: int,
     return jnp.asarray(mask.reshape(n_epochs, e, mask.shape[1]))
 
 
-def _route_counts(cfg: ClusterConfig, trace: Trace,
-                  node: np.ndarray) -> dict:
+def _result_counts(cfg: ClusterConfig, trace: Trace, node: np.ndarray,
+                   outcome: np.ndarray) -> dict:
     """``sim.result``'s counters, counted only while a profiler records:
-    ``resteered``, the invocations routed off their sticky home node
-    ``func_id % n_nodes``, and ``unhostable``, those larger than every
-    node's target pool (capacity alone, as ``size_aware`` judges it),
-    which no routing can place."""
+    ``hits``, ``misses`` and ``drops``, the invocations of each outcome
+    code (the pool step's branches: a miss is the only one that may run
+    the eviction sort); ``resteered``, the invocations routed off their
+    sticky home node ``func_id % n_nodes``; and ``unhostable``, those
+    larger than every node's target pool (capacity alone, as
+    ``size_aware`` judges it), which no routing can place."""
     if not TraceAnnotation.is_enabled():
         return {}
+    hits, misses, drops = np.bincount(outcome, minlength=3)[:3].tolist()
     caps = np.float32(cfg.pool_caps())
     tgt = np.where(np.asarray(cfg.unified)[:, None], 0, [[0, 1]])
     best = np.take_along_axis(caps, tgt, axis=1).max(axis=0)
     size = np.asarray(trace.size_mb, np.float32)
     fits = np.take(best, trace.cls) >= size - np.float32(1e-9)
     home = np.asarray(trace.func_id) % cfg.n_nodes
-    return {"resteered": int(np.count_nonzero(node != home)),
+    return {"hits": hits, "misses": misses, "drops": drops,
+            "resteered": int(np.count_nonzero(node != home)),
             "unhostable": int(size.size - np.count_nonzero(fits))}
 
 
@@ -1156,7 +1162,8 @@ def _simulate_cluster_jax(cfg: ClusterConfig, trace: Trace,
     with TraceAnnotation("sim.fetch",
                          d2h_bytes=outs[0].nbytes + outs[1].nbytes):
         node, outcome = np.asarray(outs[0]), np.asarray(outs[1])
-    with TraceAnnotation("sim.result", **_route_counts(cfg, trace, node)):
+    with TraceAnnotation("sim.result",
+                         **_result_counts(cfg, trace, node, outcome)):
         result = build_result(cfg, trace, node, outcome, cloud_cold)
         if telemetry is None and chains is None and not rz_on:
             return result
@@ -1700,7 +1707,8 @@ def _simulate_cluster_chunked_jax(
                 nodes_out[s:e] = np.asarray(nodes[:e - s])
                 outcomes_out[s:e] = np.asarray(outcomes[:e - s])
     with TraceAnnotation("sim.result",
-                         **_route_counts(cfg, trace, nodes_out)):
+                         **_result_counts(cfg, trace, nodes_out,
+                                          outcomes_out)):
         result = build_result(cfg, trace, nodes_out, outcomes_out,
                               cloud_cold)
         extras = {}

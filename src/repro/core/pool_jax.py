@@ -217,92 +217,102 @@ def _shrink_pass(p: PoolState, idle: jax.Array, want: jax.Array):
 
 
 def pool_step(p: PoolState, ev: Event) -> tuple[PoolState, jax.Array]:
-    """Process one invocation.  Returns (new_state, outcome code)."""
+    """Process one invocation.  Returns (new_state, outcome code).
+
+    Only the path the event takes runs: the eviction sort sits under a
+    ``lax.cond`` on whether this miss can evict at all, and the hit, miss
+    and drop updates under one ``lax.switch`` on the outcome.  Where the
+    predicate is batched (``jax.vmap``: the ``"vmap"`` step mode, sweep
+    lanes) JAX lowers each to all of its branches plus a select, the same
+    work and the same bits as computing every branch."""
     rz = p.alloc is not None                        # resize on (trace-time)
     idle = p.valid & (p.busy_until <= ev.t)
     match = idle & (p.func_id == ev.func_id)
     any_hit = jnp.any(match)
     cold_cost = ev.cold - ev.warm
 
-    # ---- HIT branch: touch the matching idle container with lowest seq ----
-    hit_slot = jnp.argmin(jnp.where(match, p.seq, _INF))
-    new_freq = p.freq[hit_slot] + 1.0
-    hit_extra = {} if not rz else dict(
-        acc_used=p.acc_used + p.used[hit_slot],
-        acc_alloc=p.acc_alloc + p.alloc[hit_slot],
-        # a resident serving from a shrunken limit is a bottleneck event
-        bneck=p.bneck + (p.alloc[hit_slot]
-                         < p.size[hit_slot]).astype(jnp.int32),
-    )
-    hit_state = p._replace(
-        last_use=put(p.last_use, hit_slot, ev.t),
-        freq=put(p.freq, hit_slot, new_freq),
-        gd_pri=put(p.gd_pri, hit_slot,
-                   _gd(p.clock, new_freq, cold_cost, p.size[hit_slot])),
-        busy_until=put(p.busy_until, hit_slot, ev.t + ev.warm),
-        **hit_extra,
-    )
-
-    # ---- MISS branch: shrink residents toward observed usage (resize
-    # only), then evict the minimal (priority, seq)-prefix, then insert ----
+    # ---- the miss path's shrink pass (resize only) and its cheap tests:
+    # can the container fit once every idle slot is evicted? ----
     if rz:
         alloc1, reclaimed = _shrink_pass(p, idle, ev.size - p.free)
         free1 = p.free + reclaimed
     else:
         alloc1, free1 = None, p.free
     deficit = ev.size - free1
-    with jax.named_scope("pool.evict"):
-        evict, freed = _evict_prefix(p, idle, deficit, alloc1)
     total_evictable = jnp.sum(
         jnp.where(idle, p.size if alloc1 is None else alloc1, 0.0))
+    fits = ((ev.size <= p.capacity + 1e-9)
+            & (total_evictable >= deficit - 1e-9))
 
+    # ---- evict the minimal (priority, seq)-prefix, on a miss that needs
+    # room and can get it.  Skipping is exact: at deficit - 1e-9 <= 0 the
+    # prefix is empty (the bytes freed before a slot are never negative),
+    # and otherwise the step hits or drops and its eviction is unused ----
+    with jax.named_scope("pool.evict"):
+        evict, freed = jax.lax.cond(
+            ~any_hit & (deficit - 1e-9 > 0) & fits,
+            lambda: _evict_prefix(p, idle, deficit, alloc1),
+            lambda: (jnp.zeros_like(p.valid), jnp.float32(0.0)))
     valid_after = p.valid & ~evict
-    empty_exists = jnp.any(~valid_after)
-    can_place = ((ev.size <= p.capacity + 1e-9)
-                 & (total_evictable >= deficit - 1e-9)
-                 & empty_exists)
-
-    ins = jnp.argmax(~valid_after)                  # first empty slot
-    is_gd = p.policy == int(Policy.GREEDY_DUAL)
-    # with no eviction the inner max is -inf and maximum() degrades to
-    # p.clock, so no extra any(evict) guard is needed (regression-pinned
-    # by test_pool_kernel.test_gd_clock_no_eviction)
-    new_clock = jnp.where(
-        is_gd,
-        jnp.maximum(p.clock, jnp.max(jnp.where(evict, p.gd_pri, -_INF))),
-        p.clock)
-    miss_extra = {} if not rz else dict(
-        alloc=put(jnp.where(evict, 0.0, alloc1), ins, ev.size),
-        used=put(jnp.where(evict, 0.0, p.used), ins, ev.used),
-        acc_used=p.acc_used + ev.used,
-        acc_alloc=p.acc_alloc + ev.size,
-    )
-    miss_state = p._replace(
-        func_id=put(p.func_id, ins, ev.func_id),
-        size=put(p.size, ins, ev.size),
-        last_use=put(p.last_use, ins, ev.t),
-        freq=put(p.freq, ins, 1.0),
-        gd_pri=put(p.gd_pri, ins, _gd(new_clock, 1.0, cold_cost, ev.size)),
-        busy_until=put(p.busy_until, ins, ev.t + ev.cold),
-        seq=put(p.seq, ins, p.next_seq),
-        valid=put(valid_after, ins, True),
-        free=free1 + freed - ev.size,
-        clock=new_clock,
-        next_seq=p.next_seq + 1.0,
-        **miss_extra,
-    )
-
-    # ---- select ----
+    can_place = fits & jnp.any(~valid_after)
     outcome = jnp.where(any_hit, HIT, jnp.where(can_place, MISS, DROP))
 
-    def pick(h, m, d):
-        return jax.tree_util.tree_map(
-            lambda a, b, c: jnp.where(
-                outcome == HIT, a, jnp.where(outcome == MISS, b, c)),
-            h, m, d)
+    def hit():
+        # touch the matching idle container with lowest seq
+        hit_slot = jnp.argmin(jnp.where(match, p.seq, _INF))
+        new_freq = p.freq[hit_slot] + 1.0
+        hit_extra = {} if not rz else dict(
+            acc_used=p.acc_used + p.used[hit_slot],
+            acc_alloc=p.acc_alloc + p.alloc[hit_slot],
+            # a resident serving from a shrunken limit is a bottleneck
+            bneck=p.bneck + (p.alloc[hit_slot]
+                             < p.size[hit_slot]).astype(jnp.int32),
+        )
+        return p._replace(
+            last_use=put(p.last_use, hit_slot, ev.t),
+            freq=put(p.freq, hit_slot, new_freq),
+            gd_pri=put(p.gd_pri, hit_slot,
+                       _gd(p.clock, new_freq, cold_cost, p.size[hit_slot])),
+            busy_until=put(p.busy_until, hit_slot, ev.t + ev.warm),
+            **hit_extra,
+        )
 
-    new_state = pick(hit_state, miss_state, p)
-    return new_state, outcome
+    def miss():
+        # insert into the first empty slot left after the eviction
+        ins = jnp.argmax(~valid_after)
+        is_gd = p.policy == int(Policy.GREEDY_DUAL)
+        # with no eviction the inner max is -inf and maximum() degrades
+        # to p.clock, so no extra any(evict) guard is needed
+        # (regression-pinned by test_pool_kernel.test_gd_clock_no_eviction)
+        new_clock = jnp.where(
+            is_gd,
+            jnp.maximum(p.clock,
+                        jnp.max(jnp.where(evict, p.gd_pri, -_INF))),
+            p.clock)
+        miss_extra = {} if not rz else dict(
+            alloc=put(jnp.where(evict, 0.0, alloc1), ins, ev.size),
+            used=put(jnp.where(evict, 0.0, p.used), ins, ev.used),
+            acc_used=p.acc_used + ev.used,
+            acc_alloc=p.acc_alloc + ev.size,
+        )
+        return p._replace(
+            func_id=put(p.func_id, ins, ev.func_id),
+            size=put(p.size, ins, ev.size),
+            last_use=put(p.last_use, ins, ev.t),
+            freq=put(p.freq, ins, 1.0),
+            gd_pri=put(p.gd_pri, ins,
+                       _gd(new_clock, 1.0, cold_cost, ev.size)),
+            busy_until=put(p.busy_until, ins, ev.t + ev.cold),
+            seq=put(p.seq, ins, p.next_seq),
+            valid=put(valid_after, ins, True),
+            free=free1 + freed - ev.size,
+            clock=new_clock,
+            next_seq=p.next_seq + 1.0,
+            **miss_extra,
+        )
+
+    # branches indexed by the outcome codes HIT, MISS, DROP = 0, 1, 2
+    return jax.lax.switch(outcome, (hit, miss, lambda: p)), outcome
 
 
 # ---------------------------------------------------------------------------

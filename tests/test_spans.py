@@ -17,7 +17,7 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.cluster.engine import (_chunk_runner, _chunk_slice, _drop_size,
-                                  _host_events, _route_counts, _tel_init,
+                                  _host_events, _result_counts, _tel_init,
                                   _widx, init_cluster, lower_chunk_program)
 from repro.core.types import Trace
 from repro.sim import Scenario, simulate, sweep
@@ -141,6 +141,8 @@ ROUTED = [  # (func, size, class, node)
     (6, 900.0, 1, 2),   # no pool holds it: home, dropped
 ]
 RESTEERED, UNHOSTABLE = 2, 2
+# every placed event finds room: the two unhostable ones drop
+HITS, MISSES, DROPS = 0, 4, 2
 
 
 @pytest.mark.parametrize("chunk", [None, 4], ids=["monolithic", "chunked"])
@@ -159,7 +161,8 @@ def test_routing_counters_on_a_heterogeneous_site(tmp_path, chunk):
     (prep,) = _named(spans, "sim.prep")
     assert prep[3] == {"nodes": 4}
     (result,) = _named(spans, "sim.result")
-    assert result[3] == {"resteered": RESTEERED, "unhostable": UNHOSTABLE}
+    assert result[3] == {"hits": HITS, "misses": MISSES, "drops": DROPS,
+                         "resteered": RESTEERED, "unhostable": UNHOSTABLE}
 
 
 def test_routing_counters_of_a_unified_pool(tmp_path):
@@ -173,7 +176,10 @@ def test_routing_counters_of_a_unified_pool(tmp_path):
                    unified=(True, False), routing="sticky")
     _, spans = _spans(tmp_path, lambda: simulate(scn, trace))
     (result,) = _named(spans, "sim.result")
-    assert result[3] == {"resteered": 0, "unhostable": 1}
+    # sticky: the 900 MB one goes home to node 1, whose large pool
+    # (102.4 MB) cannot hold it
+    assert result[3] == {"hits": 0, "misses": 1, "drops": 2,
+                         "resteered": 0, "unhostable": 1}
 
 
 def test_routing_counters_cost_nothing_without_a_profiler():
@@ -185,7 +191,23 @@ def test_routing_counters_cost_nothing_without_a_profiler():
                   cold_dur=np.full(2, 2, np.float32))
     cfg = Scenario(node_mb=(1024.0, 512.0),
                    routing="size_aware").to_cluster_config()
-    assert _route_counts(cfg, trace, np.int32([0, 1])) == {}
+    assert _result_counts(cfg, trace, np.int32([0, 1]),
+                          np.int32([1, 1])) == {}
+
+
+@pytest.mark.parametrize("chunk", [None, CHUNK], ids=["monolithic",
+                                                     "chunked"])
+def test_outcome_counters_on_sim_result(tmp_path, trace, chunk):
+    """Under a profiler ``sim.result`` counts the outcome codes: the
+    branches the pool step took (hit, miss, drop)."""
+    scn = Scenario.kiss(1024.0)
+    res, spans = _spans(tmp_path, lambda: simulate(scn, trace,
+                                                   chunk_events=chunk))
+    (result,) = _named(spans, "sim.result")
+    counts = np.bincount(res.outcome, minlength=3).tolist()
+    assert [result[3][k] for k in ("hits", "misses", "drops")] == counts
+    assert min(counts) > 0, counts
+    assert sum(counts) == EVENTS
 
 
 @pytest.mark.parametrize("mode", ["gather", "vmap", "fused"])

@@ -153,16 +153,74 @@ def _assert_one_sort(hlo: str) -> None:
     assert not any(op.startswith(("gather", "scatter")) for op in ops), ops
 
 
-def test_stress_replay_evict_is_one_sort(one_chip):
+_HEADER = re.compile(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_BRANCHES = re.compile(r" conditional\(.*branch_computations=\{([^}]*)\}")
+_BODY = re.compile(r" while\(.*body=%?([\w.\-]+)")
+
+
+def _computations(hlo: str) -> dict:
+    """Each computation of the compiled text by name: its instruction
+    lines."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = _HEADER.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _assert_evict_in_a_branch(hlo: str) -> None:
+    """The eviction sort runs in a branch of a ``conditional`` of the scan
+    body, so a step that cannot evict skips it, and not in the body."""
+    comps = _computations(hlo)
+    (sort_in,) = [c for c, lines in comps.items() for ln in lines
+                  if " sort(" in ln and "pool.evict" in ln]
+    bodies = {m.group(1) for lines in comps.values() for ln in lines
+              for m in [_BODY.search(ln)] if m}
+    assert sort_in not in bodies
+    owners = [c for c, lines in comps.items() for ln in lines
+              for m in [_BRANCHES.search(ln)] if m
+              and sort_in in [b.strip().lstrip("%")
+                              for b in m.group(1).split(",")]]
+    assert len(owners) == 1 and owners[0] in bodies, (sort_in, owners,
+                                                       bodies)
+
+
+@pytest.fixture(scope="module")
+def stress_hlo(one_chip):
+    """The stress replay's chunk program compiled for a v5e."""
+    return _lower_chunk(one_chip, Scenario(**_STRESS),
+                        65536).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def stress_hlo_cpu():
+    """The same program compiled for the default (CPU) device, where no
+    TPU topology can be described."""
+    return _lower_chunk(None, Scenario(**_STRESS), 65536).compile().as_text()
+
+
+def test_stress_replay_evict_is_one_sort(stress_hlo):
     """In the stress replay's chunk program compiled for a v5e, the
     eviction holds one sort and no gather or scatter: on the TPU a
     dynamic gather or scatter over 1,024 slots costs more than the sort."""
-    _assert_one_sort(_lower_chunk(one_chip, Scenario(**_STRESS),
-                                  65536).compile().as_text())
+    _assert_one_sort(stress_hlo)
 
 
-def test_stress_replay_evict_is_one_sort_cpu():
-    """The same structure compiled for the default (CPU) device, where no
-    TPU topology can be described."""
-    _assert_one_sort(_lower_chunk(None, Scenario(**_STRESS),
-                                  65536).compile().as_text())
+def test_stress_replay_evict_is_one_sort_cpu(stress_hlo_cpu):
+    """The same structure compiled for the CPU."""
+    _assert_one_sort(stress_hlo_cpu)
+
+
+def test_stress_replay_evict_runs_in_a_branch(stress_hlo):
+    """Compiled for a v5e, the eviction sort sits in a branch of a
+    conditional in the scan body: XLA:TPU keeps the branch."""
+    _assert_evict_in_a_branch(stress_hlo)
+
+
+def test_stress_replay_evict_runs_in_a_branch_cpu(stress_hlo_cpu):
+    _assert_evict_in_a_branch(stress_hlo_cpu)
