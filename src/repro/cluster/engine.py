@@ -2,8 +2,8 @@
 
 Every node owns two warm pools (a unified node uses pool 0 with the whole
 node memory and a zero-capacity pool 1), and all ``2N`` pools of the
-cluster are stacked on one leading axis of a single ``PoolState``.  The
-whole trace then runs as ONE ``lax.scan`` program:
+cluster are stacked on one leading axis of a single ``PoolState``.  Each
+event of the trace is one step of a ``lax.scan``:
 
 1. per-node load signals (``free``/``capacity`` of the pool that would
    serve this request) are read across the stacked axis;
@@ -40,24 +40,35 @@ Three step modes (``STEP_MODES``), numerically identical
   Mosaic on TPU (run and checked against ``"gather"`` on a TPU v5e by
   ``chip_smoke.py``), interpreted (bit-identically) on CPU.
 
-Autoscaled scenarios (``Scenario(..., autoscale=Autoscale(...))``) run the
-same per-event step inside an outer scan over fixed-length epochs
-(``_run_autoscale_impl``): each full epoch ends with every KiSS node
-re-splitting its small/large pools from the per-class pressure observed on
-that node (``pool_resize`` vmapped over the stacked pool axis), and — when
-node scaling is enabled — one node spawning or retiring from the
-cluster-wide drop fraction (the membership mask rides in the carry).  The
-trace is padded to a whole number of epochs with guaranteed-drop no-op
-events that are masked out of the pressure signal and sliced off the
-outputs.
+Two program shapes scan one event body (:func:`_make_event`), each
+single-lane (unbatched, so ``lax.cond`` skips the branches an event does
+not take) and lane-stacked for sweeps (vmapped, optionally sharded):
 
-Failure schedules (``Scenario(..., failures=Failures(...))``) compile
-host-side into per-event ``up``/``recover`` bool[T, N] masks that ride
-into the scan as data (``_run_failures_impl``; shared verbatim with the
-oracle): routing sees ``RouteCtx.node_up``, a request routed to a down
-node drops to the cloud without touching any pool, and a recovering
-node's pools are cleared first (``_invalidate_nodes``) so the re-warm
-cost is observable.
+* the **chunk program** (:func:`_chunk_impl`) scans a chunk of events
+  with the carry donated, and the host loop (:func:`_chunk_loop`)
+  threads that carry from chunk to chunk; a whole-trace call is one
+  chunk of ``len(trace)`` events.  Failure schedules
+  (``Scenario(..., failures=Failures(...))``) compile host-side into
+  per-event ``up``/``recover`` bool[T, N] masks that ride in as data
+  (shared verbatim with the oracle): routing sees ``RouteCtx.node_up``, a
+  request routed to a down node drops to the cloud without touching any
+  pool, and a recovering node's pools are cleared first
+  (``_invalidate_nodes``) so the re-warm cost is observable.
+* the **epoch grid** (:func:`_epoch_impl`) runs autoscaled scenarios
+  (``Scenario(..., autoscale=Autoscale(...))``): an outer scan over
+  fixed-length epochs, the event body inside each, and at the end of
+  every full epoch each KiSS node re-splits its small/large pools from
+  the per-class pressure observed on that node (``pool_resize`` vmapped
+  over the stacked pool axis) and — when node scaling is enabled — one
+  node spawns or retires from the cluster-wide drop fraction (the
+  membership mask rides in the carry).  The trace is padded to a whole
+  number of epochs with guaranteed-drop no-op events that are masked out
+  of the pressure signal and sliced off the outputs.
+
+Optional mechanisms (failures, telemetry, chains) are named fields of
+the carry (:class:`Carry`) and of the per-event inputs (:class:`EventXs`)
+that are ``None`` when off, so they vanish from the pytree and a run
+without them compiles none of their work.
 """
 from __future__ import annotations
 
@@ -165,23 +176,29 @@ class ClusterEvent(NamedTuple):
     used: jax.Array | None = None   # f32 observed usage (resize only)
 
 
+def _host_events(trace: Trace, n_nodes: int, *,
+                 resize: bool = False) -> ClusterEvent:
+    """The trace's events as host (numpy) columns, with their node
+    hashes; the chunk loop uploads one slice at a time."""
+    h1, h2 = route_hashes(trace.func_id, n_nodes)
+    fid = np.asarray(trace.func_id, np.int32)
+    size = np.asarray(trace.size_mb, np.float32)
+    return ClusterEvent(
+        t=np.asarray(trace.t, np.float32),
+        func_id=fid,
+        size=size,
+        cls=np.asarray(trace.cls, np.int32),
+        warm=np.asarray(trace.warm_dur, np.float32),
+        cold=np.asarray(trace.cold_dur, np.float32),
+        h1=h1, h2=h2,
+        used=observed_usage(np, fid, size) if resize else None)
+
+
 def cluster_events(trace: Trace, n_nodes: int, *,
                    resize: bool = False) -> ClusterEvent:
-    h1, h2 = route_hashes(trace.func_id, n_nodes)
-    return ClusterEvent(
-        t=jnp.asarray(trace.t, jnp.float32),
-        func_id=jnp.asarray(trace.func_id, jnp.int32),
-        size=jnp.asarray(trace.size_mb, jnp.float32),
-        cls=jnp.asarray(trace.cls, jnp.int32),
-        warm=jnp.asarray(trace.warm_dur, jnp.float32),
-        cold=jnp.asarray(trace.cold_dur, jnp.float32),
-        h1=jnp.asarray(h1, jnp.int32),
-        h2=jnp.asarray(h2, jnp.int32),
-        used=(jnp.asarray(observed_usage(
-            np, np.asarray(trace.func_id, np.int32),
-            np.asarray(trace.size_mb, np.float32)))
-            if resize else None),
-    )
+    """:func:`_host_events` as device arrays."""
+    return jax.tree_util.tree_map(
+        jnp.asarray, _host_events(trace, n_nodes, resize=resize))
 
 
 def init_cluster(cfg: ClusterConfig) -> PoolState:
@@ -241,14 +258,14 @@ def _invalidate_nodes(pools: PoolState, mask_n: jax.Array, n_nodes: int):
 # in-scan telemetry: windowed counters riding the scan carry
 # --------------------------------------------------------------------------
 # ``repro.sim.telemetry`` documents the user-facing contract; the engine
-# pieces here keep the accumulator a fixed-shape pytree so it rides any
-# scan carry (monolithic, failure-injected, epoch, or chunked) and vmaps
-# across sweep lanes.  Window indices are *global* event indices computed
-# host-side (``i // window_events``) and carried into the scan as data,
-# so a chunked run scatters into the same windows as a monolithic one —
-# chunked == monolithic holds for ANY chunk size, dividing the window or
-# not.  Row ``n_windows`` is a junk row that absorbs pad events (epoch /
-# chunk padding) and is sliced off host-side by ``_tel_np``.
+# pieces here keep the accumulator a fixed-shape pytree so it rides the
+# carry of either program shape and vmaps across sweep lanes.  Window
+# indices are *global* event indices computed host-side
+# (``i // window_events``) and carried into the scan as data, so a run in
+# chunks scatters into the same windows as a whole-trace one for ANY
+# chunk size, dividing the window or not.  Row ``n_windows`` is a junk
+# row that absorbs pad events (epoch / chunk padding) and is sliced off
+# host-side by ``_tel_np``.
 
 class TelAcc(NamedTuple):
     """The in-carry windowed accumulator (one junk row past the end)."""
@@ -266,15 +283,16 @@ def _n_windows(n_events: int, window: int) -> int:
     return -(-n_events // window)
 
 
-def _tel_init(n_windows: int, n_nodes: int) -> TelAcc:
-    w = n_windows + 1
-    return TelAcc(counts=jnp.zeros((w, 2, 3), jnp.int32),
-                  free=jnp.zeros((w, n_nodes), jnp.float32),
-                  occ=jnp.zeros((w, n_nodes), jnp.int32),
-                  inval=jnp.zeros((w,), jnp.int32),
-                  up=jnp.zeros((w,), jnp.int32),
-                  active=jnp.zeros((w,), jnp.int32),
-                  cmiss=jnp.zeros((w,), jnp.int32))
+def _tel_init(n_windows: int, n_nodes: int, lanes: tuple = ()) -> TelAcc:
+    """A zeroed accumulator; ``lanes=(L,)`` stacks one per sweep lane."""
+    w = (*lanes, n_windows + 1)
+    return TelAcc(counts=jnp.zeros((*w, 2, 3), jnp.int32),
+                  free=jnp.zeros((*w, n_nodes), jnp.float32),
+                  occ=jnp.zeros((*w, n_nodes), jnp.int32),
+                  inval=jnp.zeros(w, jnp.int32),
+                  up=jnp.zeros(w, jnp.int32),
+                  active=jnp.zeros(w, jnp.int32),
+                  cmiss=jnp.zeros(w, jnp.int32))
 
 
 @jax.named_scope("step.acc")
@@ -312,49 +330,19 @@ def _tel_np(tel: TelAcc, n_windows: int) -> dict:
         "chain_miss": np.asarray(tel.cmiss, np.int64)[:n_windows]}
 
 
-def _widx(n_events: int, window: int) -> jnp.ndarray:
-    """Global window index per event — scan data, computed host-side."""
-    return jnp.asarray(np.arange(n_events, dtype=np.int32) // window)
-
-
-def _widx_grid(n_events: int, epoch_events: int,
-               window: int) -> jnp.ndarray:
-    """Epoch-shaped [E, e] window indices (pad events index the junk
-    row) — the telemetry analogue of :func:`_epoch_grid`."""
-    e = epoch_events
-    n_epochs = -(-n_events // e)
-    pad = n_epochs * e - n_events
-    idx = np.arange(n_events, dtype=np.int32) // window
-    if pad:
-        idx = np.concatenate(
-            [idx, np.full(pad, _n_windows(n_events, window), np.int32)])
-    return jnp.asarray(idx.reshape(n_epochs, e))
-
-
-def _chunk_widx(s: int, e: int, chunk: int, window: int,
-                n_windows: int) -> jnp.ndarray:
-    """Chunk-slice of the global window indices, padded with the junk
-    index — the telemetry analogue of :func:`_chunk_slice`."""
-    idx = np.arange(s, e, dtype=np.int32) // window
-    pad = chunk - (e - s)
-    if pad:
-        idx = np.concatenate([idx, np.full(pad, n_windows, np.int32)])
-    return jnp.asarray(idx)
-
-
 # --------------------------------------------------------------------------
 # in-scan chain accounting: per-chain end-to-end state riding the carry
 # --------------------------------------------------------------------------
 # ``core.continuum.compile_chains`` turns a chained trace into a
 # ``ChainPlan`` host-side; the engine carries one f32 latency row per
 # chain (+ the junk row ``n_chains`` that absorbs pad events, exactly
-# like the telemetry junk window) through every scan shape — monolithic,
-# failure-injected, epoch, chunked — and the oracle mirrors each update
-# through float32 in the same event order, so the two engines' chain
-# latencies and deadline-miss flags are bit-identical by construction.
-# The plan's per-event arrays ride as ``xs`` data shared across sweep
-# lanes; the per-chain deadline vector and the cloud cold draws are
-# per-lane data (lanes differ in Chains config / cloud_cold_prob).
+# like the telemetry junk window) through either program shape, and the
+# oracle mirrors each update through float32 in the same event order, so
+# the two engines' chain latencies and deadline-miss flags are
+# bit-identical by construction.  The plan's per-event arrays ride as
+# ``xs`` data shared across sweep lanes; the per-chain deadline vector
+# and the cloud cold draws are per-lane data (lanes differ in Chains
+# config / cloud_cold_prob).
 
 class ChainXs(NamedTuple):
     """Per-event chain scan data (host-compiled, shared across lanes)."""
@@ -373,12 +361,13 @@ class ChainAcc(NamedTuple):
     missed: jax.Array   # bool[C+1] deadline missed (judged at last stage)
 
 
-def _chain_init(n_chains: int) -> ChainAcc:
-    c = n_chains + 1
-    return ChainAcc(lat=jnp.zeros((c,), jnp.float32),
-                    dropped=jnp.zeros((c,), bool),
-                    done=jnp.zeros((c,), bool),
-                    missed=jnp.zeros((c,), bool))
+def _chain_init(n_chains: int, lanes: tuple = ()) -> ChainAcc:
+    """A zeroed accumulator; ``lanes=(L,)`` stacks one per sweep lane."""
+    c = (*lanes, n_chains + 1)
+    return ChainAcc(lat=jnp.zeros(c, jnp.float32),
+                    dropped=jnp.zeros(c, bool),
+                    done=jnp.zeros(c, bool),
+                    missed=jnp.zeros(c, bool))
 
 
 def _chain_pre(chain: ChainAcc, cdl: jax.Array, cx: ChainXs):
@@ -423,91 +412,24 @@ def _chain_np(chain: ChainAcc, n_chains: int) -> dict:
             "missed": np.asarray(chain.missed)[:n_chains]}
 
 
-def _stack_chain(n_chains: int, lanes: int) -> ChainAcc:
-    """One zeroed chain accumulator per sweep lane (lanes in a group
-    share the trace, hence the chain count — the stack is dense)."""
-    return jax.tree_util.tree_map(
-        lambda a: jnp.zeros((lanes,) + a.shape, a.dtype),
-        _chain_init(n_chains))
-
-
 def _chain_xs(plan: ChainPlan) -> ChainXs:
-    """The plan's per-event arrays as scan data."""
-    return ChainXs(cid=jnp.asarray(plan.cid, jnp.int32),
-                   stage=jnp.asarray(plan.stage, jnp.int32),
-                   last=jnp.asarray(plan.last, bool))
-
-
-def _chain_xs_np(plan: ChainPlan) -> ChainXs:
-    """Numpy twin of :func:`_chain_xs` for the chunked host loop."""
+    """The plan's per-event arrays, host-side."""
     return ChainXs(cid=np.asarray(plan.cid, np.int32),
                    stage=np.asarray(plan.stage, np.int32),
                    last=np.asarray(plan.last, bool))
 
 
-def _chain_grid(plan: ChainPlan, n_events: int,
-                epoch_events: int) -> ChainXs:
-    """Epoch-shaped [E, e] chain xs (pad events index the junk row) —
-    the chain analogue of :func:`_epoch_grid`."""
-    e = epoch_events
-    n_epochs = -(-n_events // e)
-    pad = n_epochs * e - n_events
-    xs = _chain_xs_np(plan)
-    if pad:
-        fills = ChainXs(cid=plan.n_chains, stage=-1, last=False)
-        xs = jax.tree_util.tree_map(
-            lambda a, f: np.concatenate([a, np.full(pad, f, a.dtype)]),
-            xs, fills)
-    return jax.tree_util.tree_map(
-        lambda a: jnp.asarray(a.reshape(n_epochs, e)), xs)
-
-
-def _chunk_chain(xs: ChainXs, n_chains: int, s: int, e: int,
-                 chunk: int) -> ChainXs:
-    """Chunk-slice of the per-event chain xs, padded with junk-row
-    no-ops — the chain analogue of :func:`_chunk_slice`."""
-    sl = jax.tree_util.tree_map(lambda a: a[s:e], xs)
-    pad = chunk - (e - s)
-    if pad:
-        fills = ChainXs(cid=n_chains, stage=-1, last=False)
-        sl = jax.tree_util.tree_map(
-            lambda a, f: np.concatenate([a, np.full(pad, f, a.dtype)]),
-            sl, fills)
-    return jax.tree_util.tree_map(jnp.asarray, sl)
-
-
-def _grid_pad(arr: np.ndarray, n_events: int, epoch_events: int,
-              fill) -> jnp.ndarray:
-    """Pad a per-event 1-D array to whole epochs and reshape [E, e]."""
-    e = epoch_events
-    n_epochs = -(-n_events // e)
-    pad = n_epochs * e - n_events
-    if pad:
-        arr = np.concatenate([arr, np.full(pad, fill, arr.dtype)])
-    return jnp.asarray(arr.reshape(n_epochs, e))
-
-
-def _chunk_pad(arr: np.ndarray, s: int, e: int, chunk: int,
-               fill) -> jnp.ndarray:
-    """Chunk-slice a per-event 1-D array, padding to ``chunk``."""
-    sl = arr[s:e]
-    pad = chunk - (e - s)
-    if pad:
-        sl = np.concatenate([sl, np.full(pad, fill, arr.dtype)])
-    return jnp.asarray(sl)
-
-
 def _make_step(routing: jax.Array, unified: jax.Array, cloud: jax.Array,
                n_nodes: int, mode: str):
-    """Build the per-event scan step (route, then step the routed pool) —
-    shared by the static whole-trace scan, the failure-injected scan, and
-    the autoscaled epoch scan.  ``up_n`` (bool[N], optional) is the
-    live-node mask: routing policies read it via ``RouteCtx.node_up`` and
-    a request still routed to a down node drops to the cloud without
-    touching any pool (down pools are frozen).  ``cslack``/``cstage``
-    (optional f32/i32 scalars) are the event's chain slack and stage for
-    ``RouteCtx`` — constants ``+inf``/``-1`` when chains are off, so
-    slack-aware policies degrade to their slack-rich branch."""
+    """Build the per-event step (route, then step the routed pool) that
+    the event body of both program shapes calls.  ``up_n`` (bool[N],
+    optional) is the live-node mask: routing policies read it via
+    ``RouteCtx.node_up`` and a request still routed to a down node drops
+    to the cloud without touching any pool (down pools are frozen).
+    ``cslack``/``cstage`` (optional f32/i32 scalars) are the event's chain
+    slack and stage for ``RouteCtx`` — constants ``+inf``/``-1`` when
+    chains are off, so slack-aware policies degrade to their slack-rich
+    branch."""
     n = n_nodes
     tree = jax.tree_util.tree_map
     all_up = jnp.ones((n,), bool)
@@ -560,236 +482,144 @@ def _make_step(routing: jax.Array, unified: jax.Array, cloud: jax.Array,
     return step
 
 
-def _vert_of(pools: PoolState) -> tuple:
-    """The vertical-scaling run totals of a final pool state, as a
-    one-element tuple to splice onto a runner's outputs — empty when
-    resize is off, so resize-off output shapes stay byte-identical.
-    Always the LAST output element (after telemetry and chains)."""
-    if pools.alloc is None:
-        return ()
-    return ((pools.acc_used, pools.acc_alloc, pools.bneck),)
+# --------------------------------------------------------------------------
+# the event body and the two program shapes that scan it
+# --------------------------------------------------------------------------
+
+class Carry(NamedTuple):
+    """The scan carry of both program shapes.  A field whose mechanism is
+    off is ``None`` and vanishes from the pytree, so a run without
+    failures, telemetry or chains carries the pool state alone."""
+
+    pools: PoolState
+    inval: jax.Array | None = None   # i32[N] residents invalidated
+    tel: TelAcc | None = None
+    chain: ChainAcc | None = None
 
 
-def _vert_np(vert) -> dict:
-    """Host-side view of a ``_vert_of`` element: per-pool run totals in
-    the stacked node-major [2N] layout (or [L, 2N] sweep-lane slices) —
-    the JAX twin of the oracle's ``_vertical()`` extras."""
-    acc_used, acc_alloc, bneck = vert
-    return {"acc_used_mb": np.asarray(acc_used, np.float32),
-            "acc_alloc_mb": np.asarray(acc_alloc, np.float32),
-            "bottlenecks": np.asarray(bneck, np.int64)}
+class EventXs(NamedTuple):
+    """One event's scan inputs (stacked on a leading time axis in the
+    programs); a field whose mechanism is off is ``None``."""
+
+    ev: ClusterEvent
+    up: jax.Array | None = None      # bool[N] nodes up (failures)
+    rec: jax.Array | None = None     # bool[N] nodes recovering here
+    widx: jax.Array | None = None    # i32 global telemetry window
+    cxs: ChainXs | None = None       # chain row, stage, last flag
+    ccold: jax.Array | None = None   # bool cloud cold flip (chains)
 
 
-def _run_cluster_impl(pools: PoolState, events: ClusterEvent,
-                      routing: jax.Array, unified: jax.Array,
-                      cloud: jax.Array, widx=None, tel=None, cxs=None,
-                      ccold=None, cdl=None, chain=None, *,
-                      n_nodes: int, mode: str):
-    """The whole trace in one scan.  Returns (node i32[T], outcome
-    i32[T]); with telemetry (``widx``/``tel`` set) the final
-    :class:`TelAcc` rides along, and with chains (``cxs``/``ccold``/
-    ``cdl``/``chain`` set) the final :class:`ChainAcc` comes last —
-    ``tel is None and chain is None`` compiles the exact pre-telemetry,
-    pre-chain program."""
+#: vmap in_axes of :class:`EventXs` in the lane-stacked programs: the
+#: trace (events, window indices, chain structure) is shared by the lanes,
+#: while failure masks and cloud cold draws are each lane's own.  A field
+#: that is ``None`` in the data has no leaves, so its entry is inert.
+_XS_LANES = EventXs(ev=None, up=0, rec=0, widx=None, cxs=None, ccold=0)
+
+
+def _make_event(routing: jax.Array, unified: jax.Array, cloud: jax.Array,
+                cdl: jax.Array | None, n_nodes: int, mode: str):
+    """Build the one event body both program shapes scan.  In order it
+    clears the nodes recovering at the event (failure masks only), routes
+    and steps (:func:`_make_step`), then folds the outcome into the chain
+    accumulator and the telemetry window, each only when on.  ``up_n`` is
+    the live-node mask routing sees (``None`` = all up, which compiles no
+    mask and no select) and ``n_active`` the membership count telemetry
+    records; the epoch grid passes ``up & active`` and its live count."""
     step = _make_step(routing, unified, cloud, n_nodes, mode)
-    tel_on, ch_on = tel is not None, chain is not None
-    if not tel_on and not ch_on:
-        c_end, (nodes, outcomes) = jax.lax.scan(step, pools, events)
-        return (nodes, outcomes) + _vert_of(c_end)
+
+    def event(c: Carry, x: EventXs, up_n, n_active):
+        pools, inval, cnt = c.pools, c.inval, None
+        if x.rec is not None:
+            cnt, pools = _invalidate_nodes(pools, x.rec, n_nodes)
+            inval = inval + cnt
+        chain, slack, stage, miss = c.chain, None, None, jnp.int32(0)
+        if chain is not None:
+            slack, stage = _chain_pre(chain, cdl, x.cxs)
+        pools, (node, outcome) = step(pools, x.ev, up_n, slack, stage)
+        if chain is not None:
+            chain, miss = _chain_event(chain, x.cxs, x.ccold, cdl, x.ev,
+                                       outcome, cloud)
+        tel = c.tel
+        if tel is not None:
+            tel = _tel_event(
+                tel, x.widx, x.ev, outcome, pools, n_nodes,
+                (jnp.int32(n_nodes) if x.up is None
+                 else jnp.sum(x.up).astype(jnp.int32)), n_active,
+                jnp.int32(0) if cnt is None else jnp.sum(cnt), miss)
+        return Carry(pools, inval, tel, chain), (node, outcome)
+
+    return event
+
+
+def _chunk_impl(carry: Carry, xs: EventXs, routing: jax.Array,
+                unified: jax.Array, cloud: jax.Array, cdl=None, *,
+                n_nodes: int, mode: str):
+    """The chunk program: the event body scanned over one chunk of
+    events.  Returns ``(carry, node i32[chunk], outcome i32[chunk])``;
+    the next chunk picks the carry up, and global window indices and chain
+    rows make events land where a whole-trace run would put them.
+    ``cdl`` is the chain deadline vector (chains only)."""
+    event = _make_event(routing, unified, cloud, cdl, n_nodes, mode)
     n_up = jnp.int32(n_nodes)
-
-    def s(carry, x):
-        pools = carry[0]
-        acc = carry[1] if tel_on else None
-        chain = carry[-1] if ch_on else None
-        ev = x[0]
-        wi = x[1] if tel_on else None
-        if ch_on:
-            cx, cc = x[-2], x[-1]
-            slack, stg = _chain_pre(chain, cdl, cx)
-            pools, (node, outcome) = step(pools, ev, None, slack, stg)
-            chain, miss = _chain_event(chain, cx, cc, cdl, ev, outcome,
-                                       cloud)
-        else:
-            pools, (node, outcome) = step(pools, ev)
-            miss = jnp.int32(0)
-        if tel_on:
-            acc = _tel_event(acc, wi, ev, outcome, pools, n_nodes,
-                             n_up, n_up, jnp.int32(0), miss)
-        carry = ((pools,) + ((acc,) if tel_on else ())
-                 + ((chain,) if ch_on else ()))
-        return carry, (node, outcome)
-
-    c0 = ((pools,) + ((tel,) if tel_on else ())
-          + ((chain,) if ch_on else ()))
-    xs = ((events,) + ((widx,) if tel_on else ())
-          + ((cxs, ccold) if ch_on else ()))
-    c_end, (nodes, outcomes) = jax.lax.scan(s, c0, xs)
-    out = (nodes, outcomes)
-    if tel_on:
-        out = out + (c_end[1],)
-    if ch_on:
-        out = out + (c_end[-1],)
-    return out + _vert_of(c_end[0])
+    carry, (nodes, outcomes) = jax.lax.scan(
+        lambda c, x: event(c, x, x.up, n_up), carry, xs)
+    return carry, nodes, outcomes
 
 
-def _run_failures_impl(pools: PoolState, events: ClusterEvent,
-                       up: jax.Array, recover: jax.Array,
-                       routing: jax.Array, unified: jax.Array,
-                       cloud: jax.Array, widx=None, tel=None, cxs=None,
-                       ccold=None, cdl=None, chain=None, *,
-                       n_nodes: int, mode: str):
-    """The failure-injected trace in one scan: ``up``/``recover`` are the
-    bool[T, N] masks compiled host-side from the ``Failures`` schedule
-    (shared verbatim with the oracle).  Each event first clears the pools
-    of any node recovering at it (counting the invalidated residents —
-    the re-warm debt), then routes with ``RouteCtx.node_up = up[t]``.
-    Returns (node i32[T], outcome i32[T], invalidated i32[N]); telemetry
-    appends the final :class:`TelAcc` (recovery invalidations land in the
-    window of the event that observed them) and chains append the final
-    :class:`ChainAcc` last."""
-    step = _make_step(routing, unified, cloud, n_nodes, mode)
-    tel_on, ch_on = tel is not None, chain is not None
+def _epoch_impl(carry: Carry, xs: EventXs, valid: jax.Array,
+                routing: jax.Array, unified: jax.Array, cloud: jax.Array,
+                frac: jax.Array, node_mb: jax.Array, asc: jax.Array,
+                active0: jax.Array, cdl=None, *, n_nodes: int, mode: str):
+    """The epoch grid: an outer scan over epochs, the event body inside
+    each epoch, and a per-node re-split plus a node spawn/retire decision
+    between epochs.
 
-    def s(carry, x):
-        pools, inval = carry[0], carry[1]
-        acc = carry[2] if tel_on else None
-        chain = carry[-1] if ch_on else None
-        ev, u, r = x[0], x[1], x[2]
-        wi = x[3] if tel_on else None
-        cnt, pools = _invalidate_nodes(pools, r, n_nodes)
-        if ch_on:
-            cx, cc = x[-2], x[-1]
-            slack, stg = _chain_pre(chain, cdl, cx)
-            pools, (node, outcome) = step(pools, ev, u, slack, stg)
-            chain, miss = _chain_event(chain, cx, cc, cdl, ev, outcome,
-                                       cloud)
-        else:
-            pools, (node, outcome) = step(pools, ev, u)
-            miss = jnp.int32(0)
-        if tel_on:
-            acc = _tel_event(acc, wi, ev, outcome, pools, n_nodes,
-                             jnp.sum(u).astype(jnp.int32),
-                             jnp.int32(n_nodes), jnp.sum(cnt), miss)
-        carry = ((pools, inval + cnt) + ((acc,) if tel_on else ())
-                 + ((chain,) if ch_on else ()))
-        return carry, (node, outcome)
-
-    inval0 = jnp.zeros((n_nodes,), jnp.int32)
-    c0 = ((pools, inval0) + ((tel,) if tel_on else ())
-          + ((chain,) if ch_on else ()))
-    xs = ((events, up, recover) + ((widx,) if tel_on else ())
-          + ((cxs, ccold) if ch_on else ()))
-    c_end, (nodes, outcomes) = jax.lax.scan(s, c0, xs)
-    out = (nodes, outcomes, c_end[1])
-    if tel_on:
-        out = out + (c_end[2],)
-    if ch_on:
-        out = out + (c_end[-1],)
-    return out + _vert_of(c_end[0])
-
-
-def _run_autoscale_impl(pools: PoolState, events: ClusterEvent,
-                        valid: jax.Array, up: jax.Array, recover: jax.Array,
-                        routing: jax.Array, unified: jax.Array,
-                        cloud: jax.Array, frac: jax.Array,
-                        node_mb: jax.Array, asc: jax.Array,
-                        active0: jax.Array, widx=None, tel=None, cxs=None,
-                        ccold=None, cdl=None, chain=None, *,
-                        n_nodes: int, mode: str, masked: bool = True):
-    """The autoscaled trace: an outer scan over epochs, the existing event
-    scan inside each epoch, and a per-node re-split plus a node
-    spawn/retire decision between epochs.
-
-    ``events`` leaves are shaped ``[E, epoch_events, ...]`` (trace padded
+    ``xs`` leaves are shaped ``[E, epoch_events, ...]`` (trace padded
     with guaranteed-drop no-ops); ``valid`` is f32[E, e] marking real
     events.  Pad events never touch pool state (a drop is a no-op
     transition) and are masked out of the pressure signal here — the
     padding bias that skewed the legacy ``core.adaptive`` split decision
-    cannot arise.  ``up``/``recover`` are the epoch-shaped bool[E, e, N]
-    failure masks; ``masked`` is static so a scenario *without* a failure
-    schedule passes ``None`` masks and compiles a program with zero
-    per-event invalidation work (node scaling alone only reads the
-    membership carry — on all-up masks the masked program computes the
-    identical results, just slower).  ``frac`` is the running f32[N]
-    small-pool fraction, ``asc`` packs (min_frac, max_frac, gain,
-    spawn_drop_frac, retire_drop_frac) as data so sweeps can vmap over
-    them (+/-inf thresholds = node scaling off), and ``active0`` (bool[N])
-    is the starting membership.  Returns (node i32[E, e], outcome
-    i32[E, e], fracs f32[E, N], actives bool[E, N], invalidated i32[N]);
-    telemetry (``widx`` f32[E, e] window indices + a :class:`TelAcc`)
-    appends the final accumulator — retirement invalidations land in the
-    epoch's last real window, recovery invalidations in the window of the
-    event that observed them.  Chains (epoch-shaped ``cxs``/``ccold`` +
-    the deadline vector and a :class:`ChainAcc`) append the final chain
-    accumulator last — pad events land in its junk row.
+    cannot arise.  Without failure masks (``xs.up`` None) the program
+    holds no per-event invalidation work: routing sees the membership
+    alone.  ``carry.inval`` counts recovery and retirement invalidations.
+    ``frac`` is the starting f32[N] small-pool fraction, ``asc`` packs
+    (min_frac, max_frac, gain, spawn_drop_frac, retire_drop_frac) as data
+    so sweeps can vmap over them (+/-inf thresholds = node scaling off),
+    and ``active0`` (bool[N]) is the starting membership.  Returns
+    ``(carry, node i32[E, e], outcome i32[E, e], fracs f32[E, N],
+    actives bool[E, N])``.  Retirement invalidations land in the epoch's
+    last real telemetry window, recovery invalidations in the window of
+    the event that observed them; pad events land in the chain junk row.
     """
-    step = _make_step(routing, unified, cloud, n_nodes, mode)
+    event = _make_event(routing, unified, cloud, cdl, n_nodes, mode)
     tree = jax.tree_util.tree_map
     n = n_nodes
-    tel_on = tel is not None
-    ch_on = chain is not None
     mn, mx, gain, spawn_th, retire_th = (asc[0], asc[1], asc[2], asc[3],
                                          asc[4])
     pool_unified = jnp.repeat(unified, 2)            # bool[2N]
 
-    def epoch(carry, inp):
-        pools, frac, active, inval = (carry[0], carry[1], carry[2],
-                                      carry[3])
-        acc = carry[4] if tel_on else None
-        chain = carry[-1] if ch_on else None
-        evs, val = inp[0], inp[1]
+    def epoch(state, inp):
+        c, frac, active = state
+        x, val = inp
+        n_active = jnp.sum(active.astype(jnp.int32))
 
-        def inner(c, x):
-            pools, press, dropw, inval = c[0], c[1], c[2], c[3]
-            acc = c[4] if tel_on else None
-            chain = c[-1] if ch_on else None
-            ev, v = x[0], x[1]
-            wi = x[2] if tel_on else None
-            k = 3 if tel_on else 2
-            if masked:
-                u, r = x[k], x[k + 1]
-                cnt, pools = _invalidate_nodes(pools, r, n)
-                inval = inval + cnt
-                eff = u & active
-            else:
-                eff = active
-            if ch_on:
-                cx, cc = x[-2], x[-1]
-                slack, stg = _chain_pre(chain, cdl, cx)
-                pools, (node, outcome) = step(pools, ev, eff, slack, stg)
-                chain, miss = _chain_event(chain, cx, cc, cdl, ev,
-                                           outcome, cloud)
-            else:
-                pools, (node, outcome) = step(pools, ev, eff)
-                miss = jnp.int32(0)
+        def inner(ic, iv):
+            c, press, dropw = ic
+            x, v = iv
+            c, (node, outcome) = event(
+                c, x, active if x.up is None else x.up & active, n_active)
             # pressure = misses + 2x drops, per (routed node, size class);
             # pad events carry v == 0 and contribute nothing
             w = v * jnp.where(outcome == MISS, 1.0,
                               jnp.where(outcome == DROP, 2.0, 0.0))
-            press = press.at[node, ev.cls].add(w)
+            press = press.at[node, x.ev.cls].add(w)
             dropw = dropw + v * jnp.where(outcome == DROP, 1.0, 0.0)
-            if tel_on:
-                acc = _tel_event(
-                    acc, wi, ev, outcome, pools, n,
-                    jnp.sum(u).astype(jnp.int32) if masked
-                    else jnp.int32(n),
-                    jnp.sum(active.astype(jnp.int32)),
-                    jnp.sum(cnt) if masked else jnp.int32(0), miss)
-            c = ((pools, press, dropw, inval)
-                 + ((acc,) if tel_on else ()) + ((chain,) if ch_on else ()))
-            return c, (node, outcome)
+            return (c, press, dropw), (node, outcome)
 
-        c0 = ((pools, jnp.zeros((n, 2), jnp.float32), jnp.float32(0.0),
-               inval) + ((acc,) if tel_on else ())
-              + ((chain,) if ch_on else ()))
-        c_end, (nodes, outcomes) = jax.lax.scan(inner, c0, inp)
-        pools, press, dropw, inval = (c_end[0], c_end[1], c_end[2],
-                                      c_end[3])
-        if tel_on:
-            acc = c_end[4]
-        if ch_on:
-            chain = c_end[-1]
+        (c, press, dropw), (nodes, outcomes) = jax.lax.scan(
+            inner, (c, jnp.zeros((n, 2), jnp.float32), jnp.float32(0.0)),
+            (x, val))
+        pools = c.pools
         press_s, press_l = press[:, 0], press[:, 1]
         tot = press_s + press_l
         delta = jnp.where(tot > 0,
@@ -801,7 +631,7 @@ def _run_autoscale_impl(pools: PoolState, events: ClusterEvent,
         is_full = val[-1] > 0
         cand = jnp.minimum(mx, jnp.maximum(frac + delta, mn))
         new_frac = jnp.where(is_full & ~unified, cand, frac)
-        now = jnp.max(jnp.where(val > 0, evs.t, -jnp.inf))
+        now = jnp.max(jnp.where(val > 0, x.ev.t, -jnp.inf))
         caps = jnp.stack([node_mb * new_frac,
                           node_mb * (jnp.float32(1.0) - new_frac)],
                          axis=1).reshape(-1)
@@ -816,7 +646,6 @@ def _run_autoscale_impl(pools: PoolState, events: ClusterEvent,
         # residency decides "emptiest"; at most one node moves per epoch)
         drop_frac = ieee_div(dropw,
                              jnp.maximum(jnp.sum(val), jnp.float32(1.0)))
-        n_active = jnp.sum(active.astype(jnp.int32))
         can_spawn = is_full & (drop_frac > spawn_th) & (n_active < n)
         can_retire = (is_full & ~can_spawn & (drop_frac < retire_th)
                       & (n_active > 1))
@@ -829,53 +658,65 @@ def _run_autoscale_impl(pools: PoolState, events: ClusterEvent,
             jnp.where(can_retire, put(active, cand_retire, False), active))
         retire_mask = (jnp.arange(n) == cand_retire) & can_retire
         cnt, pools = _invalidate_nodes(pools, retire_mask, n)
-        if tel_on:
+        tel = c.tel
+        if tel is not None:
             # retirement invalidations belong to the epoch's last real
             # window (retirement only fires on full epochs, so w_end is
             # always a real index there)
-            w_end = jnp.max(jnp.where(val > 0, inp[2], -1))
-            acc = acc._replace(inval=acc.inval.at[w_end].add(jnp.sum(cnt)))
-        carry = ((pools, new_frac, new_active, inval + cnt)
-                 + ((acc,) if tel_on else ())
-                 + ((chain,) if ch_on else ()))
-        return carry, (nodes, outcomes, new_frac, new_active)
+            w_end = jnp.max(jnp.where(val > 0, x.widx, -1))
+            tel = tel._replace(inval=tel.inval.at[w_end].add(jnp.sum(cnt)))
+        c = c._replace(pools=pools, inval=c.inval + cnt, tel=tel)
+        return (c, new_frac, new_active), (nodes, outcomes, new_frac,
+                                           new_active)
 
-    xs = ((events, valid) + ((widx,) if tel_on else ())
-          + ((up, recover) if masked else ())
-          + ((cxs, ccold) if ch_on else ()))
-    c0 = ((pools, frac, active0, jnp.zeros((n,), jnp.int32))
-          + ((tel,) if tel_on else ()) + ((chain,) if ch_on else ()))
-    c_end, (nodes, outcomes, fracs, actives) = jax.lax.scan(epoch, c0, xs)
-    out = (nodes, outcomes, fracs, actives, c_end[3])
-    if tel_on:
-        out = out + (c_end[4],)
-    if ch_on:
-        out = out + (c_end[-1],)
-    return out + _vert_of(c_end[0])
+    (carry, _, _), (nodes, outcomes, fracs, actives) = jax.lax.scan(
+        epoch, (carry, frac, active0), (xs, valid))
+    return carry, nodes, outcomes, fracs, actives
 
 
-_run_cluster = jax.jit(_run_cluster_impl,
-                       static_argnames=("n_nodes", "mode"))
-
-_run_failures = jax.jit(_run_failures_impl,
-                        static_argnames=("n_nodes", "mode"))
-
-_run_autoscale = jax.jit(_run_autoscale_impl,
-                         static_argnames=("n_nodes", "mode", "masked"))
+#: vmap in_axes of the lane-stacked programs, argument by argument: each
+#: lane has its own carry and config (routing, unified, cloud, autoscale
+#: data, deadlines); the epoch validity mask is shared.
+_CHUNK_LANES = (0, _XS_LANES, 0, 0, 0, 0)
+_EPOCH_LANES = (0, _XS_LANES, None, 0, 0, 0, 0, 0, 0, 0, 0)
 
 
-def _chain_axes(tel: bool, chain: bool) -> tuple:
-    """Trailing vmap in_axes for the optional telemetry + chain args
-    ``(widx, tel, cxs, ccold, cdl, chain)``: window indices and chain
-    event data are shared across lanes; accumulators, cold draws and
-    deadlines are per-lane.  When only chains are on, the telemetry slots
-    are ``None`` args (empty pytrees — any in_axes is harmless)."""
-    axes = ()
-    if tel or chain:
-        axes += (None, 0)          # widx, TelAcc
-    if chain:
-        axes += (None, 0, 0, 0)    # cxs, ccold, cdl, ChainAcc
-    return axes
+def _jit(fn, axes=None, devices: int | None = None):
+    """``fn`` jitted with its carry (argument 0) donated, so a run's pool
+    buffers are reused in place; with lane ``axes``, vmapped over a
+    leading lane axis first and, with ``devices``, sharded across them."""
+    if axes is not None:
+        fn = _shard_lanes(jax.vmap(fn, in_axes=axes), axes, devices)
+    return jax.jit(fn, donate_argnums=(0,))
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_runner(n_nodes: int, mode: str):
+    """The single-lane chunk program.  It stays unbatched: a batched
+    ``lax.cond`` computes both branches, an unbatched one only the taken
+    branch."""
+    return _jit(functools.partial(_chunk_impl, n_nodes=n_nodes, mode=mode))
+
+
+@functools.lru_cache(maxsize=None)
+def _sweep_chunk_runner(n_nodes: int, mode: str, devices: int | None):
+    """The lane-stacked chunk program (``devices`` shards the lanes; the
+    donated carry then lives sharded and is reused shard in place)."""
+    return _jit(functools.partial(_chunk_impl, n_nodes=n_nodes, mode=mode),
+                _CHUNK_LANES, devices)
+
+
+@functools.lru_cache(maxsize=None)
+def _epoch_runner(n_nodes: int, mode: str):
+    """The single-lane epoch grid."""
+    return _jit(functools.partial(_epoch_impl, n_nodes=n_nodes, mode=mode))
+
+
+@functools.lru_cache(maxsize=None)
+def _sweep_epoch_runner(n_nodes: int, mode: str, devices: int | None):
+    """The lane-stacked epoch grid."""
+    return _jit(functools.partial(_epoch_impl, n_nodes=n_nodes, mode=mode),
+                _EPOCH_LANES, devices)
 
 
 # --------------------------------------------------------------------------
@@ -886,13 +727,13 @@ def _chain_axes(tel: bool, chain: bool) -> tuple:
 # SAME vmapped scan on its shard of lanes, so per-lane arithmetic — and
 # hence every per-lane output — is bit-identical to the unsharded run (no
 # cross-lane reductions exist anywhere in the sweep path).  The in_specs
-# mirror the runner's vmap in_axes one-for-one (lane-stacked args split,
+# mirror the program's vmap in_axes one-for-one (lane-stacked args split,
 # shared args replicate; both use the same pytree-prefix rule), and a
 # non-dividing lane count is padded with duplicates of lane 0 — the lane
-# analogue of the guaranteed-drop no-op pad events in ``_epoch_grid``:
+# analogue of the guaranteed-drop no-op pad events of the epoch grid:
 # the pad lanes run real (discarded) work and are sliced off before
 # ``Result`` assembly.  ``devices=None`` skips shard_map entirely, so the
-# single-device runners stay byte-identical to the pre-sharding programs.
+# single-device programs stay byte-identical to the pre-sharding ones.
 
 def _lane_mesh(devices: int) -> Mesh:
     """A 1-D mesh over the first ``devices`` JAX devices; on CPU,
@@ -901,20 +742,20 @@ def _lane_mesh(devices: int) -> Mesh:
     return Mesh(np.asarray(jax.devices()[:devices]), ("lanes",))
 
 
-def _lane_specs(axes: tuple) -> tuple:
-    """shard_map in_specs mirroring a vmap in_axes tuple: lane-stacked
-    args (axis 0) split across the mesh, shared args replicate.  Entries
-    are pytree prefixes, exactly like the in_axes they mirror."""
-    return tuple(PartitionSpec("lanes") if a == 0 else PartitionSpec()
-                 for a in axes)
+def _lane_specs(axes):
+    """shard_map in_specs mirroring a vmap in_axes tree: lane-stacked
+    args (axis 0) split across the mesh, shared args replicate."""
+    return jax.tree_util.tree_map(
+        lambda a: PartitionSpec("lanes") if a == 0 else PartitionSpec(),
+        axes, is_leaf=lambda a: a is None)
 
 
-def _shard_lanes(fn, axes: tuple, devices: int | None):
-    """Wrap a vmapped sweep impl in shard_map over the lane axis (every
-    output of every runner is lane-stacked, hence the blanket out_specs).
-    A no-op when ``devices`` is None.  ``check_vma=False`` because
-    pallas_call (``mode="fused"``) has no replication rule — harmless
-    here since no output is replicated."""
+def _shard_lanes(fn, axes, devices: int | None):
+    """Wrap a vmapped program in shard_map over the lane axis (every
+    output of either program is lane-stacked, hence the blanket
+    out_specs).  A no-op when ``devices`` is None.  ``check_vma=False``
+    because pallas_call (``mode="fused"``) has no replication rule —
+    harmless here since no output is replicated."""
     if devices is None:
         return fn
     return jax.shard_map(fn, mesh=_lane_mesh(devices),
@@ -941,120 +782,199 @@ def _lane_pad(lanes: int, devices: int | None) -> int:
 
 def _pad_tree(tree, pad: int):
     """Append ``pad`` copies of lane 0 along the leading axis of every
-    leaf (zeros stay zeros for accumulators; real configs just duplicate
-    — their outputs are never read)."""
+    leaf, host or device (zeros stay zeros for accumulators; real configs
+    just duplicate — their outputs are never read)."""
     if not pad:
         return tree
-    return jax.tree_util.tree_map(
-        lambda a: jnp.concatenate(
-            [jnp.asarray(a), jnp.repeat(jnp.asarray(a)[:1], pad, axis=0)]),
-        tree)
+
+    def dup(a):
+        xp = np if isinstance(a, np.ndarray) else jnp
+        return xp.concatenate([a, xp.repeat(a[:1], pad, axis=0)])
+    return jax.tree_util.tree_map(dup, tree)
 
 
-def _pad_lanes(args: tuple, axes: tuple, pad: int) -> tuple:
-    """Pad every lane-stacked runner arg (vmap in_axes 0 — same
-    pytree-prefix rule) with lane-0 duplicates; shared args and ``None``
-    placeholders pass through untouched."""
-    if not pad:
-        return args
-    return tuple(_pad_tree(arg, pad) if ax == 0 else arg
-                 for arg, ax in zip(args, axes))
+# --------------------------------------------------------------------------
+# host side: inputs, padding, the chunk loop, results
+# --------------------------------------------------------------------------
+
+def _host_xs(trace: Trace, n_nodes: int, drop_size: float, *,
+             resize: bool = False, masks=None, telemetry: int | None = None,
+             chains: ChainPlan | None = None, ccold=None):
+    """The whole trace's per-event inputs on the host, for the chunk loop
+    to slice or the epoch grid to reshape: the events, and only for the
+    mechanisms that are on the failure ``masks`` (up, recover), the
+    global telemetry window indices, and the chain structure with its
+    cloud cold draws ``ccold`` (``masks`` and ``ccold`` carry a leading
+    lane axis in sweeps).  Returns them with what each pads with past the
+    end of the trace: a guaranteed-drop no-op event (an impossible
+    function id and a size no pool can host, ``drop_size``, so no pool
+    state changes), every node up and none recovering, the junk telemetry
+    window and chain row, and no cold flip."""
+    ev = _host_events(trace, n_nodes, resize=resize)
+    xs = EventXs(
+        ev=ev,
+        up=None if masks is None else masks[0],
+        rec=None if masks is None else masks[1],
+        widx=(None if telemetry is None
+              else np.arange(len(trace), dtype=np.int32) // telemetry),
+        cxs=None if chains is None else _chain_xs(chains),
+        ccold=None if chains is None else ccold)
+    fills = EventXs(
+        ev=ClusterEvent(t=ev.t[-1] if len(ev.t) else 0.0, func_id=-2,
+                        size=drop_size, cls=0, warm=0.0, cold=0.0, h1=0,
+                        h2=0, used=None if ev.used is None else 0.0),
+        up=True, rec=False,
+        widx=None if telemetry is None else _n_windows(len(trace),
+                                                       telemetry),
+        cxs=ChainXs(cid=None if chains is None else chains.n_chains,
+                    stage=-1, last=False), ccold=False)
+    return xs, EventXs(*(None if a is None else f
+                         for a, f in zip(xs, fills)))
 
 
-def _sweep_axes(tel: bool, chain: bool) -> tuple:
-    return (0, None, 0, 0, 0) + _chain_axes(tel, chain)
+def _pad_xs(xs: EventXs, pad: int) -> EventXs:
+    """A sweep's inputs with ``pad`` more lanes in the per-lane fields."""
+    return EventXs(*(a if ax is None else _pad_tree(a, pad)
+                     for a, ax in zip(xs, _XS_LANES)))
 
 
-def _sweep_failures_axes(tel: bool, chain: bool) -> tuple:
-    return (0, None, 0, 0, 0, 0, 0) + _chain_axes(tel, chain)
+def _per_field(fn, xs: EventXs, lanes: bool, *rest) -> EventXs:
+    """``fn(array, *rest_arrays, axis=t)`` over every array of ``xs``, ``t``
+    being its time axis: 1 for a sweep's lane-stacked fields, else 0."""
+    return EventXs(*(
+        None if a is None else jax.tree_util.tree_map(
+            functools.partial(fn, axis=int(lanes and ax == 0)), a, *r)
+        for a, ax, *r in zip(xs, _XS_LANES, *rest)))
 
 
-def _sweep_autoscale_axes(masked: bool, tel: bool, chain: bool) -> tuple:
-    return ((0, None, None, 0 if masked else None,
-             0 if masked else None, 0, 0, 0, 0, 0, 0, 0)
-            + _chain_axes(tel, chain))
+def _xs_slice(xs: EventXs, fills: EventXs, s: int, e: int, length: int,
+              lanes: bool = False) -> EventXs:
+    """Events ``[s, e)`` of the host inputs, padded with ``fills`` to
+    ``length`` events."""
+    def cut(a, fill, axis):
+        sl = a[(slice(None),) * axis + (slice(s, e),)]
+        pad = length - (e - s)
+        if not pad:
+            return sl
+        shape = list(sl.shape)
+        shape[axis] = pad
+        return np.concatenate([sl, np.full(shape, fill, a.dtype)],
+                              axis=axis)
+    return _per_field(cut, xs, lanes, fills)
 
 
-@functools.lru_cache(maxsize=None)
-def _sweep_runner(n_nodes: int, mode: str, tel: bool = False,
-                  chain: bool = False, devices: int | None = None):
-    """Cached jitted vmap of the scan, keyed on the static shape args, so
-    repeated sweep calls hit the compile cache like ``_run_cluster``
-    does.  ``tel`` lanes share the window-index data and stack their
-    accumulators; ``chain`` lanes share the chain event data and stack
-    their accumulators, cold draws and deadlines.  ``devices`` shards the
-    lane axis across a device mesh (None = the exact single-device
-    program)."""
-    axes = _sweep_axes(tel, chain)
-    return jax.jit(_shard_lanes(jax.vmap(
-        functools.partial(_run_cluster_impl, n_nodes=n_nodes, mode=mode),
-        in_axes=axes), axes, devices))
-
-
-@functools.lru_cache(maxsize=None)
-def _sweep_failures_runner(n_nodes: int, mode: str, tel: bool = False,
-                           chain: bool = False,
-                           devices: int | None = None):
-    """Failure analogue of ``_sweep_runner``: every lane carries its own
-    compiled up/recover masks as data (same [T, N] shape — lanes bucket by
-    mask shape), so mixed failure schedules sweep in one program."""
-    axes = _sweep_failures_axes(tel, chain)
-    return jax.jit(_shard_lanes(jax.vmap(
-        functools.partial(_run_failures_impl, n_nodes=n_nodes, mode=mode),
-        in_axes=axes), axes, devices))
-
-
-@functools.lru_cache(maxsize=None)
-def _sweep_autoscale_runner(n_nodes: int, mode: str, masked: bool,
-                            tel: bool = False, chain: bool = False,
-                            devices: int | None = None):
-    """Autoscale analogue of ``_sweep_runner``: configs (pools, masks,
-    routing, unified, cloud, frac, node_mb, asc thresholds, active0) vmap
-    as data; the epoch grid and validity mask are shared across lanes.
-    ``masked`` lanes carry per-lane failure masks; unmasked lanes pass
-    ``None`` masks and compile the cheap no-invalidation program."""
-    axes = _sweep_autoscale_axes(masked, tel, chain)
-    return jax.jit(_shard_lanes(jax.vmap(
-        functools.partial(_run_autoscale_impl, n_nodes=n_nodes, mode=mode,
-                          masked=masked),
-        in_axes=axes), axes, devices))
-
-
-def _epoch_grid(events: ClusterEvent, n_events: int, epoch_events: int,
-                drop_size: float):
-    """Pad the trace to a whole number of epochs and reshape to [E, e].
-
-    Pad events are guaranteed-drop no-ops: an impossible function id and a
-    size larger than any pool, so ``pool_step`` leaves every pool state
-    untouched.  Returns (epoch-shaped events, valid f32[E, e]); the f32
-    mask doubles as the pressure weight inside the scan.
-    """
+def _xs_grid(xs: EventXs, fills: EventXs, n_events: int,
+             epoch_events: int, lanes: bool = False):
+    """The host inputs padded to whole epochs and reshaped to [E, e] on
+    the device, with the f32[E, e] mask of real events (which doubles as
+    the pressure weight)."""
     e = epoch_events
-    n_epochs = -(-n_events // e)
-    pad = n_epochs * e - n_events
-    if pad:
-        last_t = events.t[-1] if n_events else jnp.float32(0.0)
-        fills = ClusterEvent(
-            t=last_t, func_id=-2, size=drop_size, cls=0, warm=0.0, cold=0.0,
-            h1=0, h2=0,
-            used=None if events.used is None else 0.0)
-        events = jax.tree_util.tree_map(
-            lambda a, f: jnp.concatenate(
-                [a, jnp.full((pad,), f, a.dtype)]), events, fills)
-    epochs = jax.tree_util.tree_map(
-        lambda a: a.reshape(n_epochs, e), events)
-    valid = jnp.concatenate(
-        [jnp.ones(n_events, jnp.float32),
-         jnp.zeros(pad, jnp.float32)]).reshape(n_epochs, e)
-    return epochs, valid
+    n_ep = -(-n_events // e)
+
+    def split(a, axis):
+        return jnp.asarray(
+            a.reshape(a.shape[:axis] + (n_ep, e) + a.shape[axis + 1:]))
+    grid = _per_field(split, _xs_slice(xs, fills, 0, n_events, n_ep * e,
+                                       lanes), lanes)
+    valid = np.arange(n_ep * e) < n_events
+    return grid, jnp.asarray(valid.reshape(n_ep, e), jnp.float32)
+
+
+def _carry0(pools: PoolState, n_nodes: int, inval: bool,
+            n_windows: int | None, plan: ChainPlan | None,
+            lanes: tuple = ()) -> Carry:
+    """The carry a run starts from: ``pools`` and zeroed accumulators for
+    the mechanisms that are on (``lanes=(L,)`` stacks one per lane)."""
+    return Carry(
+        pools,
+        inval=jnp.zeros((*lanes, n_nodes), jnp.int32) if inval else None,
+        tel=None if n_windows is None else _tel_init(n_windows, n_nodes,
+                                                     lanes),
+        chain=None if plan is None else _chain_init(plan.n_chains, lanes))
+
+
+def _chunk_loop(run, carry: Carry, xs: EventXs, fills: EventXs,
+                data: tuple, t_len: int, chunk: int,
+                lanes: int | None = None, placement: dict | None = None):
+    """Run the host inputs ``xs`` through the chunk program ``run``
+    ``chunk`` events at a time, threading the donated carry, under the
+    host-loop spans: per chunk a ``sim.chunk`` holding ``sim.slice``,
+    ``sim.dispatch``, ``sim.wait`` and ``sim.fetch``.  ``data`` is the
+    per-lane config the program takes after the inputs; ``lanes`` is the
+    real lane count of a lane-stacked run (None = single lane).  Returns
+    the final carry and the node and outcome arrays, [T] or [lanes, T]."""
+    shape = (t_len,) if lanes is None else (lanes, t_len)
+    nodes_out = np.empty(shape, np.int32)
+    outcomes_out = np.empty(shape, np.int32)
+    for i, s in enumerate(range(0, t_len, chunk)):
+        e = min(s + chunk, t_len)
+        with TraceAnnotation("sim.chunk", index=i, events=e - s,
+                             pad=chunk - (e - s)):
+            with TraceAnnotation("sim.slice"):
+                x = _xs_slice(xs, fills, s, e, chunk, lanes is not None)
+            with TraceAnnotation("sim.dispatch",
+                                 h2d_bytes=_host_nbytes(x)):
+                carry, nodes, outcomes = run(carry, x, *data)
+            with TraceAnnotation("sim.wait"):
+                jax.block_until_ready((nodes, outcomes))
+            if lanes is None:
+                with TraceAnnotation("sim.fetch",
+                                     d2h_bytes=2 * nodes_out[s:e].nbytes):
+                    nodes_out[s:e] = np.asarray(nodes[:e - s])
+                    outcomes_out[s:e] = np.asarray(outcomes[:e - s])
+            else:
+                with TraceAnnotation(
+                        "sim.fetch", d2h_bytes=nodes.nbytes + outcomes.nbytes):
+                    nodes_out[:, s:e] = _lanes_np(nodes, placement)[
+                        :lanes, :e - s]
+                    outcomes_out[:, s:e] = np.asarray(outcomes)[:lanes,
+                                                                :e - s]
+    return carry, nodes_out, outcomes_out
+
+
+def _carry_np(c: Carry) -> tuple:
+    """Host copy of what a run reports from its final carry: the pools'
+    vertical-scaling run totals (resize only), the invalidation counts,
+    and the telemetry and chain accumulators — ``None`` where off."""
+    vert = (None if c.pools.alloc is None
+            else (c.pools.acc_used, c.pools.acc_alloc, c.pools.bneck))
+    return jax.device_get((vert, c.inval, c.tel, c.chain))
+
+
+def _extras(host: tuple, n_windows: int | None,
+            plan: ChainPlan | None) -> dict:
+    """One lane's extras from its :func:`_carry_np` fields."""
+    vert, inval, tel, chain = host
+    extras = {}
+    if tel is not None:
+        extras["telemetry"] = _tel_np(tel, n_windows)
+    if chain is not None:
+        extras["chains"] = _chain_np(chain, plan.n_chains)
+    if vert is not None:
+        # the stacked node-major [2N] layout — the JAX twin of the
+        # oracle's ``_vertical()`` extras
+        acc_used, acc_alloc, bneck = vert
+        extras["vertical"] = {
+            "acc_used_mb": np.asarray(acc_used, np.float32),
+            "acc_alloc_mb": np.asarray(acc_alloc, np.float32),
+            "bottlenecks": np.asarray(bneck, np.int64)}
+    if inval is not None:
+        extras["invalidated"] = np.asarray(inval, np.int64)
+    return extras
+
+
+def _lane(tree, g: int):
+    """Lane ``g`` of a lane-stacked host tree."""
+    return jax.tree_util.tree_map(lambda a: a[g], tree)
 
 
 def _autoscale_inputs(cfg: ClusterConfig, asc: Autoscale):
-    """The per-config data the autoscaled scan consumes beyond the static
-    scan's (routing, unified, cloud): initial fracs, node capacities, the
-    (min_frac, max_frac, gain, spawn, retire) vector (+/-inf thresholds
-    encode "node scaling off" — the decision arithmetic runs identically
-    and never fires), and the initial membership — all vmappable data."""
+    """The per-config data the epoch grid consumes beyond the chunk
+    program's (routing, unified, cloud): initial fracs, node capacities,
+    the (min_frac, max_frac, gain, spawn, retire) vector (+/-inf
+    thresholds encode "node scaling off" — the decision arithmetic runs
+    identically and never fires), and the initial membership — all
+    vmappable data."""
     n = cfg.n_nodes
     spawn = asc.spawn_drop_frac if asc.node_scaled else np.inf
     retire = asc.retire_drop_frac if asc.node_scaled else -np.inf
@@ -1077,18 +997,33 @@ def _failure_masks(failures: Failures | None, trace: Trace, n_nodes: int):
     return failures.masks(np.asarray(trace.t), n_nodes)
 
 
-def _mask_grid(mask: np.ndarray, n_events: int, epoch_events: int,
-               fill: bool):
-    """Pad a per-event [T, N] mask to whole epochs and reshape to
-    [E, e, N] — the mask analogue of :func:`_epoch_grid` (pad rows are
-    all-up / never-recovering so pad events stay no-ops)."""
-    e = epoch_events
-    n_epochs = -(-n_events // e)
-    pad = n_epochs * e - n_events
-    if pad:
-        mask = np.concatenate(
-            [mask, np.full((pad, mask.shape[1]), fill, bool)])
-    return jnp.asarray(mask.reshape(n_epochs, e, mask.shape[1]))
+def _lane_masks(failures, trace: Trace, n_nodes: int, lanes: int,
+                what: str):
+    """A sweep's lane-stacked (up, recover) bool[L, T, N] masks from one
+    ``Failures`` (or None) per config; ``None`` when ``failures`` is."""
+    if failures is None:
+        return None
+    failures = list(failures)
+    if len(failures) != lanes:
+        raise ValueError(f"{what}: need one Failures (or None) per config")
+    masks = [_failure_masks(f, trace, n_nodes) for f in failures]
+    return (np.stack([m[0] for m in masks]), np.stack([m[1] for m in masks]))
+
+
+def _lane_plan(chains, lanes: int):
+    """The chain structure a sweep's lanes share and their stacked
+    deadlines, from one ``ChainPlan`` per config; ``(None, None)``
+    without chains."""
+    if chains is None:
+        return None, None
+    chains = list(chains)
+    if len(chains) != lanes or any(p is None for p in chains):
+        raise ValueError("chain sweep: need one ChainPlan per config")
+    plan = chains[0]
+    if any(p.n_chains != plan.n_chains for p in chains):
+        raise ValueError("chain sweep: lanes must share the trace's "
+                         "chain structure")
+    return plan, jnp.asarray(np.stack([p.deadline for p in chains]))
 
 
 def _result_counts(cfg: ClusterConfig, trace: Trace, node: np.ndarray,
@@ -1125,80 +1060,25 @@ def _cloud_vec(cfg: ClusterConfig) -> jnp.ndarray:
     return jnp.asarray([cfg.cloud_rtt_s, cfg.cloud_cold_prob], jnp.float32)
 
 
-# The implementations below are shared by the deprecated public names and
-# the ``repro.sim`` front door (which must not trip its own deprecation
-# warnings).
-
-def _simulate_cluster_jax(cfg: ClusterConfig, trace: Trace,
-                          rng_seed: int = 0, mode: str = "gather",
-                          telemetry: int | None = None,
-                          chains: ChainPlan | None = None):
-    """Returns the ``ClusterResult`` — or, with ``telemetry`` (a window
-    length in events) and/or ``chains`` (a compiled :class:`ChainPlan`),
-    ``(result, extras)`` with ``"telemetry"`` window arrays /
-    ``"chains"`` per-chain arrays."""
-    check_step_mode(mode)
-    rz_on = cfg.resize_policy is not None
-    with TraceAnnotation("sim.prep", nodes=cfg.n_nodes):
-        events = cluster_events(trace, cfg.n_nodes, resize=rz_on)
-        cloud_cold = cloud_cold_draws(len(trace), cfg.cloud_cold_prob,
-                                      rng_seed)
-        args = (init_cluster(cfg), events, jnp.int32(int(cfg.routing)),
-                jnp.asarray(cfg.unified, bool), _cloud_vec(cfg))
-        n_w = (None if telemetry is None
-               else _n_windows(len(trace), telemetry))
-        if telemetry is not None or chains is not None:
-            args = args + ((None, None) if telemetry is None else
-                           (_widx(len(trace), telemetry),
-                            _tel_init(n_w, cfg.n_nodes)))
-        if chains is not None:
-            args = args + (_chain_xs(chains), jnp.asarray(cloud_cold),
-                           jnp.asarray(chains.deadline),
-                           _chain_init(chains.n_chains))
-    with TraceAnnotation("sim.dispatch", h2d_bytes=_host_nbytes(args)):
-        outs = _run_cluster(*args, n_nodes=cfg.n_nodes, mode=mode)
-    with TraceAnnotation("sim.wait"):
-        jax.block_until_ready(outs[:2])
-    with TraceAnnotation("sim.fetch",
-                         d2h_bytes=outs[0].nbytes + outs[1].nbytes):
-        node, outcome = np.asarray(outs[0]), np.asarray(outs[1])
-    with TraceAnnotation("sim.result",
-                         **_result_counts(cfg, trace, node, outcome)):
-        result = build_result(cfg, trace, node, outcome, cloud_cold)
-        if telemetry is None and chains is None and not rz_on:
-            return result
-        extras = {}
-        if telemetry is not None:
-            extras["telemetry"] = _tel_np(outs[2], n_w)
-        if chains is not None:
-            extras["chains"] = _chain_np(outs[-2] if rz_on else outs[-1],
-                                         chains.n_chains)
-        if rz_on:
-            extras["vertical"] = _vert_np(outs[-1])
-        return result, extras
+def _drop_size(cfg: ClusterConfig) -> float:
+    """A pad-event size no pool of this cluster can ever host, even after
+    the autoscaler grows it to the whole node."""
+    return float(max(cfg.node_mb)) * 10.0
 
 
-def _simulate_cluster_ref(cfg: ClusterConfig, trace: Trace,
-                          rng_seed: int = 0,
-                          telemetry: int | None = None,
-                          chains: ChainPlan | None = None):
-    cloud_cold = cloud_cold_draws(len(trace), cfg.cloud_cold_prob, rng_seed)
-    out = cluster_outcomes_ref(cfg, trace, telemetry=telemetry,
-                               chains=chains,
-                               chain_cold=(cloud_cold if chains is not None
-                                           else None))
-    if (telemetry is None and chains is None
-            and cfg.resize_policy is None):
-        node, outcome = out
-        return build_result(cfg, trace, node, outcome, cloud_cold)
-    node, outcome, extras = out
-    return build_result(cfg, trace, node, outcome, cloud_cold), extras
+def _lane_data(cfg: ClusterConfig, plan: ChainPlan | None) -> tuple:
+    """One config's program inputs after the events: routing code,
+    unified flags, cloud pricing, and the chain deadlines (None without
+    chains)."""
+    return (jnp.int32(int(cfg.routing)), jnp.asarray(cfg.unified, bool),
+            _cloud_vec(cfg),
+            None if plan is None else jnp.asarray(plan.deadline))
 
 
 def _stack_configs(configs, what: str):
     """Validate the shared stacked shapes (``n_nodes``/``max_slots``) and
     stack the per-config scan inputs — the one place both sweep
-    entrypoints (static and autoscaled) build their vmapped data from."""
+    drivers (chunk program and epoch grid) build their vmapped data from."""
     configs = list(configs)
     if not configs:
         raise ValueError(f"{what}: configs must be non-empty")
@@ -1221,664 +1101,130 @@ def _stack_configs(configs, what: str):
     return configs, n, pools, routing, unified, cloud
 
 
-def _stack_tel(n_windows: int, n_nodes: int, lanes: int) -> TelAcc:
-    """One zeroed accumulator per sweep lane, stacked on a leading axis
-    (lanes in a group share the window count, so the stack is dense)."""
-    return jax.tree_util.tree_map(
-        lambda a: jnp.zeros((lanes,) + a.shape, a.dtype),
-        _tel_init(n_windows, n_nodes))
-
-
-def _sweep_chain_data(chains, configs, t_len: int, rng_seed: int):
-    """Stacked per-lane chain inputs for a sweep bucket: one
-    ``ChainPlan`` per config (same trace -> shared event structure),
-    per-lane deadlines and per-lane common-random-number cloud cold
-    draws.  Returns ``(plan, clouds, chain_args)``."""
-    chains = list(chains)
-    if len(chains) != len(configs) or any(p is None for p in chains):
-        raise ValueError("chain sweep: need one ChainPlan per config")
-    plan = chains[0]
-    if any(p.n_chains != plan.n_chains for p in chains):
-        raise ValueError("chain sweep: lanes must share the trace's "
-                         "chain structure")
-    clouds = [cloud_cold_draws(t_len, c.cloud_cold_prob, rng_seed)
-              for c in configs]
-    chain_args = (_chain_xs(plan), jnp.asarray(np.stack(clouds)),
-                  jnp.asarray(np.stack([p.deadline for p in chains])),
-                  _stack_chain(plan.n_chains, len(configs)))
-    return plan, clouds, chain_args
-
-
-def _sweep_cluster(trace: Trace, configs, rng_seed: int = 0,
-                   mode: str = "gather", telemetry: int | None = None,
-                   chains=None, devices: int | None = None,
-                   placement: dict | None = None):
-    """Returns one ``ClusterResult`` per config — or, with ``telemetry``
-    and/or ``chains`` (one compiled ``ChainPlan`` per config), one
-    ``(result, extras)`` pair per config.  ``devices`` shards the lane
-    axis across a device mesh (results stay bit-identical; pad lanes are
-    sliced off here by never reading their rows); ``placement`` receives
-    the lane rows per device (see :func:`_lanes_np`)."""
-    check_step_mode(mode)
-    devices = check_devices(devices)
-    configs, n, pools, routing, unified, cloud = _stack_configs(
-        configs, "sweep_cluster")
-    rz_on = configs[0].resize_policy is not None
-    events = cluster_events(trace, n, resize=rz_on)
-    tel_on, ch_on = telemetry is not None, chains is not None
-    args = (pools, events, routing, unified, cloud)
-    n_w = None if not tel_on else _n_windows(len(trace), telemetry)
-    if tel_on or ch_on:
-        args = args + ((None, None) if not tel_on else
-                       (_widx(len(trace), telemetry),
-                        _stack_tel(n_w, n, len(configs))))
-    if ch_on:
-        plan, clouds, chain_args = _sweep_chain_data(
-            chains, configs, len(trace), rng_seed)
-        args = args + chain_args
-    args = _pad_lanes(args, _sweep_axes(tel_on, ch_on),
-                      _lane_pad(len(configs), devices))
-    outs = _sweep_runner(n, mode, tel=tel_on, chain=ch_on,
-                         devices=devices)(*args)
-    nodes, outcomes = _lanes_np(outs[0], placement), np.asarray(outs[1])
-    out = []
-    for g, c in enumerate(configs):
-        cc = (clouds[g] if ch_on
-              else cloud_cold_draws(len(trace), c.cloud_cold_prob,
-                                    rng_seed))
-        res = build_result(c, trace, nodes[g], outcomes[g], cc)
-        extras = {}
-        if tel_on:
-            lane = jax.tree_util.tree_map(lambda a: a[g], outs[2])
-            extras["telemetry"] = _tel_np(lane, n_w)
-        if ch_on:
-            lane = jax.tree_util.tree_map(
-                lambda a: a[g], outs[-2] if rz_on else outs[-1])
-            extras["chains"] = _chain_np(lane, plan.n_chains)
-        if rz_on:
-            extras["vertical"] = _vert_np(
-                tuple(np.asarray(a)[g] for a in outs[-1]))
-        out.append((res, extras) if extras else res)
-    return out
-
-
-def _drop_size(cfg: ClusterConfig) -> float:
-    """A pad-event size no pool of this cluster can ever host, even after
-    the autoscaler grows it to the whole node."""
-    return float(max(cfg.node_mb)) * 10.0
-
-
-def _simulate_cluster_failures_jax(
-        cfg: ClusterConfig, failures: Failures, trace: Trace,
-        rng_seed: int = 0, mode: str = "gather",
-        telemetry: int | None = None,
-        chains: ChainPlan | None = None) -> tuple[ClusterResult, dict]:
-    """Failure-injected twin of :func:`_simulate_cluster_jax`: returns
-    (ClusterResult, extras) with the compiled ``node_up`` mask and the
-    per-node ``invalidated`` resident counts (plus ``"telemetry"`` window
-    arrays / ``"chains"`` per-chain arrays when requested)."""
-    check_step_mode(mode)
-    rz_on = cfg.resize_policy is not None
-    up, recover = _failure_masks(failures, trace, cfg.n_nodes)
-    cloud_cold = cloud_cold_draws(len(trace), cfg.cloud_cold_prob, rng_seed)
-    tel_on, ch_on = telemetry is not None, chains is not None
-    args = (init_cluster(cfg),
-            cluster_events(trace, cfg.n_nodes, resize=rz_on),
-            jnp.asarray(up), jnp.asarray(recover),
-            jnp.int32(int(cfg.routing)), jnp.asarray(cfg.unified, bool),
-            _cloud_vec(cfg))
-    n_w = None if not tel_on else _n_windows(len(trace), telemetry)
-    if tel_on or ch_on:
-        args = args + ((None, None) if not tel_on else
-                       (_widx(len(trace), telemetry),
-                        _tel_init(n_w, cfg.n_nodes)))
-    if ch_on:
-        args = args + (_chain_xs(chains), jnp.asarray(cloud_cold),
-                       jnp.asarray(chains.deadline),
-                       _chain_init(chains.n_chains))
-    outs = _run_failures(*args, n_nodes=cfg.n_nodes, mode=mode)
-    node, outcome, inval = outs[0], outs[1], outs[2]
-    extras = {}
-    if tel_on:
-        extras["telemetry"] = _tel_np(outs[3], n_w)
-    if ch_on:
-        extras["chains"] = _chain_np(outs[-2] if rz_on else outs[-1],
-                                     chains.n_chains)
-    if rz_on:
-        extras["vertical"] = _vert_np(outs[-1])
-    extras.update(invalidated=np.asarray(inval, np.int64), node_up=up)
-    return (build_result(cfg, trace, np.asarray(node), np.asarray(outcome),
-                         cloud_cold), extras)
-
-
-def _simulate_cluster_failures_ref(
-        cfg: ClusterConfig, failures: Failures, trace: Trace,
-        rng_seed: int = 0, telemetry: int | None = None,
-        chains: ChainPlan | None = None) -> tuple[ClusterResult, dict]:
-    cloud_cold = cloud_cold_draws(len(trace), cfg.cloud_cold_prob, rng_seed)
-    node, outcome, extras = cluster_outcomes_ref(
-        cfg, trace, failures=failures, telemetry=telemetry, chains=chains,
-        chain_cold=(cloud_cold if chains is not None else None))
-    return build_result(cfg, trace, node, outcome, cloud_cold), extras
-
-
-def _sweep_cluster_failures(
-        trace: Trace, configs, failures, rng_seed: int = 0,
-        mode: str = "gather", telemetry: int | None = None,
-        chains=None, devices: int | None = None,
-        placement: dict | None = None) -> list[tuple[ClusterResult, dict]]:
-    """Vmapped sweep over failure-injected configs: each lane's compiled
-    up/recover masks ride as data (lanes bucket by mask shape, which the
-    shared trace and ``n_nodes`` pin)."""
-    check_step_mode(mode)
-    devices = check_devices(devices)
-    failures = list(failures)
-    configs, n, pools, routing, unified, cloud = _stack_configs(
-        configs, "failure sweep")
-    if len(configs) != len(failures):
-        raise ValueError("failure sweep: need one Failures per config")
-    masks = [_failure_masks(f, trace, n) for f in failures]
-    up = np.stack([m[0] for m in masks])
-    recover = np.stack([m[1] for m in masks])
-    tel_on, ch_on = telemetry is not None, chains is not None
-    rz_on = configs[0].resize_policy is not None
-    args = (pools, cluster_events(trace, n, resize=rz_on),
-            jnp.asarray(up), jnp.asarray(recover), routing, unified, cloud)
-    n_w = None if not tel_on else _n_windows(len(trace), telemetry)
-    if tel_on or ch_on:
-        args = args + ((None, None) if not tel_on else
-                       (_widx(len(trace), telemetry),
-                        _stack_tel(n_w, n, len(configs))))
-    if ch_on:
-        plan, clouds, chain_args = _sweep_chain_data(
-            chains, configs, len(trace), rng_seed)
-        args = args + chain_args
-    args = _pad_lanes(args, _sweep_failures_axes(tel_on, ch_on),
-                      _lane_pad(len(configs), devices))
-    outs = _sweep_failures_runner(n, mode, tel=tel_on, chain=ch_on,
-                                  devices=devices)(*args)
-    nodes, outcomes = _lanes_np(outs[0], placement), np.asarray(outs[1])
-    invals = np.asarray(outs[2], np.int64)
-    out = []
-    for g, c in enumerate(configs):
-        extras = {"invalidated": invals[g], "node_up": up[g]}
-        if tel_on:
-            lane = jax.tree_util.tree_map(lambda a: a[g], outs[3])
-            extras["telemetry"] = _tel_np(lane, n_w)
-        if ch_on:
-            lane = jax.tree_util.tree_map(
-                lambda a: a[g], outs[-2] if rz_on else outs[-1])
-            extras["chains"] = _chain_np(lane, plan.n_chains)
-        if rz_on:
-            extras["vertical"] = _vert_np(
-                tuple(np.asarray(a)[g] for a in outs[-1]))
-        cc = (clouds[g] if ch_on
-              else cloud_cold_draws(len(trace), c.cloud_cold_prob,
-                                    rng_seed))
-        out.append((build_result(c, trace, nodes[g], outcomes[g], cc),
-                    extras))
-    return out
-
-
 # --------------------------------------------------------------------------
-# chunked-scan execution mode: million-invocation replays, bounded memory
+# drivers: each returns (ClusterResult, extras) per config — the epoch
+# grid's with the split trajectory between them
 # --------------------------------------------------------------------------
-# ``simulate(..., chunk_events=...)`` splits the trace host-side into
-# fixed-size chunks and runs each through the SAME per-event scan step,
-# threading the pool state (and, with failures, the invalidation counters)
-# between chunks as a donated carry.  ``lax.scan`` is sequential, so a
-# chunked run is bit-identical to the monolithic scan by construction —
-# regression-tested in tests/test_replay.py — while peak device memory is
-# bounded by one chunk of events + outputs instead of the whole trace.
-# The final partial chunk is padded with the same guaranteed-drop no-op
-# events the autoscale epoch grid uses (they never touch pool state) so
-# every chunk runs the one compiled program.
+# The implementations below are shared by the deprecated public names and
+# the ``repro.sim`` front door (which must not trip its own deprecation
+# warnings).
 
-def _run_cluster_chunk_impl(carry, events: ClusterEvent,
-                            routing: jax.Array, unified: jax.Array,
-                            cloud: jax.Array, widx=None, cxs=None,
-                            ccold=None, cdl=None, *,
-                            n_nodes: int, mode: str):
-    """One chunk of the static trace — ``_run_cluster_impl`` that also
-    returns the final carry so the next chunk can pick it up.  The carry
-    is the pool state, extended to ``(pools[, TelAcc][, ChainAcc])`` with
-    telemetry (``widx`` set) and/or chains (``cxs`` set): global window
-    indices and the threaded chain accumulator make events land in the
-    same windows / chain rows a monolithic scan would."""
-    step = _make_step(routing, unified, cloud, n_nodes, mode)
-    tel_on, ch_on = widx is not None, cxs is not None
-    if not tel_on and not ch_on:
-        carry, (nodes, outcomes) = jax.lax.scan(step, carry, events)
-        return carry, nodes, outcomes
-    n_up = jnp.int32(n_nodes)
-
-    def s(c, x):
-        pools = c[0]
-        acc = c[1] if tel_on else None
-        chain = c[-1] if ch_on else None
-        ev = x[0]
-        if ch_on:
-            cx, cc = x[-2], x[-1]
-            slack, stg = _chain_pre(chain, cdl, cx)
-            pools, (node, outcome) = step(pools, ev, None, slack, stg)
-            chain, miss = _chain_event(chain, cx, cc, cdl, ev, outcome,
-                                       cloud)
-        else:
-            pools, (node, outcome) = step(pools, ev)
-            miss = jnp.int32(0)
-        if tel_on:
-            acc = _tel_event(acc, x[1], ev, outcome, pools, n_nodes,
-                             n_up, n_up, jnp.int32(0), miss)
-        nc = ((pools,) + ((acc,) if tel_on else ())
-              + ((chain,) if ch_on else ()))
-        return nc, (node, outcome)
-
-    xs = ((events,) + ((widx,) if tel_on else ())
-          + ((cxs, ccold) if ch_on else ()))
-    carry, (nodes, outcomes) = jax.lax.scan(s, carry, xs)
-    return carry, nodes, outcomes
-
-
-def _run_failures_chunk_impl(carry, events: ClusterEvent, up: jax.Array,
-                             recover: jax.Array, routing: jax.Array,
-                             unified: jax.Array, cloud: jax.Array,
-                             widx=None, cxs=None, ccold=None, cdl=None,
-                             *, n_nodes: int, mode: str):
-    """One chunk of the failure-injected trace; the carry is
-    ``(pools, invalidated i32[N][, TelAcc][, ChainAcc])``."""
-    step = _make_step(routing, unified, cloud, n_nodes, mode)
-    tel_on, ch_on = widx is not None, cxs is not None
-
-    def s(c, x):
-        pools, inval = c[0], c[1]
-        acc = c[2] if tel_on else None
-        chain = c[-1] if ch_on else None
-        ev, u, r = x[0], x[1], x[2]
-        cnt, pools = _invalidate_nodes(pools, r, n_nodes)
-        if ch_on:
-            cx, cc = x[-2], x[-1]
-            slack, stg = _chain_pre(chain, cdl, cx)
-            pools, (node, outcome) = step(pools, ev, u, slack, stg)
-            chain, miss = _chain_event(chain, cx, cc, cdl, ev, outcome,
-                                       cloud)
-        else:
-            pools, (node, outcome) = step(pools, ev, u)
-            miss = jnp.int32(0)
-        if tel_on:
-            acc = _tel_event(acc, x[3], ev, outcome, pools, n_nodes,
-                             jnp.sum(u).astype(jnp.int32),
-                             jnp.int32(n_nodes), jnp.sum(cnt), miss)
-        nc = ((pools, inval + cnt) + ((acc,) if tel_on else ())
-              + ((chain,) if ch_on else ()))
-        return nc, (node, outcome)
-
-    xs = ((events, up, recover) + ((widx,) if tel_on else ())
-          + ((cxs, ccold) if ch_on else ()))
-    carry, (nodes, outcomes) = jax.lax.scan(s, carry, xs)
-    return carry, nodes, outcomes
-
-
-@functools.lru_cache(maxsize=None)
-def _chunk_runner(n_nodes: int, mode: str):
-    """Jitted chunk step with the carry donated: the previous chunk's pool
-    buffers are reused in place, so a replay's footprint stays flat no
-    matter how many chunks it spans."""
-    return jax.jit(functools.partial(_run_cluster_chunk_impl,
-                                     n_nodes=n_nodes, mode=mode),
-                   donate_argnums=(0,))
-
-
-@functools.lru_cache(maxsize=None)
-def _failures_chunk_runner(n_nodes: int, mode: str):
-    return jax.jit(functools.partial(_run_failures_chunk_impl,
-                                     n_nodes=n_nodes, mode=mode),
-                   donate_argnums=(0,))
-
-
-def _chunk_chain_axes(tel: bool, chain: bool) -> tuple:
-    """Trailing vmap in_axes for the optional chunk args
-    ``(widx[, cxs, ccold, cdl])`` — the accumulators ride the stacked
-    (axis-0) carry, so only the per-chunk data appears here: window
-    indices and chain event data are shared, cold draws and deadlines are
-    per-lane."""
-    axes = ()
-    if tel or chain:
-        axes += (None,)            # widx (None arg when only chains on)
-    if chain:
-        axes += (None, 0, 0)       # cxs, ccold, cdl
-    return axes
-
-
-def _sweep_chunk_axes(tel: bool, chain: bool) -> tuple:
-    return (0, None, 0, 0, 0) + _chunk_chain_axes(tel, chain)
-
-
-def _sweep_failures_chunk_axes(tel: bool, chain: bool) -> tuple:
-    return (0, None, 0, 0, 0, 0, 0) + _chunk_chain_axes(tel, chain)
-
-
-@functools.lru_cache(maxsize=None)
-def _sweep_chunk_runner(n_nodes: int, mode: str, tel: bool = False,
-                        chain: bool = False, devices: int | None = None):
-    """Vmapped chunk step for sweeps: lanes stack on the carry/config axes,
-    the chunk's events are shared, and the stacked carry is donated.
-    The leading ``0`` is a pytree prefix, so it maps every carry leaf —
-    plain pools, ``(pools, TelAcc)`` or ``(pools[, TelAcc], ChainAcc)``
-    alike.  ``devices`` shards the lane axis; the donated carry then
-    lives sharded across the mesh and is reused shard-in-place chunk
-    over chunk."""
-    axes = _sweep_chunk_axes(tel, chain)
-    return jax.jit(_shard_lanes(jax.vmap(
-        functools.partial(_run_cluster_chunk_impl, n_nodes=n_nodes,
-                          mode=mode),
-        in_axes=axes), axes, devices),
-        donate_argnums=(0,))
-
-
-@functools.lru_cache(maxsize=None)
-def _sweep_failures_chunk_runner(n_nodes: int, mode: str,
-                                 tel: bool = False, chain: bool = False,
-                                 devices: int | None = None):
-    axes = _sweep_failures_chunk_axes(tel, chain)
-    return jax.jit(_shard_lanes(jax.vmap(
-        functools.partial(_run_failures_chunk_impl, n_nodes=n_nodes,
-                          mode=mode),
-        in_axes=axes), axes, devices),
-        donate_argnums=(0,))
-
-
-def _host_events(trace: Trace, n_nodes: int, *,
-                 resize: bool = False) -> ClusterEvent:
-    """Numpy twin of :func:`cluster_events`: the whole trace stays host-
-    side and chunked replay uploads one slice at a time."""
-    h1, h2 = route_hashes(trace.func_id, n_nodes)
-    fid = np.asarray(trace.func_id, np.int32)
-    size = np.asarray(trace.size_mb, np.float32)
-    return ClusterEvent(
-        t=np.asarray(trace.t, np.float32),
-        func_id=fid,
-        size=size,
-        cls=np.asarray(trace.cls, np.int32),
-        warm=np.asarray(trace.warm_dur, np.float32),
-        cold=np.asarray(trace.cold_dur, np.float32),
-        h1=h1, h2=h2,
-        used=observed_usage(np, fid, size) if resize else None)
-
-
-def _chunk_slice(ev: ClusterEvent, s: int, e: int, chunk: int,
-                 drop_size: float) -> ClusterEvent:
-    """Slice ``[s, e)`` out of host-side events, padding a final partial
-    chunk to ``chunk`` with guaranteed-drop no-ops (same fill rule as
-    :func:`_epoch_grid`)."""
-    sl = jax.tree_util.tree_map(lambda a: a[s:e], ev)
-    pad = chunk - (e - s)
-    if pad:
-        last_t = sl.t[-1] if e > s else np.float32(0.0)
-        fills = ClusterEvent(t=last_t, func_id=-2, size=drop_size, cls=0,
-                             warm=0.0, cold=0.0, h1=0, h2=0,
-                             used=None if ev.used is None else 0.0)
-        sl = jax.tree_util.tree_map(
-            lambda a, f: np.concatenate([a, np.full(pad, f, a.dtype)]),
-            sl, fills)
-    return sl
-
-
-def _chunk_mask(mask: np.ndarray, s: int, e: int, chunk: int, fill: bool,
-                axis: int = 0) -> np.ndarray:
-    """Chunk-slice a per-event mask along ``axis``, padding like
-    :func:`_chunk_slice` (pad rows all-up / never-recovering)."""
-    sl = np.take(mask, np.arange(s, e), axis=axis)
-    pad = chunk - (e - s)
-    if pad:
-        shape = list(sl.shape)
-        shape[axis] = pad
-        sl = np.concatenate([sl, np.full(shape, fill, bool)], axis=axis)
-    return sl
-
-
-def _simulate_cluster_chunked_jax(
+def _simulate_cluster_jax(
         cfg: ClusterConfig, trace: Trace, rng_seed: int = 0,
-        mode: str = "gather", chunk_events: int = 65536,
-        failures: Failures | None = None,
-        telemetry: int | None = None,
-        chains: ChainPlan | None = None):
-    """Chunked twin of ``_simulate_cluster_jax`` /
-    ``_simulate_cluster_failures_jax`` — same return shapes, bit-identical
-    outcomes, peak memory bounded by one chunk.  Telemetry and chain
-    accumulators thread through the donated carry (with *global* window
-    indices / chain rows), so the windows and per-chain metrics match the
-    monolithic scan for any chunk size."""
+        mode: str = "gather", chunk_events: int | None = None,
+        failures: Failures | None = None, telemetry: int | None = None,
+        chains: ChainPlan | None = None) -> tuple[ClusterResult, dict]:
+    """One cluster over ``trace`` in the chunk program, ``chunk_events``
+    at a time (None = the whole trace as one chunk): peak device memory
+    is bounded by one chunk, and since ``lax.scan`` is sequential the
+    outcomes do not depend on the chunk size.  Returns ``(result,
+    extras)``: ``"telemetry"`` window arrays and ``"chains"`` per-chain
+    arrays when requested, ``"vertical"`` run totals with resize, and
+    with ``failures`` the per-node ``"invalidated"`` resident counts and
+    the compiled ``"node_up"`` mask."""
     check_step_mode(mode)
-    chunk = check_chunk_events(chunk_events)
     n, t_len = cfg.n_nodes, len(trace)
-    rz_on = cfg.resize_policy is not None
-    tel_on, ch_on = telemetry is not None, chains is not None
+    chunk = check_chunk_events(chunk_events) or max(t_len, 1)
     with TraceAnnotation("sim.prep", nodes=n):
-        ev_np = _host_events(trace, n, resize=rz_on)
-        routing = jnp.int32(int(cfg.routing))
-        unified = jnp.asarray(cfg.unified, bool)
-        cloud = _cloud_vec(cfg)
-        drop = _drop_size(cfg)
-        n_w = None if not tel_on else _n_windows(t_len, telemetry)
+        n_w = None if telemetry is None else _n_windows(t_len, telemetry)
         cloud_cold = cloud_cold_draws(t_len, cfg.cloud_cold_prob, rng_seed)
-        cxs_np = _chain_xs_np(chains) if ch_on else None
-        cdl = jnp.asarray(chains.deadline) if ch_on else None
-        nodes_out = np.empty(t_len, np.int32)
-        outcomes_out = np.empty(t_len, np.int32)
-        if failures is None:
-            run = _chunk_runner(n, mode)
-            carry = init_cluster(cfg)
-            if tel_on or ch_on:
-                carry = ((carry,) + ((_tel_init(n_w, n),) if tel_on else ())
-                         + ((_chain_init(chains.n_chains),) if ch_on
-                            else ()))
-        else:
-            run = _failures_chunk_runner(n, mode)
-            up_full, rec_full = _failure_masks(failures, trace, n)
-            carry = ((init_cluster(cfg), jnp.zeros((n,), jnp.int32))
-                     + ((_tel_init(n_w, n),) if tel_on else ())
-                     + ((_chain_init(chains.n_chains),) if ch_on else ()))
-    for i, s in enumerate(range(0, t_len, chunk)):
-        e = min(s + chunk, t_len)
-        with TraceAnnotation("sim.chunk", index=i, events=e - s,
-                             pad=chunk - (e - s)):
-            with TraceAnnotation("sim.slice"):
-                ev = _chunk_slice(ev_np, s, e, chunk, drop)
-                fmask = () if failures is None else (
-                    jnp.asarray(_chunk_mask(up_full, s, e, chunk, True)),
-                    jnp.asarray(_chunk_mask(rec_full, s, e, chunk, False)))
-                kw = ({} if not tel_on
-                      else {"widx": _chunk_widx(s, e, chunk, telemetry,
-                                                n_w)})
-                if ch_on:
-                    kw.update(cxs=_chunk_chain(cxs_np, chains.n_chains, s,
-                                               e, chunk),
-                              ccold=_chunk_pad(cloud_cold, s, e, chunk,
-                                               False),
-                              cdl=cdl)
-            with TraceAnnotation("sim.dispatch",
-                                 h2d_bytes=_host_nbytes((ev, kw))):
-                carry, nodes, outcomes = run(carry, ev, *fmask, routing,
-                                             unified, cloud, **kw)
-            with TraceAnnotation("sim.wait"):
-                jax.block_until_ready((nodes, outcomes))
-            with TraceAnnotation("sim.fetch",
-                                 d2h_bytes=2 * nodes_out[s:e].nbytes):
-                nodes_out[s:e] = np.asarray(nodes[:e - s])
-                outcomes_out[s:e] = np.asarray(outcomes[:e - s])
+        masks = (None if failures is None
+                 else _failure_masks(failures, trace, n))
+        xs, fills = _host_xs(
+            trace, n, _drop_size(cfg), resize=cfg.resize_policy is not None,
+            masks=masks, telemetry=telemetry, chains=chains, ccold=cloud_cold)
+        carry = _carry0(init_cluster(cfg), n, masks is not None, n_w, chains)
+        data = _lane_data(cfg, chains)
+    carry, node, outcome = _chunk_loop(_chunk_runner(n, mode), carry, xs,
+                                       fills, data, t_len, chunk)
     with TraceAnnotation("sim.result",
-                         **_result_counts(cfg, trace, nodes_out,
-                                          outcomes_out)):
-        result = build_result(cfg, trace, nodes_out, outcomes_out,
-                              cloud_cold)
-        extras = {}
-        if tel_on:
-            extras["telemetry"] = _tel_np(
-                carry[1 if failures is None else 2], n_w)
-        if ch_on:
-            extras["chains"] = _chain_np(carry[-1], chains.n_chains)
-        if rz_on:
-            # the accumulators ride the threaded carry's pool state, so
-            # the final chunk's pools already hold the whole-trace totals
-            p_end = carry if isinstance(carry, PoolState) else carry[0]
-            extras["vertical"] = _vert_np(_vert_of(p_end)[0])
-        if failures is None:
-            return result if not extras else (result, extras)
-        extras.update(invalidated=np.asarray(carry[1], np.int64),
-                      node_up=up_full)
+                         **_result_counts(cfg, trace, node, outcome)):
+        result = build_result(cfg, trace, node, outcome, cloud_cold)
+        extras = _extras(_carry_np(carry), n_w, chains)
+        if masks is not None:
+            extras["node_up"] = masks[0]
         return result, extras
+
+
+def _simulate_cluster_ref(
+        cfg: ClusterConfig, trace: Trace, rng_seed: int = 0,
+        failures: Failures | None = None, telemetry: int | None = None,
+        chains: ChainPlan | None = None) -> tuple[ClusterResult, dict]:
+    """The numpy oracle's twin of :func:`_simulate_cluster_jax`."""
+    cloud_cold = cloud_cold_draws(len(trace), cfg.cloud_cold_prob, rng_seed)
+    node, outcome, *extras = cluster_outcomes_ref(
+        cfg, trace, failures=failures, telemetry=telemetry, chains=chains,
+        chain_cold=cloud_cold if chains is not None else None)
+    return (build_result(cfg, trace, node, outcome, cloud_cold),
+            extras[0] if extras else {})
 
 
 def lower_chunk_program(cfg: ClusterConfig, trace: Trace,
                         mode: str = "gather", chunk_events: int = 65536):
-    """The first-chunk program of a static chunked run (what
-    ``simulate(..., chunk_events=...)`` executes), lowered but not run,
-    to inspect what the compiler is given — e.g. whether
-    ``mode="fused"`` holds the Pallas kernel as a ``tpu_custom_call``."""
+    """The first-chunk program of a run in chunks with no failures,
+    telemetry or chains (what ``simulate(..., chunk_events=...)``
+    executes), lowered but not run, to inspect what the compiler is given
+    — e.g. whether ``mode="fused"`` holds the Pallas kernel as a
+    ``tpu_custom_call``."""
     check_step_mode(mode)
     chunk = check_chunk_events(chunk_events)
-    ev = _chunk_slice(_host_events(trace, cfg.n_nodes), 0,
-                      min(chunk, len(trace)), chunk, _drop_size(cfg))
+    xs, fills = _host_xs(trace, cfg.n_nodes, _drop_size(cfg))
+    x = _xs_slice(xs, fills, 0, min(chunk, len(trace)), chunk)
     return _chunk_runner(cfg.n_nodes, mode).lower(
-        init_cluster(cfg), ev, jnp.int32(int(cfg.routing)),
-        jnp.asarray(cfg.unified, bool), _cloud_vec(cfg))
+        _carry0(init_cluster(cfg), cfg.n_nodes, False, None, None), x,
+        *_lane_data(cfg, None))
 
 
-def _sweep_cluster_chunked(trace: Trace, configs, rng_seed: int = 0,
-                           mode: str = "gather",
-                           chunk_events: int = 65536,
-                           failures=None, telemetry: int | None = None,
-                           chains=None, devices: int | None = None,
-                           placement: dict | None = None):
-    """Chunked twin of ``_sweep_cluster`` / ``_sweep_cluster_failures``:
-    the chunk loop threads one *stacked* donated carry across all lanes.
-    With ``failures`` (one ``Failures``/None per config), ``telemetry``
-    or ``chains`` returns ``(result, extras)`` pairs, else plain
-    results.  ``devices`` shards the lane axis (pad lanes included in the
-    donated carry, sliced off per chunk below)."""
+def _sweep_cluster(trace: Trace, configs, rng_seed: int = 0,
+                   mode: str = "gather", chunk_events: int | None = None,
+                   failures=None, telemetry: int | None = None,
+                   chains=None, devices: int | None = None,
+                   placement: dict | None = None
+                   ) -> list[tuple[ClusterResult, dict]]:
+    """Many configs (sharing ``n_nodes``/``max_slots``) over one trace in
+    the lane-stacked chunk program: one stacked donated carry, threaded
+    ``chunk_events`` at a time (None = the whole trace as one chunk).
+    ``failures`` is one ``Failures`` (or None) per config, or None;
+    ``chains`` one ``ChainPlan`` per config.  ``devices`` shards the lane
+    axis (pad lanes ride in the carry and are never read back);
+    ``placement`` receives the lane rows per device (see
+    :func:`_lanes_np`).  Returns one ``(result, extras)`` per config, as
+    :func:`_simulate_cluster_jax` does."""
     check_step_mode(mode)
-    chunk = check_chunk_events(chunk_events)
     devices = check_devices(devices)
+    t_len = len(trace)
+    chunk = check_chunk_events(chunk_events) or max(t_len, 1)
     with TraceAnnotation("sim.prep"):
-        failing = failures is not None
-        telw = telemetry
-        tel_on, ch_on = telw is not None, chains is not None
         configs, n, pools, routing, unified, cloud = _stack_configs(
-            configs, "chunked sweep")
-        rz_on = configs[0].resize_policy is not None
-        t_len, lanes = len(trace), len(configs)
+            configs, "sweep")
+        lanes = len(configs)
         pad = _lane_pad(lanes, devices)
-        lanes_p = lanes + pad
-        pools = _pad_tree(pools, pad)
-        routing, unified, cloud = (_pad_tree(a, pad)
-                                   for a in (routing, unified, cloud))
-        ev_np = _host_events(trace, n, resize=rz_on)
-        drop = max(_drop_size(c) for c in configs)
-        n_w = None if telw is None else _n_windows(t_len, telw)
-        clouds = plan = cxs_np = cdl = None
-        if ch_on:
-            plan, clouds, _ = _sweep_chain_data(chains, configs, t_len,
-                                                rng_seed)
-            cxs_np = _chain_xs_np(plan)
-            cdl = _pad_tree(jnp.asarray(
-                np.stack([p.deadline for p in list(chains)])), pad)
-            clouds_p = clouds + clouds[:1] * pad
-        nodes_out = np.empty((lanes, t_len), np.int32)
-        outcomes_out = np.empty((lanes, t_len), np.int32)
-        if failing:
-            failures = list(failures)
-            if len(failures) != lanes:
-                raise ValueError("chunked failure sweep: need one Failures "
-                                 "(or None) per config")
-            masks = [_failure_masks(f, trace, n) for f in failures]
-            up_full = np.stack([m[0] for m in masks])       # [L, T, N]
-            rec_full = np.stack([m[1] for m in masks])
-            if pad:
-                up_p = np.concatenate(
-                    [up_full, np.repeat(up_full[:1], pad, axis=0)])
-                rec_p = np.concatenate(
-                    [rec_full, np.repeat(rec_full[:1], pad, axis=0)])
-            else:
-                up_p, rec_p = up_full, rec_full
-            run = _sweep_failures_chunk_runner(n, mode, tel=tel_on,
-                                               chain=ch_on, devices=devices)
-            carry = (pools, jnp.zeros((lanes_p, n), jnp.int32))
-            if tel_on:
-                carry = carry + (_stack_tel(n_w, n, lanes_p),)
-            if ch_on:
-                carry = carry + (_stack_chain(plan.n_chains, lanes_p),)
-        else:
-            run = _sweep_chunk_runner(n, mode, tel=tel_on, chain=ch_on,
-                                      devices=devices)
-            if tel_on or ch_on:
-                carry = ((pools,)
-                         + ((_stack_tel(n_w, n, lanes_p),) if tel_on else ())
-                         + ((_stack_chain(plan.n_chains, lanes_p),)
-                            if ch_on else ()))
-            else:
-                carry = pools
-    for i, s in enumerate(range(0, t_len, chunk)):
-        e = min(s + chunk, t_len)
-        with TraceAnnotation("sim.chunk", index=i, events=e - s,
-                             pad=chunk - (e - s)):
-            with TraceAnnotation("sim.slice"):
-                ev = _chunk_slice(ev_np, s, e, chunk, drop)
-                fmask = () if not failing else (
-                    jnp.asarray(_chunk_mask(up_p, s, e, chunk, True,
-                                            axis=1)),
-                    jnp.asarray(_chunk_mask(rec_p, s, e, chunk, False,
-                                            axis=1)))
-                wx = ()
-                if tel_on or ch_on:
-                    wx += (None if telw is None
-                           else _chunk_widx(s, e, chunk, telw, n_w),)
-                if ch_on:
-                    wx += (_chunk_chain(cxs_np, plan.n_chains, s, e, chunk),
-                           jnp.stack([_chunk_pad(cc, s, e, chunk, False)
-                                      for cc in clouds_p]), cdl)
-            with TraceAnnotation("sim.dispatch",
-                                 h2d_bytes=_host_nbytes((ev, wx))):
-                carry, nodes, outcomes = run(carry, ev, *fmask, routing,
-                                             unified, cloud, *wx)
-            with TraceAnnotation("sim.wait"):
-                jax.block_until_ready((nodes, outcomes))
-            with TraceAnnotation("sim.fetch",
-                                 d2h_bytes=nodes.nbytes + outcomes.nbytes):
-                nodes_out[:, s:e] = _lanes_np(nodes, placement)[:lanes,
-                                                                :e - s]
-                outcomes_out[:, s:e] = np.asarray(outcomes)[:lanes, :e - s]
+        n_w = None if telemetry is None else _n_windows(t_len, telemetry)
+        plan, cdl = _lane_plan(chains, lanes)
+        clouds = np.stack([cloud_cold_draws(t_len, c.cloud_cold_prob,
+                                            rng_seed) for c in configs])
+        masks = _lane_masks(failures, trace, n, lanes, "sweep")
+        xs, fills = _host_xs(
+            trace, n, max(_drop_size(c) for c in configs),
+            resize=configs[0].resize_policy is not None, masks=masks,
+            telemetry=telemetry, chains=plan, ccold=clouds)
+        xs = _pad_xs(xs, pad)
+        carry = _carry0(_pad_tree(pools, pad), n, masks is not None, n_w,
+                        plan, (lanes + pad,))
+        data = _pad_tree((routing, unified, cloud, cdl), pad)
+    carry, nodes, outcomes = _chunk_loop(
+        _sweep_chunk_runner(n, mode, devices), carry, xs, fills, data,
+        t_len, chunk, lanes, placement)
     with TraceAnnotation("sim.result"):
+        host = _carry_np(carry)
         out = []
-        invals = (np.asarray(carry[1], np.int64) if failing else None)
-        tels = None
-        if tel_on:
-            tels = carry[2] if failing else carry[1]
-        chs = carry[-1] if ch_on else None
-        p_end = carry if isinstance(carry, PoolState) else carry[0]
         for g, c in enumerate(configs):
-            cc = (clouds[g] if ch_on
-                  else cloud_cold_draws(t_len, c.cloud_cold_prob, rng_seed))
-            res = build_result(c, trace, nodes_out[g], outcomes_out[g], cc)
-            extras = {}
-            if tel_on:
-                lane = jax.tree_util.tree_map(lambda a: a[g], tels)
-                extras["telemetry"] = _tel_np(lane, n_w)
-            if ch_on:
-                lane = jax.tree_util.tree_map(lambda a: a[g], chs)
-                extras["chains"] = _chain_np(lane, plan.n_chains)
-            if rz_on:
-                extras["vertical"] = _vert_np(
-                    tuple(np.asarray(a)[g] for a in _vert_of(p_end)[0]))
-            if failing:
-                extras.update(invalidated=invals[g], node_up=up_full[g])
-            out.append((res, extras) if extras else res)
+            extras = _extras(_lane(host, g), n_w, plan)
+            if masks is not None:
+                extras["node_up"] = masks[0][g]
+            out.append((build_result(c, trace, nodes[g], outcomes[g],
+                                     clouds[g]), extras))
         return out
-
-
-def _autoscale_extras(actives, inval, up, failures) -> dict:
-    return {"invalidated": np.asarray(inval, np.int64),
-            "node_up": up if failures is not None else None,
-            "active": np.asarray(actives, bool)}
 
 
 def _simulate_cluster_autoscale_jax(
@@ -1886,54 +1232,29 @@ def _simulate_cluster_autoscale_jax(
         mode: str = "gather", failures: Failures | None = None,
         telemetry: int | None = None, chains: ChainPlan | None = None
         ) -> tuple[ClusterResult, np.ndarray, dict]:
-    """Autoscaled twin of :func:`_simulate_cluster_jax`: returns
-    (ClusterResult, fracs f32[E, N], extras) — extras carries the
-    membership trajectory (``active`` bool[E, N]), per-node
-    ``invalidated`` resident counts, the ``node_up`` failure mask
-    (None without a schedule), and the ``telemetry`` window arrays /
-    ``chains`` per-chain arrays when requested."""
+    """One autoscaled cluster over ``trace`` in the epoch grid: returns
+    (ClusterResult, fracs f32[E, N], extras) — extras as
+    :func:`_simulate_cluster_jax`'s, plus the membership trajectory
+    (``active`` bool[E, N]), with ``invalidated`` always (retirements
+    count) and ``node_up`` None without a failure schedule."""
     check_step_mode(mode)
-    n_events = len(trace)
-    e = asc.epoch_events
-    rz_on = cfg.resize_policy is not None
-    epochs, valid = _epoch_grid(
-        cluster_events(trace, cfg.n_nodes, resize=rz_on),
-        n_events, e, _drop_size(cfg))
-    masked = failures is not None
-    tel_on, ch_on = telemetry is not None, chains is not None
-    up = up_g = rec_g = None
-    if masked:
-        up, recover = _failure_masks(failures, trace, cfg.n_nodes)
-        up_g = _mask_grid(up, n_events, e, True)
-        rec_g = _mask_grid(recover, n_events, e, False)
-    frac0, node_mb, asc_vec, active0 = _autoscale_inputs(cfg, asc)
-    cloud_cold = cloud_cold_draws(n_events, cfg.cloud_cold_prob, rng_seed)
-    args = (init_cluster(cfg), epochs, valid, up_g, rec_g,
-            jnp.int32(int(cfg.routing)), jnp.asarray(cfg.unified, bool),
-            _cloud_vec(cfg), frac0, node_mb, asc_vec, active0)
-    n_w = None if not tel_on else _n_windows(n_events, telemetry)
-    if tel_on or ch_on:
-        args = args + ((None, None) if not tel_on else
-                       (_widx_grid(n_events, e, telemetry),
-                        _tel_init(n_w, cfg.n_nodes)))
-    if ch_on:
-        args = args + (_chain_grid(chains, n_events, e),
-                       _grid_pad(cloud_cold, n_events, e, False),
-                       jnp.asarray(chains.deadline),
-                       _chain_init(chains.n_chains))
-    outs = _run_autoscale(*args, n_nodes=cfg.n_nodes, mode=mode,
-                          masked=masked)
-    node, outcome, fracs, actives, inval = outs[:5]
-    node = np.asarray(node).reshape(-1)[:n_events]
-    outcome = np.asarray(outcome).reshape(-1)[:n_events]
-    extras = _autoscale_extras(actives, inval, up, failures)
-    if tel_on:
-        extras["telemetry"] = _tel_np(outs[5], n_w)
-    if ch_on:
-        extras["chains"] = _chain_np(outs[-2] if rz_on else outs[-1],
-                                     chains.n_chains)
-    if rz_on:
-        extras["vertical"] = _vert_np(outs[-1])
+    n, t_len = cfg.n_nodes, len(trace)
+    n_w = None if telemetry is None else _n_windows(t_len, telemetry)
+    cloud_cold = cloud_cold_draws(t_len, cfg.cloud_cold_prob, rng_seed)
+    masks = None if failures is None else _failure_masks(failures, trace, n)
+    xs, fills = _host_xs(
+        trace, n, _drop_size(cfg), resize=cfg.resize_policy is not None,
+        masks=masks, telemetry=telemetry, chains=chains, ccold=cloud_cold)
+    grid, valid = _xs_grid(xs, fills, t_len, asc.epoch_events)
+    routing, unified, cloud, cdl = _lane_data(cfg, chains)
+    carry, node, outcome, fracs, actives = _epoch_runner(n, mode)(
+        _carry0(init_cluster(cfg), n, True, n_w, chains), grid, valid,
+        routing, unified, cloud, *_autoscale_inputs(cfg, asc), cdl)
+    extras = _extras(_carry_np(carry), n_w, chains)
+    extras.update(node_up=None if masks is None else masks[0],
+                  active=np.asarray(actives, bool))
+    node = np.asarray(node).reshape(-1)[:t_len]
+    outcome = np.asarray(outcome).reshape(-1)[:t_len]
     return (build_result(cfg, trace, node, outcome, cloud_cold),
             np.asarray(fracs), extras)
 
@@ -1956,98 +1277,60 @@ def _sweep_cluster_autoscale(
         mode: str = "gather", telemetry: int | None = None, chains=None,
         devices: int | None = None, placement: dict | None = None
         ) -> list[tuple[ClusterResult, np.ndarray, dict]]:
-    """Vmapped sweep over autoscaled configs.  All configs must share
-    ``n_nodes``/``max_slots`` AND all autoscales ``epoch_events`` (the
-    stacked shapes); min/max/gain, node-scaling thresholds, initial
-    membership, fracs, capacities, and per-lane failure masks vary as
-    data."""
+    """Many autoscaled configs over one trace in the lane-stacked epoch
+    grid.  All configs must share ``n_nodes``/``max_slots`` AND all
+    autoscales ``epoch_events`` (the stacked shapes); min/max/gain,
+    node-scaling thresholds, initial membership, fracs, capacities, and
+    per-lane failure masks vary as data.  Any lane with a failure
+    schedule gives the group masks (lanes without one ride along on
+    all-up masks — the same arithmetic); ``repro.sim.sweep`` buckets
+    failure-free lanes separately."""
     check_step_mode(mode)
     devices = check_devices(devices)
     autoscales = list(autoscales)
     configs, n, pools, routing, unified, cloud = _stack_configs(
         configs, "autoscale sweep")
-    if len(configs) != len(autoscales):
+    lanes, t_len = len(configs), len(trace)
+    if len(autoscales) != lanes:
         raise ValueError("autoscale sweep: need one Autoscale per config")
-    failures = (list(failures) if failures is not None
-                else [None] * len(configs))
-    if len(configs) != len(failures):
-        raise ValueError("autoscale sweep: need one Failures (or None) "
-                         "per config")
     e = autoscales[0].epoch_events
     if any(a.epoch_events != e for a in autoscales):
         raise ValueError("autoscale sweep: configs must share epoch_events"
                          " (sweep() buckets mixed epoch shapes for you)")
+    failures = [None] * lanes if failures is None else list(failures)
+    masks = _lane_masks(failures, trace, n, lanes, "autoscale sweep")
+    if all(f is None for f in failures):
+        masks = None    # the unmasked grid: no per-event invalidation
+    pad = _lane_pad(lanes, devices)
+    n_w = None if telemetry is None else _n_windows(t_len, telemetry)
+    plan, cdl = _lane_plan(chains, lanes)
+    clouds = np.stack([cloud_cold_draws(t_len, c.cloud_cold_prob, rng_seed)
+                       for c in configs])
+    xs, fills = _host_xs(
+        trace, n, max(_drop_size(c) for c in configs),
+        resize=configs[0].resize_policy is not None, masks=masks,
+        telemetry=telemetry, chains=plan, ccold=clouds)
+    grid, valid = _xs_grid(_pad_xs(xs, pad), fills, t_len, e, lanes=True)
     per_cfg = [_autoscale_inputs(c, a) for c, a in zip(configs, autoscales)]
-    frac0, node_mb, asc_vec, active0 = (jnp.stack([p[i] for p in per_cfg])
-                                        for i in range(4))
-    n_events = len(trace)
-    rz_on = configs[0].resize_policy is not None
-    drop_size = max(_drop_size(c) for c in configs)
-    epochs, valid = _epoch_grid(cluster_events(trace, n, resize=rz_on),
-                                n_events, e, drop_size)
-    # any lane with a schedule forces the masked program for the group
-    # (lanes without one ride along on all-up masks — same arithmetic);
-    # repro.sim.sweep buckets failure-free lanes separately
-    masked = any(f is not None for f in failures)
-    up = [None] * len(configs)
-    up_g = rec_g = None
-    if masked:
-        masks = [_failure_masks(f, trace, n) for f in failures]
-        up = np.stack([m[0] for m in masks])
-        up_g = jnp.stack([_mask_grid(m[0], n_events, e, True)
-                          for m in masks])
-        rec_g = jnp.stack([_mask_grid(m[1], n_events, e, False)
-                           for m in masks])
-    tel_on, ch_on = telemetry is not None, chains is not None
-    args = (pools, epochs, valid, up_g, rec_g, routing, unified, cloud,
-            frac0, node_mb, asc_vec, active0)
-    n_w = None if not tel_on else _n_windows(n_events, telemetry)
-    if tel_on or ch_on:
-        args = args + ((None, None) if not tel_on else
-                       (_widx_grid(n_events, e, telemetry),
-                        _stack_tel(n_w, n, len(configs))))
-    clouds = None
-    if ch_on:
-        chains = list(chains)
-        if len(chains) != len(configs) or any(p is None for p in chains):
-            raise ValueError("chain sweep: need one ChainPlan per config")
-        plan = chains[0]
-        clouds = [cloud_cold_draws(n_events, c.cloud_cold_prob, rng_seed)
-                  for c in configs]
-        args = args + (_chain_grid(plan, n_events, e),
-                       jnp.stack([_grid_pad(cc, n_events, e, False)
-                                  for cc in clouds]),
-                       jnp.asarray(np.stack([p.deadline for p in chains])),
-                       _stack_chain(plan.n_chains, len(configs)))
-    args = _pad_lanes(args, _sweep_autoscale_axes(masked, tel_on, ch_on),
-                      _lane_pad(len(configs), devices))
-    outs = _sweep_autoscale_runner(n, mode, masked, tel=tel_on,
-                                   chain=ch_on, devices=devices)(*args)
-    nodes, outcomes, fracs, actives, invals = outs[:5]
+    asc_data = tuple(jnp.stack([p[i] for p in per_cfg]) for i in range(4))
+    carry, nodes, outcomes, fracs, actives = _sweep_epoch_runner(
+        n, mode, devices)(
+        _carry0(_pad_tree(pools, pad), n, True, n_w, plan, (lanes + pad,)),
+        grid, valid,
+        *_pad_tree((routing, unified, cloud, *asc_data, cdl), pad))
     # pad lanes (if any) are dropped here: only real lane rows are read
-    nodes = (_lanes_np(nodes, placement)[:len(configs)]
-             .reshape(len(configs), -1)[:, :n_events])
-    outcomes = (np.asarray(outcomes)[:len(configs)]
-                .reshape(len(configs), -1)[:, :n_events])
-    fracs = np.asarray(fracs)
+    nodes = (_lanes_np(nodes, placement)[:lanes]
+             .reshape(lanes, -1)[:, :t_len])
+    outcomes = np.asarray(outcomes)[:lanes].reshape(lanes, -1)[:, :t_len]
+    fracs, actives = np.asarray(fracs), np.asarray(actives, bool)
+    host = _carry_np(carry)
     out = []
     for g, c in enumerate(configs):
-        extras = _autoscale_extras(actives[g], invals[g], up[g],
-                                   failures[g])
-        if tel_on:
-            lane = jax.tree_util.tree_map(lambda a: a[g], outs[5])
-            extras["telemetry"] = _tel_np(lane, n_w)
-        if ch_on:
-            lane = jax.tree_util.tree_map(
-                lambda a: a[g], outs[-2] if rz_on else outs[-1])
-            extras["chains"] = _chain_np(lane, plan.n_chains)
-        if rz_on:
-            extras["vertical"] = _vert_np(
-                tuple(np.asarray(a)[g] for a in outs[-1]))
-        cc = (clouds[g] if ch_on
-              else cloud_cold_draws(n_events, c.cloud_cold_prob, rng_seed))
-        out.append((build_result(c, trace, nodes[g], outcomes[g], cc),
-                    fracs[g], extras))
+        extras = _extras(_lane(host, g), n_w, plan)
+        extras.update(node_up=None if failures[g] is None else masks[0][g],
+                      active=actives[g])
+        out.append((build_result(c, trace, nodes[g], outcomes[g],
+                                 clouds[g]), fracs[g], extras))
     return out
 
 
@@ -2056,7 +1339,7 @@ def simulate_cluster_jax(cfg: ClusterConfig, trace: Trace,
                          rng_seed: int = 0,
                          mode: str = "gather") -> ClusterResult:
     """Simulate the cluster on ``trace``; one jitted scan end to end."""
-    return _simulate_cluster_jax(cfg, trace, rng_seed, mode)
+    return _simulate_cluster_jax(cfg, trace, rng_seed, mode)[0]
 
 
 @deprecated("repro.sim.simulate(Scenario.cluster(...), engine='ref')")
@@ -2064,7 +1347,7 @@ def simulate_cluster_ref(cfg: ClusterConfig, trace: Trace,
                          rng_seed: int = 0) -> ClusterResult:
     """Numpy-oracle twin of :func:`simulate_cluster_jax` (same result
     type, sequential engine from ``core/continuum.py``)."""
-    return _simulate_cluster_ref(cfg, trace, rng_seed)
+    return _simulate_cluster_ref(cfg, trace, rng_seed)[0]
 
 
 @deprecated("repro.sim.sweep(trace, scenarios)")
@@ -2079,4 +1362,5 @@ def sweep_cluster(trace: Trace, configs, rng_seed: int = 0,
     use common random numbers across configs.  (``repro.sim.sweep``
     additionally buckets mixed shapes into multiple vmapped runs.)
     """
-    return _sweep_cluster(trace, configs, rng_seed, mode)
+    return [res for res, _ in _sweep_cluster(trace, configs, rng_seed,
+                                             mode)]

@@ -4,9 +4,11 @@ One entrypoint for every configuration (single node, heterogeneous
 cluster, any registered policy, failure schedules, node add/remove) and
 both engines:
 
-* ``engine="jax"`` — the whole trace as one jitted ``lax.scan``
-  (``repro.cluster``); sweeps run vmapped, one device program per group
-  of like-shaped scenarios.
+* ``engine="jax"`` — the jitted ``lax.scan`` programs of
+  ``repro.cluster``: the chunk program, over the whole trace as one chunk
+  or ``chunk_events`` at a time, and the epoch grid for autoscaled
+  scenarios; sweeps run vmapped, one device program per group of
+  like-shaped scenarios.
 * ``engine="ref"`` — the sequential numpy oracle, one event at a time
   (``repro.core.continuum``); slower, bit-identical, the ground truth the
   JAX engine is equivalence-tested against.
@@ -20,14 +22,10 @@ from jax.profiler import TraceAnnotation
 
 from ..cluster.engine import (STEP_MODES, _simulate_cluster_autoscale_jax,
                               _simulate_cluster_autoscale_ref,
-                              _simulate_cluster_chunked_jax,
-                              _simulate_cluster_failures_jax,
-                              _simulate_cluster_failures_ref,
                               _simulate_cluster_jax, _simulate_cluster_ref,
                               _sweep_cluster, _sweep_cluster_autoscale,
-                              _sweep_cluster_chunked,
-                              _sweep_cluster_failures, check_chunk_events,
-                              check_devices, check_step_mode)
+                              check_chunk_events, check_devices,
+                              check_step_mode)
 from ..core.types import Trace
 from .chains import metrics_from_arrays
 from .result import Result
@@ -52,6 +50,12 @@ def _check_chunkable(scenario: Scenario, chunk_events) -> int | None:
             "which already bounds per-step work — drop chunk_events or "
             "the Autoscale")
     return chunk
+
+
+def _n_chunks(t_len: int, chunk: int | None) -> int:
+    """The chunks a run of the chunk program takes (``chunk=None`` is the
+    whole trace as one chunk; an empty trace takes none)."""
+    return -(-t_len // (chunk or max(t_len, 1)))
 
 
 def _telw(scenario: Scenario) -> int | None:
@@ -119,20 +123,20 @@ def simulate(scenario: Scenario, trace: Trace, *, engine: str = "jax",
     fixes the cloud cold-start draws (common random numbers: both engines
     and every scenario of a sweep price offloads identically).
 
-    ``chunk_events`` (a positive int, default ``None`` = monolithic)
-    selects the chunked-scan execution mode for the JAX engine: the trace
-    is split host-side into fixed-size chunks and each chunk runs through
-    the same ``lax.scan`` step with the pool state threaded between
-    chunks as a donated carry.  Outcomes are **bit-identical** to the
-    monolithic scan (``lax.scan`` is sequential either way) but peak
-    device memory is bounded by one chunk — the mode that makes
-    million-invocation Azure-2019 replays practical (see
+    ``chunk_events`` (a positive int, default ``None`` = the whole trace
+    as one chunk) sets how many events the JAX engine's chunk program
+    takes at a time: the trace is split host-side into fixed-size chunks
+    and each chunk runs through the same ``lax.scan`` step with the pool
+    state threaded between chunks as a donated carry.  Outcomes are
+    **bit-identical** for any chunk size (``lax.scan`` is sequential
+    either way) but peak device memory is bounded by one chunk — what
+    makes million-invocation Azure-2019 replays practical (see
     ``repro.workloads.replay``).  The reference engine is already
     one-event-at-a-time and ignores it (after validation), so the same
     call runs on both engines.
 
     An autoscaled scenario (``scenario.autoscale`` set) runs the epoch
-    re-splitting engines instead; the returned :class:`Result` then
+    grid instead; the returned :class:`Result` then
     carries the per-epoch split trajectory in ``.fracs`` (and, with node
     scaling, the membership trajectory in ``.active``).  A failure
     schedule (``scenario.failures``) composes with either path — and
@@ -142,7 +146,8 @@ def simulate(scenario: Scenario, trace: Trace, *, engine: str = "jax",
     _check_engine(engine)
     check_step_mode(mode)
     chunk = _check_chunkable(scenario, chunk_events)
-    chunks = -(-len(trace) // chunk) if chunk and engine == "jax" else 0
+    chunks = (_n_chunks(len(trace), chunk)
+              if engine == "jax" and scenario.autoscale is None else 0)
     with TraceAnnotation("sim.simulate", events=len(trace), chunks=chunks):
         return _simulate(scenario, trace, engine, mode, rng_seed, chunk)
 
@@ -161,38 +166,23 @@ def _simulate(scenario: Scenario, trace: Trace, engine: str, mode: str,
             "rng_seed": rng_seed,
             "trace_fingerprint": _fingerprint(trace)}
     fracs = None
-    rz_on = scenario.resize is not None
-    bare = fails is None and telw is None and plan is None and not rz_on
-    if asc is None:
-        if chunk is not None and engine == "jax":
-            out = _simulate_cluster_chunked_jax(
-                cfg, trace, rng_seed, mode, chunk, failures=fails,
+    if asc is not None:
+        if engine == "jax":
+            raw, fracs, extras = _simulate_cluster_autoscale_jax(
+                cfg, asc, trace, rng_seed, mode, failures=fails,
                 telemetry=telw, chains=plan)
-            raw, extras = (out, {}) if bare else out
-        elif fails is None:
-            if engine == "jax":
-                out = _simulate_cluster_jax(cfg, trace, rng_seed, mode,
-                                            telemetry=telw, chains=plan)
-            else:
-                out = _simulate_cluster_ref(cfg, trace, rng_seed,
-                                            telemetry=telw, chains=plan)
-            raw, extras = (out, {}) if telw is None and plan is None \
-                and not rz_on else out
-        elif engine == "jax":
-            raw, extras = _simulate_cluster_failures_jax(
-                cfg, fails, trace, rng_seed, mode, telemetry=telw,
-                chains=plan)
         else:
-            raw, extras = _simulate_cluster_failures_ref(
-                cfg, fails, trace, rng_seed, telemetry=telw, chains=plan)
-    elif engine == "jax":
-        raw, fracs, extras = _simulate_cluster_autoscale_jax(
-            cfg, asc, trace, rng_seed, mode, failures=fails,
-            telemetry=telw, chains=plan)
-    else:
-        raw, fracs, extras = _simulate_cluster_autoscale_ref(
-            cfg, asc, trace, rng_seed, failures=fails, telemetry=telw,
+            raw, fracs, extras = _simulate_cluster_autoscale_ref(
+                cfg, asc, trace, rng_seed, failures=fails, telemetry=telw,
+                chains=plan)
+    elif engine == "ref":
+        raw, extras = _simulate_cluster_ref(
+            cfg, trace, rng_seed, failures=fails, telemetry=telw,
             chains=plan)
+    else:
+        raw, extras = _simulate_cluster_jax(
+            cfg, trace, rng_seed, mode, chunk, failures=fails,
+            telemetry=telw, chains=plan)
     return _wrap(scenario, trace, raw, extras, fracs, telw, info, plan)
 
 
@@ -218,8 +208,8 @@ def sweep(trace: Trace, scenarios: Iterable[Scenario], *,
     max_frac, gain), the node-scaling thresholds, initial membership, and
     any failure masks as data.
 
-    ``chunk_events`` selects the chunked-scan execution mode for every
-    lane (see :func:`simulate`): each group's chunk loop threads ONE
+    ``chunk_events`` sets the chunk size for every lane (see
+    :func:`simulate`): each group's chunk loop threads ONE
     stacked donated carry across all of its lanes, so replay-scale
     traces sweep with the same bounded footprint as a single run.
     Autoscaled scenarios do not compose with it (yet) and raise.
@@ -263,7 +253,8 @@ def sweep(trace: Trace, scenarios: Iterable[Scenario], *,
         # anyway (the chunk_events precedent)
         return [simulate(s, trace, engine="ref", rng_seed=rng_seed)
                 for s in scenarios]
-    chunks = -(-len(trace) // chunk) if chunk else 0
+    chunks = (_n_chunks(len(trace), chunk)
+              if any(s.autoscale is None for s in scenarios) else 0)
     with TraceAnnotation("sim.sweep", events=len(trace),
                          lanes=len(scenarios), chunks=chunks):
         return _sweep(trace, scenarios, modes, rng_seed, chunk, dev)
@@ -296,7 +287,7 @@ def _sweep(trace: Trace, scenarios: list, modes: list, rng_seed: int,
     base_info = {"engine": "jax", "chunk_events": chunk,
                  "devices": dev, "rng_seed": rng_seed,
                  "trace_fingerprint": _fingerprint(trace)}
-    for ((_, _, epoch, failing, telw, chained, rz, gmode),
+    for ((_, _, epoch, failing, telw, chained, _, gmode),
          idxs) in groups.items():
         cfgs = [scenarios[i].to_cluster_config() for i in idxs]
         chs = [plans[i] for i in idxs] if chained else None
@@ -304,46 +295,20 @@ def _sweep(trace: Trace, scenarios: list, modes: list, rng_seed: int,
         placement = None
         if dev:     # sharded groups record the lane rows each device held
             placement = info["lane_devices"] = {}
-        if epoch is None and not failing:
-            if chunk is not None:
-                outs = _sweep_cluster_chunked(trace, cfgs, rng_seed=rng_seed,
-                                              mode=gmode, chunk_events=chunk,
-                                              telemetry=telw, chains=chs,
-                                              devices=dev,
-                                              placement=placement)
-            else:
-                outs = _sweep_cluster(trace, cfgs, rng_seed=rng_seed,
-                                      mode=gmode, telemetry=telw, chains=chs,
-                                      devices=dev, placement=placement)
-            for i, out in zip(idxs, outs):
-                raw, extras = (out, {}) if telw is None and not chained \
-                    and not rz else out
-                results[i] = _wrap(scenarios[i], trace, raw, extras, None,
-                                   telw, info, plans[i])
-        elif epoch is None:
-            fails = [scenarios[i].failures for i in idxs]
-            if chunk is not None:
-                pairs = _sweep_cluster_chunked(
-                    trace, cfgs, rng_seed=rng_seed, mode=gmode,
-                    chunk_events=chunk, failures=fails, telemetry=telw,
-                    chains=chs, devices=dev, placement=placement)
-            else:
-                pairs = _sweep_cluster_failures(
-                    trace, cfgs, fails, rng_seed=rng_seed, mode=gmode,
-                    telemetry=telw, chains=chs, devices=dev,
-                    placement=placement)
-            for i, (raw, extras) in zip(idxs, pairs):
-                results[i] = _wrap(scenarios[i], trace, raw, extras, None,
-                                   telw, info, plans[i])
+        fails = [scenarios[i].failures for i in idxs] if failing else None
+        if epoch is None:
+            outs = [(raw, None, extras) for raw, extras in _sweep_cluster(
+                trace, cfgs, rng_seed=rng_seed, mode=gmode,
+                chunk_events=chunk, failures=fails, telemetry=telw,
+                chains=chs, devices=dev, placement=placement)]
         else:
-            triples = _sweep_cluster_autoscale(
-                trace, cfgs, [scenarios[i].autoscale for i in idxs],
-                [scenarios[i].failures for i in idxs],
+            outs = _sweep_cluster_autoscale(
+                trace, cfgs, [scenarios[i].autoscale for i in idxs], fails,
                 rng_seed=rng_seed, mode=gmode, telemetry=telw, chains=chs,
                 devices=dev, placement=placement)
-            for i, (raw, fracs, extras) in zip(idxs, triples):
-                results[i] = _wrap(scenarios[i], trace, raw, extras, fracs,
-                                   telw, info, plans[i])
+        for i, (raw, fracs, extras) in zip(idxs, outs):
+            results[i] = _wrap(scenarios[i], trace, raw, extras, fracs,
+                               telw, info, plans[i])
     return results
 
 

@@ -1,15 +1,18 @@
 """Azure-2019 replay: schema ingest edge cases + chunked-scan
 bit-equivalence (the two halves of the replay tentpole)."""
+import dataclasses
 import os
 
 import numpy as np
 import pytest
 
 from repro.core.types import Trace
-from repro.sim import Autoscale, Failures, Scenario, simulate, sweep
+from repro.sim import (Autoscale, Chains, Failures, Resize, Scenario,
+                       simulate, sweep)
 from repro.workloads import (ReplayConfig, SchemaConfig, load_azure_trace,
                              read_azure_csvs, synthesize_azure_schema,
                              trace_from_tables, write_azure_csvs)
+from repro.workloads.chains import ChainConfig, chained_trace
 from repro.workloads.replay import (DURATION_PCT_LEVELS, MEMORY_PCT_LEVELS,
                                     AzureTables, _interp_pcts)
 
@@ -310,3 +313,75 @@ def test_chunked_accepts_tiny_trace():
                cold_dur=np.full(n, 2, np.float32))
     scn = Scenario.kiss(256.0, max_slots=8)
     _assert_same(simulate(scn, tr), simulate(scn, tr, chunk_events=64))
+
+
+# ---------------------------------------------------------------------------
+# every optional mechanism through the one chunk program, against the oracle
+# ---------------------------------------------------------------------------
+
+MECHANISMS = {
+    "failures": dict(failures=((20.0, 50.0, 0), (35.0, 70.0, 2))),
+    "telemetry": dict(telemetry=64),
+    "chains": dict(chains=Chains(slack=2.0)),
+    "resize": dict(resize=Resize("fair_share", min_mb=32.0)),
+}
+COMBOS = {"none": ()} | {k: (k,) for k in MECHANISMS} | {
+    "all": tuple(MECHANISMS)}
+
+
+@pytest.fixture(scope="module")
+def chained():
+    """About 360 events in chains of four stages."""
+    tr = chained_trace(ChainConfig(duration_s=90.0, seed=5))
+    assert len(tr) <= 400
+    return tr
+
+
+def _mech_scenario(combo: str, routing: str = "least_loaded") -> Scenario:
+    kw = {}
+    for k in COMBOS[combo]:
+        kw.update(MECHANISMS[k])
+    return Scenario.cluster(CLUSTER, routing=routing, max_slots=32, **kw)
+
+
+def _assert_result_equal(a, b):
+    """Bit-equal outcomes and extras, field by field."""
+    _assert_same(a.raw, b.raw)
+    for f in ("node_up", "invalidated"):
+        x, y = getattr(a, f), getattr(b, f)
+        assert (x is None) == (y is None), f
+        if x is not None:
+            np.testing.assert_array_equal(x, y, err_msg=f)
+    for f in ("telemetry", "chains"):
+        x, y = getattr(a, f), getattr(b, f)
+        assert (x is None) == (y is None), f
+        if x is not None:
+            for fld in dataclasses.fields(x):
+                np.testing.assert_array_equal(
+                    getattr(x, fld.name), getattr(y, fld.name),
+                    err_msg=f"{f}.{fld.name}")
+    assert (a.vertical is None) == (b.vertical is None)
+    if a.vertical is not None:
+        assert a.vertical.keys() == b.vertical.keys()
+        for k in a.vertical:
+            np.testing.assert_array_equal(a.vertical[k], b.vertical[k],
+                                          err_msg=k)
+
+
+@pytest.mark.parametrize("chunk", [None, 97], ids=["whole", "chunk97"])
+@pytest.mark.parametrize("combo", list(COMBOS))
+def test_mechanisms_match_oracle(chained, combo, chunk):
+    """No mechanism, each of failures, telemetry, chains and resize alone,
+    and all four: the chunk program, over the whole trace as one chunk
+    and in chunks of 97, equals the numpy oracle bit for bit."""
+    scn = _mech_scenario(combo)
+    got = simulate(scn, chained, chunk_events=chunk)
+    _assert_result_equal(got, simulate(scn, chained, engine="ref"))
+    if "failures" in COMBOS[combo]:
+        assert got.invalidated.sum() > 0
+
+
+def test_two_lane_sweep_with_every_mechanism_matches_oracle(chained):
+    lanes = [_mech_scenario("all"), _mech_scenario("all", "size_aware")]
+    for scn, got in zip(lanes, sweep(chained, lanes, chunk_events=97)):
+        _assert_result_equal(got, simulate(scn, chained, engine="ref"))

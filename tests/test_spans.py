@@ -16,9 +16,10 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
-from repro.cluster.engine import (_chunk_runner, _chunk_slice, _drop_size,
-                                  _host_events, _result_counts, _tel_init,
-                                  _widx, init_cluster, lower_chunk_program)
+from repro.cluster.engine import (Carry, _chunk_runner, _drop_size,
+                                  _host_xs, _lane_data, _result_counts,
+                                  _tel_init, _xs_slice, init_cluster,
+                                  lower_chunk_program)
 from repro.core.types import Trace
 from repro.sim import Scenario, simulate, sweep
 
@@ -117,14 +118,22 @@ def test_chunked_sweep_span_tree_and_counters(tmp_path, trace):
 
 
 def test_monolithic_simulate_spans(tmp_path, trace):
+    """A whole-trace call runs the chunk program once: one ``sim.chunk``
+    of every event, with no pad."""
     scn = Scenario.kiss(2048.0)
     _, spans = _spans(tmp_path, lambda: simulate(scn, trace))
     (root,) = _named(spans, "sim.simulate")
-    assert root[3] == {"events": EVENTS, "chunks": 0}
+    assert root[3] == {"events": EVENTS, "chunks": 1}
     names = [s[2] for s in spans if s is not root]
-    assert names == ["sim.fingerprint", "sim.prep", "sim.dispatch",
-                     "sim.wait", "sim.fetch", "sim.result"]
+    assert names == ["sim.fingerprint", "sim.prep", "sim.chunk",
+                     "sim.slice", "sim.dispatch", "sim.wait", "sim.fetch",
+                     "sim.result"]
     assert all(_inside(s, root) for s in spans)
+    (chunk,) = _named(spans, "sim.chunk")
+    assert chunk[3] == {"index": 0, "events": EVENTS, "pad": 0}
+    assert all(_inside(s, chunk) for s in spans if s[2] in CHUNK_SPANS)
+    (dispatch,) = _named(spans, "sim.dispatch")
+    assert dispatch[3] == {"h2d_bytes": EVENTS * 8 * 4}
     (fetch,) = _named(spans, "sim.fetch")
     assert fetch[3] == {"d2h_bytes": 2 * EVENTS * 4}
 
@@ -223,11 +232,9 @@ def test_scopes_in_the_lowered_chunk_program(trace, mode):
 
 def test_accumulator_scope_in_a_telemetry_chunk_program(trace):
     cfg = Scenario.kiss(2048.0).to_cluster_config()
-    ev = _chunk_slice(_host_events(trace, cfg.n_nodes), 0, CHUNK, CHUNK,
-                      _drop_size(cfg))
-    carry = (init_cluster(cfg), _tel_init(3, cfg.n_nodes))
+    xs, fills = _host_xs(trace, cfg.n_nodes, _drop_size(cfg), telemetry=64)
+    x = _xs_slice(xs, fills, 0, CHUNK, CHUNK)
+    carry = Carry(init_cluster(cfg), tel=_tel_init(5, cfg.n_nodes))
     lowered = _chunk_runner(cfg.n_nodes, "gather").lower(
-        carry, ev, jax.numpy.int32(0),
-        jax.numpy.asarray(cfg.unified), jax.numpy.zeros((2,)),
-        widx=_widx(CHUNK, 64))
+        carry, x, *_lane_data(cfg, None))
     assert "step.acc" in lowered.as_text(debug_info=True)

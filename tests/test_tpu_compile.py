@@ -22,7 +22,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.cluster.engine import ClusterEvent, _chunk_runner, init_cluster
+from repro.cluster.engine import (Carry, ClusterEvent, EventXs,
+                                  _chunk_runner, init_cluster)
 from repro.kernels.pool_step import _column, fused_evict_place_impl
 from repro.sim import Scenario
 
@@ -113,9 +114,9 @@ def _lower_chunk(sharding, scn: Scenario, chunk: int):
                           cls=col(i32), warm=col(f32), cold=col(f32),
                           h1=col(i32), h2=col(i32))
     return _chunk_runner(cfg.n_nodes, "gather").lower(
-        pools, events, sds(jax.ShapeDtypeStruct((), i32)),
+        Carry(pools), EventXs(events), sds(jax.ShapeDtypeStruct((), i32)),
         sds(jax.ShapeDtypeStruct((cfg.n_nodes,), jnp.bool_)),
-        sds(jax.ShapeDtypeStruct((2,), f32)))
+        sds(jax.ShapeDtypeStruct((2,), f32)), None)
 
 
 def test_gather_chunk_program_compiles(one_chip):
